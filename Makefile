@@ -1,7 +1,8 @@
 # victorialogs_tpu build/test entry points.
 #
-# The native host core (victorialogs_tpu/native/libvlnative.so) also builds
-# itself on first import; this target is for explicit/offline builds.
+# The native host core (victorialogs_tpu/native/libvlnative-<hash>.so, keyed
+# on a hash of vlnative.cpp) also builds itself on first import; this
+# target is for explicit/offline builds.
 
 NATIVE_DIR := victorialogs_tpu/native
 
@@ -21,10 +22,8 @@ help:
 	@echo "  make native   build the native host core explicitly"
 	@echo "  make bench-*  recorded performance rounds (see PERF.md)"
 
-native: $(NATIVE_DIR)/libvlnative.so
-
-$(NATIVE_DIR)/libvlnative.so: $(NATIVE_DIR)/vlnative.cpp
-	g++ -O3 -std=c++17 -shared -fPIC -o $@ $<
+native:
+	python -c "from victorialogs_tpu import native; import sys; sys.exit(not native.available())"
 
 test:
 	python -m pytest tests/ -x -q
@@ -143,4 +142,4 @@ bench-ingest:
 	python tools/bench_ingest.py --json BENCH_ingest.json
 
 clean:
-	rm -f $(NATIVE_DIR)/libvlnative.so
+	rm -f $(NATIVE_DIR)/libvlnative*.so

@@ -65,7 +65,10 @@ def storage(tmp_path_factory):
 # ride along as regression cover
 MATRIX_QUERIES = [
     'err | sort by (dur desc) limit 7 | fields dur, app',
-    'err | sort by (dur) limit 9 | fields dur, app, _time',
+    # limits sit on tie-group boundaries (7 err rows per dur value):
+    # WHICH rows of a split tie group make the cut is arrival order,
+    # and the host path scans day partitions on concurrent threads
+    'err | sort by (dur) limit 14 | fields dur, app, _time',
     '* | stats by (dur:1) count() c, sum(dur) s, min(dur) mn, '
     'max(dur) mx',
     '"err" | stats by (dur:1) count() c',
@@ -123,8 +126,11 @@ def test_row_order_matches_serial_across_partitions(storage,
     monkeypatch.setenv("VL_INFLIGHT", "1")
     monkeypatch.setenv("VL_PACK_PARTS", "1")
     monkeypatch.setenv("VL_CROSS_PARTITION", "0")
-    serial = run_query_collect(storage, [TEN], qs, timestamp=T0,
-                               runner=BatchRunner())
+    # concurrency=1: VL_CROSS_PARTITION=0 also restores the
+    # thread-per-partition fan-out, which is not a serial walk
+    serial = run_query_collect(storage, [TEN],
+                               "options(concurrency=1) " + qs,
+                               timestamp=T0, runner=BatchRunner())
     monkeypatch.setenv("VL_INFLIGHT", "4")
     monkeypatch.setenv("VL_PACK_PARTS", "8")
     monkeypatch.setenv("VL_CROSS_PARTITION", "1")
@@ -214,7 +220,10 @@ def test_cancellation_mid_partition_drains(storage, monkeypatch):
     monkeypatch.setenv("VL_PACK_PARTS", "1")
     runner = BatchRunner()
     qs = 'err | fields _time | limit 3'
-    cpu = run_query_collect(storage, [TEN], qs, timestamp=T0)
+    # `limit` without `sort` takes the first rows to ARRIVE: only the
+    # serial host walk (concurrency=1) has the window's arrival order
+    cpu = run_query_collect(storage, [TEN],
+                            "options(concurrency=1) " + qs, timestamp=T0)
     dev = run_query_collect(storage, [TEN], qs, timestamp=T0,
                             runner=runner)
     assert _norm(cpu) == _norm(dev)

@@ -98,6 +98,12 @@ SHAPES = [
 
 
 def _run(storage, qs, runner):
+    if runner is None:
+        # the SERIAL host walk: partitions in day order, blocks in part
+        # order.  Without the option the host path scans day partitions
+        # on concurrent threads and LogsQL defines no row order across
+        # them — bit identity would then compare scheduler luck.
+        qs = "options(concurrency=1) " + qs
     return run_query_collect(storage, [TEN], qs, timestamp=TS,
                              runner=runner)
 

@@ -30,15 +30,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-try:
-    from jax._src import xla_bridge as _xb
-    for _k in [k for k in list(_xb._backend_factories) if k != "cpu"]:
-        _xb._backend_factories.pop(_k, None)
-    import jax as _jax
-    _jax.config.update("jax_platforms", "cpu")
-except Exception:  # pragma: no cover - plain environments need no surgery
-    pass
-
 # emit shapes: full column set (the default /query response), a narrow
 # fields projection (typed _time fast path), and a wide-match sweep
 QUERIES = [

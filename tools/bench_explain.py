@@ -32,15 +32,6 @@ _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
-try:
-    from jax._src import xla_bridge as _xb
-    for _k in [k for k in list(_xb._backend_factories) if k != "cpu"]:
-        _xb._backend_factories.pop(_k, None)
-    import jax as _jax
-    _jax.config.update("jax_platforms", "cpu")
-except Exception:  # pragma: no cover - plain environments need no surgery
-    pass
-
 N_PARTS = 32
 ROWS_PER_PART = 2048
 QUERY = "err warn | fields _time"

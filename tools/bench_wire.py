@@ -33,15 +33,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-try:
-    from jax._src import xla_bridge as _xb
-    for _k in [k for k in list(_xb._backend_factories) if k != "cpu"]:
-        _xb._backend_factories.pop(_k, None)
-    import jax as _jax
-    _jax.config.update("jax_platforms", "cpu")
-except Exception:  # pragma: no cover - plain environments need no surgery
-    pass
-
 NS = 1_000_000_000
 T0 = 1_753_660_800_000_000_000  # 2025-07-28T00:00:00Z
 

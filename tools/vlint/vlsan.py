@@ -292,6 +292,18 @@ class Sanitizer:
                 owned = bb.live_build_pools() if bb else 0
                 if len(builders) <= owned:
                     leaked = [t for t in leaked if t not in builders]
+            # vl-ingest-encode workers: the refcounted shared encoder
+            # pool (server/wire_ingest.py) spawns workers lazily, so a
+            # function-scoped frontend can mint one that a module-scoped
+            # frontend's reference keeps alive past this test;
+            # release_pool() joins them when the LAST owner closes —
+            # only ownerless survivors count
+            encoders = [t for t in leaked
+                        if t.name.startswith("vl-ingest-encode")]
+            if encoders:
+                wi = _mod("victorialogs_tpu.server.wire_ingest")
+                if wi is not None and wi.live_pool_refs() > 0:
+                    leaked = [t for t in leaked if t not in encoders]
             if leaked:
                 # an abandoned ThreadPoolExecutor's workers exit once
                 # the executor is collected (its weakref callback

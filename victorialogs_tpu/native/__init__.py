@@ -1,16 +1,22 @@
-"""Native host core loader: builds/loads libvlnative.so via ctypes.
+"""Native host core loader: builds/loads libvlnative-<hash>.so via ctypes.
 
 The shared library is compiled on first use with g++ (no pip deps, no
-pybind11 — plain C ABI).  Every consumer has a pure-numpy fallback, so a
-missing toolchain degrades performance, never correctness.  Set
-VL_NO_NATIVE=1 to force the fallbacks (used in tests to diff outputs).
+pybind11 — plain C ABI) and keyed on a hash of vlnative.cpp, so a copied
+or freshly checked-out tree (scrambled mtimes, no .so) neither trusts a
+stale binary nor rebuilds one that matches.  Every consumer has a
+pure-numpy fallback, so a missing toolchain degrades performance, never
+correctness — but it says so once on stderr, and available() reports it.
+Set VL_NO_NATIVE=1 to force the fallbacks (used in tests to diff outputs).
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -18,23 +24,41 @@ from .. import config
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "vlnative.cpp")
-_SO = os.path.join(_HERE, "libvlnative.so")
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
 
-def _build() -> bool:
-    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC",
-           "-o", _SO + ".tmp", _SRC]
+def so_path() -> str:
+    """The built library for THIS vlnative.cpp (Makefile `native` target
+    builds to the same name)."""
+    with open(_SRC, "rb") as f:
+        h = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"libvlnative-{h}.so")
+
+
+def _build(so: str) -> bool:
+    tmp = f"{so}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", tmp, _SRC]
     try:
         res = subprocess.run(cmd, capture_output=True, timeout=120)
-    except (OSError, subprocess.TimeoutExpired):
+    except (OSError, subprocess.TimeoutExpired) as e:
+        print(f"victorialogs_tpu.native: build failed ({e}); host "
+              f"staging/scans fall back to numpy", file=sys.stderr)
         return False
     if res.returncode != 0:
+        print(f"victorialogs_tpu.native: g++ failed; host staging/scans "
+              f"fall back to numpy:\n{res.stderr.decode()[-2000:]}",
+              file=sys.stderr)
         return False
-    os.replace(_SO + ".tmp", _SO)
+    os.replace(tmp, so)
+    for old in glob.glob(os.path.join(_HERE, "libvlnative*.so")):
+        if old != so:          # binaries of earlier sources
+            try:
+                os.remove(old)
+            except FileNotFoundError:
+                pass           # a concurrent builder got there first
     return True
 
 
@@ -46,55 +70,46 @@ def _load():
         _tried = True
         if config.env("VL_NO_NATIVE"):
             return None
-        try:
-            if not os.path.exists(_SO) or \
-                    os.path.getmtime(_SO) < os.path.getmtime(_SRC):
-                # vlint: allow-lock-blocking-deep(one-time lazy init — the compile is deliberately serialized under _lock; every contender needs the artifact and must wait for it)
-                if not _build():
-                    return None
-            lib = ctypes.CDLL(_SO)
-        except OSError:
+        # vlint: allow-lock-blocking-deep(one-time lazy init — hashing the source is part of locating the artifact every contender waits for)
+        so = so_path()
+        # vlint: allow-lock-blocking-deep(one-time lazy init — the compile is deliberately serialized under _lock; every contender needs the artifact and must wait for it)
+        if not os.path.exists(so) and not _build(so):
             return None
+        lib = ctypes.CDLL(so)
         i64 = ctypes.c_int64
         u64 = ctypes.c_uint64
         i32 = ctypes.c_int32
         p_u8 = ctypes.POINTER(ctypes.c_uint8)
         p_i64 = ctypes.POINTER(ctypes.c_int64)
         p_u64 = ctypes.POINTER(ctypes.c_uint64)
-        try:
-            lib.vl_to_fixed_width.argtypes = [p_u8, p_i64, p_i64, i64,
-                                              p_u8, i64, i64]
-            lib.vl_to_fixed_width.restype = None
-            lib.vl_tokenize_arena.argtypes = [p_u8, p_i64, p_i64, i64,
-                                              p_i64, p_i64, p_i64, i64]
-            lib.vl_tokenize_arena.restype = i64
-            lib.vl_unique_token_hashes.argtypes = [p_u8, p_i64, p_i64, i64,
-                                                   p_u64, i64]
-            lib.vl_unique_token_hashes.restype = i64
-            lib.vl_xxh64.argtypes = [p_u8, i64, u64]
-            lib.vl_xxh64.restype = u64
-            lib.vl_phrase_scan.argtypes = [p_u8, p_i64, p_i64, i64, p_u8,
-                                           i64, i32, i32, i32, p_u8]
-            lib.vl_phrase_scan.restype = None
-            lib.vl_ordered_pair_scan.argtypes = [p_u8, p_i64, p_i64, i64,
-                                                 p_u8, i64, p_u8, i64,
-                                                 p_u8, p_u8]
-            lib.vl_ordered_pair_scan.restype = None
-            p_i32 = ctypes.POINTER(ctypes.c_int32)
-            lib.vl_jsonline_scan.argtypes = [p_u8, i64, p_u8, i64,
-                                             p_i32, i64, p_i32, i64,
-                                             p_i64, p_i64]
-            lib.vl_jsonline_scan.restype = i64
-            p_pp = ctypes.POINTER(ctypes.c_void_p)
-            lib.vl_emit_ndjson.argtypes = [i64, i64, p_pp, p_i64,
-                                           p_pp, p_pp, p_pp, p_i64,
-                                           p_i64, p_u8, i64]
-            lib.vl_emit_ndjson.restype = i64
-        except AttributeError:
-            # a stale .so without the newer symbols (mtime tricked the
-            # rebuild check): degrade to the Python paths instead of
-            # failing the first query
-            return None
+        lib.vl_to_fixed_width.argtypes = [p_u8, p_i64, p_i64, i64,
+                                          p_u8, i64, i64]
+        lib.vl_to_fixed_width.restype = None
+        lib.vl_tokenize_arena.argtypes = [p_u8, p_i64, p_i64, i64,
+                                          p_i64, p_i64, p_i64, i64]
+        lib.vl_tokenize_arena.restype = i64
+        lib.vl_unique_token_hashes.argtypes = [p_u8, p_i64, p_i64, i64,
+                                               p_u64, i64]
+        lib.vl_unique_token_hashes.restype = i64
+        lib.vl_xxh64.argtypes = [p_u8, i64, u64]
+        lib.vl_xxh64.restype = u64
+        lib.vl_phrase_scan.argtypes = [p_u8, p_i64, p_i64, i64, p_u8,
+                                       i64, i32, i32, i32, p_u8]
+        lib.vl_phrase_scan.restype = None
+        lib.vl_ordered_pair_scan.argtypes = [p_u8, p_i64, p_i64, i64,
+                                             p_u8, i64, p_u8, i64,
+                                             p_u8, p_u8]
+        lib.vl_ordered_pair_scan.restype = None
+        p_i32 = ctypes.POINTER(ctypes.c_int32)
+        lib.vl_jsonline_scan.argtypes = [p_u8, i64, p_u8, i64,
+                                         p_i32, i64, p_i32, i64,
+                                         p_i64, p_i64]
+        lib.vl_jsonline_scan.restype = i64
+        p_pp = ctypes.POINTER(ctypes.c_void_p)
+        lib.vl_emit_ndjson.argtypes = [i64, i64, p_pp, p_i64,
+                                       p_pp, p_pp, p_pp, p_i64,
+                                       p_i64, p_u8, i64]
+        lib.vl_emit_ndjson.restype = i64
         _lib = lib
         return _lib
 

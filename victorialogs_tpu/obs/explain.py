@@ -466,10 +466,11 @@ def _price_unit(seq, group, by_part, runner, batch, peek, plans,
         # members' — the cold-bytes estimate must probe the same keys
         uid = ("pack",) + tuple(p.uid for p, _b in group) \
             if len(group) > 1 else group[0][0].uid
-        for plan in plans:
-            key = (uid, "#fl", plan.field) if fused \
-                else (uid, plan.field)
-            if not runner.cache.contains(key):
+        # same rule as BatchRunner._gate_host_est: once per field,
+        # warm under either staging layout
+        for fld in {plan.field for plan in plans}:
+            if not (runner.cache.contains((uid, fld)) or
+                    runner.cache.contains((uid, "#fl", fld))):
                 cold += scan_bytes
         n_dispatch = 1 if stats_rows or fused else \
             sum(max(len(p.ops), 1) for p in plans)

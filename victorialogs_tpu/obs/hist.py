@@ -159,7 +159,7 @@ SLOT_WAIT = histogram(
 
 # cost-model accountability (obs/explain.py): per-query relative error
 # |predicted - actual| / actual of the plan-time pricing pass, one
-# histogram per priced dimension.  EWMA drift (backend change, tunnel
+# histogram per priced dimension.  EWMA drift (backend change, host-link
 # degradation, workload shift) shows up here as a rightward creep —
 # alarmable long before the VL_INFLIGHT=auto window or a future
 # priced-admission gate start making bad calls on stale rates.

@@ -87,7 +87,7 @@ def distributed_scan_count(mesh, rows, lengths,
         return bms, total, hist.astype(jnp.int32)
 
     spec = P(BLOCK_AXIS)
-    return K.shard_map_fn()(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=(spec, P(), P()))(rows, lengths, bucket_ids)
@@ -141,7 +141,7 @@ def _stats_values_mesh(mesh, values, ids_tuple, strides, mask,
         return K.pack_stats(cnt, sums, lo, hi)
 
     spec = P(BLOCK_AXIS)
-    return K.shard_map_fn()(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(spec, tuple(spec for _ in ids_tuple), spec),
         out_specs=P())(values, ids_tuple, mask)
@@ -156,7 +156,7 @@ def _stats_count_mesh(mesh, ids_tuple, strides, mask, num_buckets):
         return jax.lax.psum(cnt, BLOCK_AXIS)
 
     spec = P(BLOCK_AXIS)
-    return K.shard_map_fn()(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(tuple(spec for _ in ids_tuple), spec),
         out_specs=P())(ids_tuple, mask)
@@ -186,17 +186,23 @@ class MeshBatchRunner(BatchRunner):
     stats_host_threshold = 0
 
     def __init__(self, mesh: Mesh | None = None, **kw):
-        super().__init__(**kw)
+        mesh = mesh if mesh is not None else make_mesh()
+        super().__init__(devices=list(mesh.devices.flat), **kw)
         # the mesh runner exists to run SPMD — the whole point is ICI
         # reductions, so the per-part cost gate never routes it to host
         # (an explicit VL_COST_FORCE still wins)
         if not self.cost.force:
             self.cost.force = "device"
-        self.mesh = mesh if mesh is not None else make_mesh()
+        self.mesh = mesh
         self.ndev = int(self.mesh.devices.size)
         self.stats_shards = self.ndev
         self._row_sharding = NamedSharding(self.mesh, P(BLOCK_AXIS))
         self._replicated = NamedSharding(self.mesh, P())
+
+    def pallas_enabled(self) -> bool:
+        # the Pallas variants are single-device programs, compiled and
+        # diffed on one chip only; under shard_map the XLA twins serve
+        return False
 
     def _put(self, arr, row_axis: int = 0):
         # shard the row axis when it divides evenly (stats layouts always
@@ -208,6 +214,10 @@ class MeshBatchRunner(BatchRunner):
                 return jax.device_put(arr, self._row_sharding)
             return jax.device_put(
                 arr, NamedSharding(self.mesh, P(None, BLOCK_AXIS)))
+        # never on a power-of-two mesh (row buckets are 128-multiples);
+        # counted so a mesh that does replicate its rows says so on
+        # /metrics instead of quietly holding ndev copies
+        self._bump("replicated_row_puts")
         return jax.device_put(arr, self._replicated)
 
     def _put_replicated(self, arr):

@@ -86,6 +86,35 @@ def main(argv=None) -> int:
     flush_ns = parse_duration(args.inmemoryDataFlushInterval) or 5e9
     future_ns = parse_duration(args.futureRetention) or 2 * 86400e9
 
+    runner = None
+    if args.tpu:
+        import jax
+
+        from ..tpu import compile_cache_dir, cpu_pinned
+        devs = jax.devices()
+        platform = devs[0].platform
+        # -tpu means TPU: a chip that failed to initialise (held by
+        # another process, wrong libtpu) must not leave a server that
+        # answers from the jax-CPU backend and says nothing.  Only an
+        # explicit JAX_PLATFORMS naming cpu asks for the jax-CPU device
+        # path (tests, make check).
+        if platform != "tpu" and not cpu_pinned():
+            print(f"-tpu: jax selected platform={platform!r}, not a TPU; "
+                  f"refusing to serve from a silent fallback — set "
+                  f"JAX_PLATFORMS=cpu to run the device path on jax-CPU "
+                  f"on purpose", file=sys.stderr)
+            return 3
+        if len(devs) > 1:
+            # multi-chip: shard staged rows over the mesh, psum stats
+            from ..parallel.distributed import MeshBatchRunner
+            runner = MeshBatchRunner()
+        else:
+            from ..tpu.batch import BatchRunner
+            runner = BatchRunner()
+        print(f"device: platform={platform} kind={devs[0].device_kind} "
+              f"n={len(devs)} runner={type(runner).__name__} "
+              f"compile_cache={compile_cache_dir()}", flush=True)
+
     storage = Storage(
         args.storageDataPath,
         retention_days=retention_ns / 86400e9,
@@ -93,17 +122,6 @@ def main(argv=None) -> int:
         future_retention_days=future_ns / 86400e9,
         max_disk_usage_bytes=args.max_disk_bytes,
     )
-
-    runner = None
-    if args.tpu:
-        import jax
-        if len(jax.devices()) > 1:
-            # multi-chip: shard staged rows over the mesh, psum stats
-            from ..parallel.distributed import MeshBatchRunner
-            runner = MeshBatchRunner()
-        else:
-            from ..tpu.batch import BatchRunner
-            runner = BatchRunner()
 
     host, _, port_s = args.httpListenAddr.rpartition(":")
     server = VLServer(storage, listen_addr=host or "0.0.0.0",

@@ -650,13 +650,9 @@ def handle_internal_insert(storage, args, body: bytes) -> int:
 
 def _internal_insert(storage, args, body: bytes) -> int:
     with ingestledger.hop("decode"):
-        try:
-            data = _zstd.decompress(body, max_output_size=1 << 30)
-        except Exception as e:
-            # zlib.error / ZstdError are NOT ValueErrors; an
-            # undecodable body is the sender's corruption, not our
-            # 500 — whole-batch 400
-            raise ValueError(f"undecodable insert body: {e}") from None
+        # an undecodable body raises ValueError: the sender's
+        # corruption, not our 500 — whole-batch 400
+        data = _zstd.decompress(body, max_output_size=1 << 30)
     if data.startswith(wire_ingest.INSERT_MAGIC):
         # typed i1 body (self-describing: JSON lines start with "{").
         # With the kill switch thrown this node speaks legacy ONLY —

@@ -271,7 +271,7 @@ def reencode_legacy(body: bytes) -> bytes | None:
     speaking i1 between spool time and replay time."""
     try:
         data = _zstd.decompress(body, max_output_size=MAX_FRAME_BYTES)
-    except (ValueError, OSError, RuntimeError):
+    except ValueError:
         return None
     if not data.startswith(INSERT_MAGIC):
         return None
@@ -532,6 +532,14 @@ def acquire_pool():
                 thread_name_prefix="vl-ingest-encode")
         _pool_refs += 1
         return _pool
+
+
+def live_pool_refs() -> int:
+    """How many live acquirers own the shared encoder pool (vlsan: a
+    worker spawned lazily while one remains is infrastructure, not a
+    leak — release_pool() joins them when the last owner closes)."""
+    with _pool_mu:
+        return _pool_refs
 
 
 def release_pool() -> None:

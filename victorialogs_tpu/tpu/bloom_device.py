@@ -14,10 +14,10 @@ arguments:
   single-dispatch jit (tpu/fused.py) — the per-block keep-mask gathers
   to rows through the staged block-id column and ANDs against the scan
   tree IN HBM, no host round-trip.
-- ``plane_keep_pallas``: a VMEM-tiled Pallas variant (gate behind
-  VL_PALLAS=1, exactly like kernels_pallas.match_scan) replacing the
-  gather with a lane-select so the probe stays a dense VPU op;
-  interpret-mode parity is pinned in tests/pallas_check.py.
+- ``plane_keep_pallas``: a VMEM-tiled Pallas variant (behind
+  VL_PALLAS=1) replacing the gather with a lane-select so the probe
+  stays a dense VPU op; tests/pallas_check.py pins parity in interpret
+  mode on CPU and, run by chip_smoke.py, compiled on the chip.
 
 Layout contract (split-block style, Lang et al. arXiv:2101.01719):
   plane  uint32[B, WP]  2 little-endian lanes per uint64 word, 0-padded
@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kernels_pallas import _VMEM, PALLAS_AVAILABLE, pl
+from .kernels_pallas import pl, pltpu, vmem_spec
 
 PROBE_TILE_B = 128     # pallas block-axis tile (int32 sublane multiple)
 PROBE_LANE = 128       # pallas lane width; also the max probe count
@@ -57,8 +57,7 @@ def probe_np(plane: np.ndarray, idx: np.ndarray, shift: np.ndarray,
 def plane_keep(plane, idx, shift, nwords, use_pallas: bool = False,
                interpret: bool = False):
     """jnp keep-mask; traceable inside an outer jit (fused dispatch)."""
-    if use_pallas and PALLAS_AVAILABLE and \
-            _pallas_ok(plane.shape, idx.shape):
+    if use_pallas and _pallas_ok(plane.shape, idx.shape):
         return plane_keep_pallas(plane, idx, shift, nwords,
                                  interpret=interpret)
     words = jnp.take_along_axis(plane, idx, axis=1)
@@ -80,28 +79,54 @@ def _pallas_ok(plane_shape, idx_shape) -> bool:
             and 0 < idx_shape[1] <= MAX_PALLAS_PROBES)
 
 
-def _probe_kernel(plane_ref, idx_ref, shift_ref, nw_ref, out_ref, *,
-                  nprobes: int, wp: int):
-    """One (PROBE_TILE_B, WP) tile: all probes tested from VMEM.
+def _lane_tile(wp: int) -> int:
+    """Widest lane tile (<= 2048) that divides the padded plane width:
+    a big part's blooms run to thousands of lanes per block, and a
+    whole-row (PROBE_TILE_B, WP) tile would not fit scoped VMEM."""
+    return next(t for t in (2048, 1024, 512, 256, 128) if wp % t == 0)
+
+
+def _probe_kernel(plane_ref, idx_ref, shift_ref, nw_ref, out_ref, acc_ref,
+                  *, nprobes: int, tw: int):
+    """One (PROBE_TILE_B, TW) plane tile: all probes tested from VMEM.
 
     No gather: each probe selects its lane by comparing a broadcast
     iota against the per-block lane index and sum-reducing the masked
-    plane (exactly one lane matches; idx < 2*nwords <= WP always), so
-    the probe lowers to dense VPU compare/select/reduce ops — the same
-    Mosaic-friendly shape discipline as kernels_pallas._scan_kernel.
-    """
-    plane = plane_ref[:]                       # int32[TB, WP] bit pattern
+    tile (exactly one lane of one tile matches; idx < 2*nwords <= WP
+    always), so the probe lowers to dense VPU compare/select/reduce
+    ops.  Per-probe columns are themselves lane-selected out of the
+    (TB, 128) idx tile — no single-lane slices, which Mosaic only takes
+    at aligned offsets.  Probe j's word accumulates in lane j of the
+    (TB, 128) scratch across the lane-tile grid axis; the last lane
+    tile tests the bits."""
+    w = pl.program_id(1)
+
+    @pl.when(w == 0)
+    def _init():
+        acc_ref[:, :] = jnp.zeros_like(acc_ref)
+
+    plane = plane_ref[:, :]                    # int32[TB, TW] bit pattern
     tb = plane.shape[0]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (tb, wp), 1)
-    ok = jnp.ones((tb, 1), dtype=jnp.bool_)
+    idx = idx_ref[:, :]                        # int32[TB, 128]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tb, tw), 1) + w * tw
+    slot = jax.lax.broadcasted_iota(jnp.int32, (tb, PROBE_LANE), 1)
+    acc = acc_ref[:, :]
     for j in range(nprobes):
-        sel = lane == idx_ref[:, j:j + 1]
-        word = jnp.sum(jnp.where(sel, plane, 0), axis=1, keepdims=True)
+        idx_j = jnp.sum(jnp.where(slot == j, idx, 0), axis=1,
+                        keepdims=True)
+        word = jnp.sum(jnp.where(lane == idx_j, plane, 0), axis=1,
+                       keepdims=True)
+        acc = acc + jnp.where(slot == j, word, 0)
+    acc_ref[:, :] = acc
+
+    @pl.when(w == pl.num_programs(1) - 1)
+    def _finish():
         # arithmetic >> then &1 extracts the bit regardless of sign
-        bit = (word >> shift_ref[:, j:j + 1]) & 1
-        ok = jnp.logical_and(ok, bit > 0)
-    keep = jnp.logical_or(ok, nw_ref[:, :] == 0)
-    out_ref[:, :] = keep.astype(jnp.int8)
+        bit = (acc >> shift_ref[:, :]) & 1
+        miss = jnp.where((slot < nprobes) & (bit == 0), 1, 0)
+        ok = jnp.max(miss, axis=1, keepdims=True) == 0
+        keep = jnp.logical_or(ok, nw_ref[:, :] == 0)
+        out_ref[:, :] = keep.astype(jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -110,7 +135,7 @@ def plane_keep_pallas(plane, idx, shift, nwords, interpret: bool = False):
     b, wp = plane.shape
     assert _pallas_ok(plane.shape, idx.shape), (plane.shape, idx.shape)
     nprobes = idx.shape[1]
-    g = b // PROBE_TILE_B
+    tw = _lane_tile(wp)
     # uint32 planes ride as int32 bit patterns (Mosaic int32 lanes)
     plane_i = jax.lax.bitcast_convert_type(plane, jnp.int32)
     pad = PROBE_LANE - nprobes
@@ -118,28 +143,26 @@ def plane_keep_pallas(plane, idx, shift, nwords, interpret: bool = False):
         idx = jnp.pad(idx, ((0, 0), (0, pad)))
         shift = jnp.pad(shift, ((0, 0), (0, pad)))
     nw_col = nwords.reshape(b, 1).astype(jnp.int32)
-    vmem = None if interpret else _VMEM
 
-    def spec(block, index_map):
-        if vmem is None:
-            return pl.BlockSpec(block, index_map)
-        return pl.BlockSpec(block, index_map, memory_space=vmem)
-
-    kernel = partial(_probe_kernel, nprobes=nprobes, wp=wp)
+    spec = vmem_spec
+    kernel = partial(_probe_kernel, nprobes=nprobes, tw=tw)
     out = pl.pallas_call(
         kernel,
-        grid=(g,),
+        grid=(b // PROBE_TILE_B, wp // tw),
         in_specs=[
-            spec((PROBE_TILE_B, wp), lambda i: (i, 0)),
-            spec((PROBE_TILE_B, PROBE_LANE), lambda i: (i, 0)),
-            spec((PROBE_TILE_B, PROBE_LANE), lambda i: (i, 0)),
-            spec((PROBE_TILE_B, 1), lambda i: (i, 0)),
+            spec((PROBE_TILE_B, tw), lambda i, w: (i, w)),
+            spec((PROBE_TILE_B, PROBE_LANE), lambda i, w: (i, 0)),
+            spec((PROBE_TILE_B, PROBE_LANE), lambda i, w: (i, 0)),
+            spec((PROBE_TILE_B, 1), lambda i, w: (i, 0)),
         ],
-        out_specs=spec((PROBE_TILE_B, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.int8),
+        out_specs=spec((PROBE_TILE_B, 1), lambda i, w: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((PROBE_TILE_B, PROBE_LANE), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(plane_i, idx.astype(jnp.int32), shift.astype(jnp.int32), nw_col)
-    return out.reshape(b).astype(jnp.bool_)
+    return out.reshape(b) != 0
 
 
 # ---------------- device staging helpers ----------------

@@ -213,6 +213,22 @@ class StagingCache:
             return {"hits": self.hits, "misses": self.misses,
                     "bytes": self._bytes, "entries": len(self._lru)}
 
+    def device_resident_bytes(self) -> dict:
+        """device id -> bytes of the cached entries' arrays that sit on
+        that device, read off the arrays' own shards (not the budget's
+        nominal costs): the evidence that a mesh runner's staging is
+        spread over its devices rather than piled on one."""
+        with self._mu:
+            entries = list(self._lru.values())
+        out: dict[int, int] = {}
+        for e in entries:
+            for v in getattr(e, "__dict__", {}).values():
+                if isinstance(v, jax.Array):
+                    for sh in v.addressable_shards:
+                        out[sh.device.id] = out.get(sh.device.id, 0) \
+                            + sh.data.nbytes
+        return out
+
     def check_balanced(self) -> bool:
         """Budget-accounting invariant: the running byte total equals
         the recomputed cost of every live entry.  The pipeline's

@@ -28,9 +28,9 @@ therefore stops counting the segment axis toward MAX_BUCKETS.
 
 A Pallas variant of the count reduction (the dominant shape: plain
 ``count()`` group-bys) is gated behind VL_PALLAS=1 like every Pallas
-kernel in this repo (kernels_pallas.py — never on by default, parity
-checked in a clean subprocess via tests/pallas_check.py); the values
-variant stays jnp until profiled on hardware.
+kernel in this repo (never on by default; tests/pallas_check.py pins
+parity in interpret mode on CPU and, run by chip_smoke.py, compiled on
+the chip); the values variant stays jnp.
 """
 
 from __future__ import annotations
@@ -42,7 +42,7 @@ import jax.numpy as jnp
 
 from . import kernels as K
 from .kernels import STATS_CHUNK, _vary
-from .kernels_pallas import _VMEM, PALLAS_AVAILABLE, pl
+from .kernels_pallas import pl, pltpu, vmem_spec
 
 # Pallas tile geometry: segments pad to one f32 sublane tile, buckets
 # to the 128-lane vector width (same discipline as kernels_pallas).
@@ -67,7 +67,7 @@ def stats_count_seg_local(seg_ids: jnp.ndarray, bucket_ids: jnp.ndarray,
     mask: bool[R] (padding rows False).  Returns uint32[nseg*nb] in the
     exact order kernels.stats_count_local produced for the widened
     combined id (seg stride == nb) — the host decode is unchanged."""
-    if use_pallas and PALLAS_AVAILABLE and nseg <= SEG_TILE:
+    if use_pallas and nseg <= SEG_TILE:
         return stats_count_seg_pallas(seg_ids, bucket_ids, mask, nseg,
                                       nb, interpret=interpret)
     sg = seg_ids.astype(jnp.int32).reshape(-1, STATS_CHUNK)
@@ -271,32 +271,40 @@ def stats_values_slots(values, seg_map, bucket_ids, mask, nb: int):
 
 # ---------------- Pallas count variant (VL_PALLAS gate) ----------------
 
-def _count_seg_kernel(seg_ref, b_ref, m_ref, out_ref, *, nseg: int,
-                      nbp: int):
-    """One (STATS_CHUNK, 1) id-column tile: both one-hots built from
-    broadcast iotas (dense VPU compares, no gather) and contracted on
-    the MXU; the [SEG_TILE, nbp] accumulator lives in the revisited
-    output block (same multi-step accumulation discipline as
-    kernels_pallas, init on the first grid step)."""
-    i = pl.program_id(0)
+# bucket-tile lanes per grid step, and the one-hot tile budget the row
+# chunk is sized against: a (BUCKET_TILE, chunk) f32 tile plus its
+# compare temporaries must sit well inside v5e's 16 MiB scoped VMEM
+BUCKET_TILE = 512
+_ONEHOT_TILE_ELEMS = 512 * 1024
 
-    @pl.when(i == 0)
+
+def _count_seg_kernel(seg_ref, b_ref, out_ref, *, tbk: int):
+    """One (bucket tile, row chunk) grid step.
+
+    Ids arrive LANE-major as (1, chunk) rows — a (chunk, 1) column
+    block would pad every id to a 128-lane vreg row (4 MiB per 8192-row
+    column) — so both one-hots are built transposed by comparing the
+    broadcast id row against a SUBLANE iota: seg1hT (SEG_TILE, chunk),
+    b1hT (tbk, chunk), dense VPU compares, no gather.  They contract on
+    the chunk axis as A @ B^T on the MXU; per-chunk cell counts are
+    <= chunk < 2**24, exact in f32, and accumulate as int32 in the
+    revisited output block (zeroed on the first row chunk).  Dead rows
+    carry segment -1, which matches no sublane."""
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[:, :] = jnp.zeros_like(out_ref)
 
-    sg = seg_ref[:, :]                         # int32[C, 1]
-    bi = b_ref[:, :]
-    mi = m_ref[:, :]                           # int32[C, 1] 0/1
-    c = sg.shape[0]
-    seg_iota = jax.lax.broadcasted_iota(jnp.int32, (c, SEG_TILE), 1)
-    b_iota = jax.lax.broadcasted_iota(jnp.int32, (c, nbp), 1)
-    # padding segments/buckets never match a real id: rows land only in
-    # their own (segment, bucket) cell, mask zeroes dead rows
-    seg1h = ((sg == seg_iota) & (mi > 0)).astype(jnp.float32)
-    b1h = (bi == b_iota).astype(jnp.float32)
+    sg = seg_ref[0]                            # int32[1, chunk]
+    bi = b_ref[0]
+    c = sg.shape[1]
+    seg_iota = jax.lax.broadcasted_iota(jnp.int32, (SEG_TILE, c), 0)
+    b_iota = jax.lax.broadcasted_iota(jnp.int32, (tbk, c), 0) \
+        + pl.program_id(0) * tbk
+    seg1h = jnp.where(sg == seg_iota, 1.0, 0.0).astype(jnp.float32)
+    b1h = jnp.where(bi == b_iota, 1.0, 0.0).astype(jnp.float32)
     out_ref[:, :] += jax.lax.dot_general(
-        seg1h, b1h, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        seg1h, b1h, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("nseg", "nb", "interpret"))
@@ -305,30 +313,28 @@ def stats_count_seg_pallas(seg_ids, bucket_ids, mask, nseg: int,
     """Pallas seg-major count; bit-identical to the jnp path (padded
     segments/buckets reduce to zero and are sliced off)."""
     r = seg_ids.shape[0]
-    g = r // STATS_CHUNK
     nbp = ((nb + LANE - 1) // LANE) * LANE
-    sg = seg_ids.astype(jnp.int32).reshape(r, 1)
-    b = bucket_ids.astype(jnp.int32).reshape(r, 1)
-    m = mask.astype(jnp.int32).reshape(r, 1)
+    tbk = min(nbp, BUCKET_TILE)
+    nbp = ((nbp + tbk - 1) // tbk) * tbk
+    chunk = min(STATS_CHUNK, _ONEHOT_TILE_ELEMS // tbk)
+    g = r // chunk
+    sg = jnp.where(mask, seg_ids.astype(jnp.int32), -1).reshape(g, 1, chunk)
+    b = bucket_ids.astype(jnp.int32).reshape(g, 1, chunk)
 
-    def spec(block, index_map):
-        if interpret or _VMEM is None:
-            return pl.BlockSpec(block, index_map)
-        return pl.BlockSpec(block, index_map, memory_space=_VMEM)
-
-    kernel = partial(_count_seg_kernel, nseg=nseg, nbp=nbp)
+    spec = vmem_spec
     out = pl.pallas_call(
-        kernel,
-        grid=(g,),
+        partial(_count_seg_kernel, tbk=tbk),
+        grid=(nbp // tbk, g),
         in_specs=[
-            spec((STATS_CHUNK, 1), lambda i: (i, 0)),
-            spec((STATS_CHUNK, 1), lambda i: (i, 0)),
-            spec((STATS_CHUNK, 1), lambda i: (i, 0)),
+            spec((1, 1, chunk), lambda j, i: (i, 0, 0)),
+            spec((1, 1, chunk), lambda j, i: (i, 0, 0)),
         ],
-        out_specs=spec((SEG_TILE, nbp), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((SEG_TILE, nbp), jnp.float32),
+        out_specs=spec((SEG_TILE, tbk), lambda j, i: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((SEG_TILE, nbp), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(sg, b, m)
+    )(sg, b)
     return out[:nseg, :nb].astype(jnp.uint32).reshape(-1)
 
 

@@ -12,25 +12,13 @@ sporadic "Data corruption detected" under concurrent flush+query load.)
 from __future__ import annotations
 
 import threading
-import zlib
 
-try:
-    import zstandard
-except ImportError:
-    # containers without the zstandard wheel fall back to zlib below
-    zstandard = None
+import zstandard
 
 _tls = threading.local()
 
-# zlib-fallback frame marker.  Real zstd frames start with the magic
-# 28 B5 2F FD, so the two container formats can never be confused; data
-# written by the fallback stays readable if zstandard appears later.
-_ZLIB_MAGIC = b"VLZ1"
-
 
 def compress(data: bytes, level: int = 1) -> bytes:
-    if zstandard is None:
-        return _ZLIB_MAGIC + zlib.compress(data, min(level, 9))
     key = f"zc{level}"
     zc = getattr(_tls, key, None)
     if zc is None:
@@ -40,21 +28,14 @@ def compress(data: bytes, level: int = 1) -> bytes:
 
 
 def decompress(data: bytes, max_output_size: int = 0) -> bytes:
-    if data[:4] == _ZLIB_MAGIC:
-        if max_output_size:
-            # enforce the bound DURING decompression (like the zstd
-            # path) so a hostile frame can't balloon before the check
-            d = zlib.decompressobj()
-            out = d.decompress(data[4:], max_output_size)
-            if d.unconsumed_tail:
-                raise ValueError(
-                    f"decompressed size exceeds limit {max_output_size}")
-            return out
-        return zlib.decompress(data[4:])
-    if zstandard is None:
-        raise RuntimeError(
-            "zstd frame but the zstandard module is unavailable")
+    """Raises ValueError on anything that is not a decodable zstd frame
+    (or exceeds max_output_size): an undecodable body is the sender's
+    corruption, and ValueError is what every caller already maps to a
+    whole-batch reject."""
     zd = getattr(_tls, "zd", None)
     if zd is None:
         zd = _tls.zd = zstandard.ZstdDecompressor()
-    return zd.decompress(data, max_output_size=max_output_size)
+    try:
+        return zd.decompress(data, max_output_size=max_output_size)
+    except zstandard.ZstdError as e:
+        raise ValueError(f"undecodable zstd frame: {e}") from None
