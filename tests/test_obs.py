@@ -271,7 +271,12 @@ def test_http_trace_roundtrip(tmp_path, runner):
         # the trace rides ONE extra final line; rows are bit-identical
         assert traced_lines[:-1] == plain_lines
         tree = json.loads(traced_lines[-1])["_trace"]
-        assert tree["name"] == "query"
+        # the root is the request; the handler's `query` span (name,
+        # extent and subtree as before) hangs beneath it
+        assert tree["name"] == "request"
+        (qtree,) = [c for c in tree["children"] if c["name"] == "query"]
+        assert "error" in qtree["attrs"]["query"]
+        assert find_spans(qtree, "partition")
         assert find_spans(tree, "partition")
         assert find_spans(tree, "harvest")
         # round-trips through JSON
@@ -282,7 +287,9 @@ def test_http_trace_roundtrip(tmp_path, runner):
         _s, data = _req(srv, "GET",
                         f"/select/logsql/stats_query?query={sq}&trace=1")
         obj = json.loads(data)
-        assert obj["trace"]["name"] == "query"
+        assert obj["trace"]["name"] == "request"
+        assert [c["name"] for c in obj["trace"]["children"]] == \
+            ["admission_wait", "parse", "query"]
         _s, data = _req(srv, "GET",
                         f"/select/logsql/stats_query?query={sq}")
         assert "trace" not in json.loads(data)

@@ -7,6 +7,8 @@ import subprocess
 import sys
 import textwrap
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
@@ -689,6 +691,30 @@ def test_span_discipline_clean_and_annotated():
     assert "span-discipline" not in checkers(lint(annotated))
 
 
+@pytest.mark.parametrize("maker", ["make_root", "request_root",
+                                   "make_child"])
+def test_span_discipline_flags_dropped_detached_span(maker):
+    arg = "parent, " if maker == "make_child" else ""
+    bad = f"""
+        from victorialogs_tpu.obs import tracing
+
+        def f(parent):
+            tracing.{maker}({arg}"query")
+    """
+    out = lint(bad)
+    assert "span-discipline" in checkers(out)
+    assert any("made and dropped" in f.message for f in out)
+    kept = f"""
+        from victorialogs_tpu.obs import tracing
+
+        def f(parent):
+            sp = tracing.{maker}({arg}"query")
+            with tracing.activate(sp):
+                pass
+    """
+    assert "span-discipline" not in checkers(lint(kept))
+
+
 def test_span_discipline_skips_tracing_module():
     out = lint(SPAN_BAD_CTOR,
                path="victorialogs_tpu/obs/tracing.py")
@@ -704,7 +730,9 @@ def test_span_discipline_repo_instrumentation_is_clean():
     for rel in ("engine/searcher.py", "storage/filterbank.py",
                 "tpu/pipeline.py", "tpu/batch.py", "tpu/layout.py",
                 "parallel/distributed.py", "server/cluster.py",
-                "server/vlselect.py", "server/app.py"):
+                "server/vlselect.py", "server/app.py",
+                "sched/admission.py", "tpu/fused.py",
+                "obs/stallwatch.py"):
         path = os.path.join(REPO, "victorialogs_tpu", rel)
         sf = SourceFile.parse(path,
                               display_path=f"victorialogs_tpu/{rel}")

@@ -11,13 +11,22 @@ tests pin.  Two ways to break that discipline, both flagged:
   ``parent.span(...)`` (closed by its with-block);
 - span-discipline: a ``.span(...)`` / ``start_trace(...)`` call that is
   not the context expression of a ``with`` item (assigned, passed,
-  returned, or bare) — such a span would never close.
+  returned, or bare) — such a span would never close;
+- span-discipline: a detached span made and dropped: a bare
+  ``make_root(...)`` / ``request_root(...)`` / ``make_child(...)``
+  statement.  Those hand back a span that only ``tracing.activate``
+  closes (the request's root in server/app.py, the handler's ``query``
+  span beneath it in server/vlselect.py); a result nobody keeps can
+  never be activated, and under ``make_child`` it would hang open in
+  its parent's tree for good.
 
 Deliberate sites carry ``# vlint: allow-span-discipline(<why>)``, same
 annotation + baseline discipline as every other checker.
 """
 
 from __future__ import annotations
+
+import ast
 
 from .core import Finding, SourceFile, check_ctx_discipline
 
@@ -38,8 +47,30 @@ _OPENERS = {
 }
 
 
+# calls that hand back a DETACHED span, closed only by tracing.activate
+_MAKERS = ("make_root", "request_root", "make_child")
+
+
+def _dropped_makers(sf: SourceFile) -> list[Finding]:
+    out = []
+    for node in ast.walk(sf.tree):
+        if not (isinstance(node, ast.Expr)
+                and isinstance(node.value, ast.Call)):
+            continue
+        func = node.value.func
+        name = func.attr if isinstance(func, ast.Attribute) else \
+            getattr(func, "id", "")
+        if name in _MAKERS:
+            out.append(Finding(
+                "span-discipline", sf.path, node.lineno, "",
+                f"{name}(...) made and dropped — only "
+                f"`with tracing.activate(span)` closes a detached span; "
+                f"keep the result and activate it"))
+    return out
+
+
 def check(sf: SourceFile) -> list[Finding]:
     if sf.path.replace("\\", "/").endswith(_TRACING_MODULE):
         return []
     return check_ctx_discipline(sf, "span-discipline", _CTORS,
-                                _OPENERS)
+                                _OPENERS) + _dropped_makers(sf)

@@ -431,9 +431,6 @@ declare_env(
     "VL_NO_NATIVE", None, "str",
     "`1` = skip the C++ host core, numpy fallbacks", display="off")
 declare_env(
-    "VL_XLA_TRACE_DIR", None, "str",
-    "XLA profiler traces at the runner seam", display="off")
-declare_env(
     "VL_RESULT_CACHE", "1", "bool",
     "per-part result cache (`engine/standing/resultcache.py`): "
     "repeated queries replay sealed parts' cached stats partials / "
@@ -631,6 +628,16 @@ declare_metric("vl_trace_children_dropped_total", "counter",
 declare_metric("vl_slowlog_emit_failures_total", "counter",
                "slow-query log lines whose sink write failed",
                single_roll=True)
+declare_metric("vl_process_stalls_total", "counter",
+               "stalls the always-on heartbeat saw: a beat over 250 ms "
+               "late, or a query over 1 s old while none finished "
+               "(obs/stallwatch.py)")
+declare_metric("vl_process_stall_seconds_total", "counter",
+               "seconds those stalls lasted")
+declare_metric("vl_gc_collections_total", "counter",
+               "generation-2 garbage collections")
+declare_metric("vl_gc_pause_seconds_total", "counter",
+               "seconds spent in generation-2 garbage collections")
 declare_metric("vl_top_queries_evicted_total", "counter",
                "completed-query ring evictions", single_roll=True)
 declare_metric("vl_journal_dropped_total", "counter",

@@ -17,7 +17,19 @@ nothing measurable on the hot query path: `tracing.current_span()`
 returns a shared no-op singleton whenever no trace is active, and every
 span operation on it (span()/set()/add()) is a constant-time no-op with
 no allocation — asserted by tests/test_obs.py.  Real spans only exist
-inside a `tracing.activate(root)` dynamic extent, which the query
-handlers enter when the request carries `?trace=1` (or the slow-query
-log is armed).
+inside a `tracing.activate(root)` dynamic extent.  A served select
+request enters it where it arrives (server/app.py, before the admission
+gate) when it carries `?trace=1` (or the slow-query log is armed): the
+root is `request`, with the wall clock beside its start
+(`tracing.request_root`), and `admission_wait` (sched/admission.py),
+`parse` and the handler's `query` span (`tracing.make_child`,
+server/vlselect.py) hang beneath it, down to each dispatch's `submit` ->
+`args` + `launch` (tpu/fused.py), so one tree on one clock runs from the
+socket to the device and lays over a device profile.
+
+What no request can ask for is always on: the stall watch
+(stallwatch.py) is one heartbeat thread and a generation-2 `gc` hook,
+started with the server, that write ONE line per stall through the slow
+log's sink and the event bus (`vl_process_stalls_total`,
+`vl_gc_pause_seconds_total`); it does no per-request work.
 """

@@ -548,6 +548,14 @@ def active_snapshot(tenant: str | None = None) -> list[dict]:
     return snaps
 
 
+def progress_counts() -> tuple[int, int]:
+    """(queries ever registered, queries live now): their difference is
+    the count that finished, which the stall watch (obs/stallwatch.py)
+    compares from beat to beat."""
+    with _reg_mu:
+        return _qid_next, len(_active)
+
+
 def cancel(qid: str) -> bool:
     """Flip a live query's cancel flag (POST /select/logsql/
     cancel_query).  False when no such query is active."""

@@ -83,6 +83,14 @@ def maybe_log(endpoint: str, query: str, duration_s: float,
     events.emit("slow_query", endpoint=endpoint, qid=qid or "",
                 duration_ms=rec["duration_ms"],
                 threshold_ms=thr, query=query)
+    write_line(line)
+    return True
+
+
+def write_line(line: str) -> None:
+    """One line to the log's sink: stderr, or what set_sink() put in its
+    place.  Shared with the stall watch (obs/stallwatch.py), whose line
+    belongs in the same stream."""
     sink = _sink
     try:
         if sink is not None:
@@ -94,4 +102,3 @@ def maybe_log(endpoint: str, query: str, duration_s: float,
         # previously silent: a failing sink write now shows up as
         # vl_slowlog_emit_failures_total on /metrics
         events.note("slowlog_emit_failures")
-    return True

@@ -12,6 +12,14 @@ context-manager-only:
             h.add("rows_downloaded", n)
     tree = root.to_dict()
 
+A served select request makes its root where the request arrives
+(server/app.py, `request_root("request", ...)`, before the admission
+gate) and the handler hangs its `query` span under it with
+`make_child()`, so one tree runs from the socket to the device.  Only a
+`request_root` carries `start_unix_ns`, the wall clock read beside its
+perf_counter start: any span's absolute time is `start_unix_ns +
+start_ms`, the clock a device profile's gaps are converted to.
+
 Direct ``Span(...)`` construction and un-with'd ``.span(...)`` calls are
 forbidden outside this module by the vlint `span-discipline` checker:
 the with-block is what guarantees every span closes on every exit path
@@ -306,6 +314,26 @@ def current_span():
 def make_root(name: str, **attrs) -> Span:
     """A detached root span; close it by exiting activate(root)."""
     return Span(name, attrs)
+
+
+def request_root(name: str, **attrs) -> Span:
+    """make_root for the span a request arrives under: it also records
+    `start_unix_ns`, the wall clock beside its perf_counter start, so
+    every span of the tree has an absolute time (start_unix_ns +
+    start_ms) that lays over a device profile."""
+    root = Span(name, attrs)
+    attrs["start_unix_ns"] = time.time_ns()
+    return root
+
+
+def make_child(parent, name: str, **attrs) -> Span:
+    """A detached span hung under `parent`: like make_root (close it by
+    exiting activate(child), possibly on another thread), but part of
+    the parent's tree.  The query handlers use it for the `query` span
+    beneath the request's root."""
+    sp = Span(name, attrs)
+    parent.attach(sp)
+    return sp
 
 
 class _Activation:

@@ -68,6 +68,7 @@ def distributed_scan_count(mesh, rows, lengths,
     the two aggregates psum-reduced across devices.
     """
 
+    @jax.named_scope("match_scan")
     def per_block(rw, lens):
         bm = K.match_scan(rw, lens, pattern, pat_len, mode, starts_tok,
                           ends_tok)
@@ -130,6 +131,7 @@ def _stats_values_mesh(mesh, values, ids_tuple, strides, mask,
     same chunked kernel body, then count/sums ride psum and min/max ride
     pmin/pmax over ICI — the mesh analogue of the reference's mergeState
     (pipe_stats.go:354-377)."""
+    @jax.named_scope("stats")
     def shard_fn(v, ids, m):
         b = K.combine_ids(ids, strides)
         cnt, sums, lo, hi = K.stats_values_local(v, b, m, num_buckets,
@@ -149,6 +151,7 @@ def _stats_values_mesh(mesh, values, ids_tuple, strides, mask,
 
 @partial(jax.jit, static_argnames=("num_buckets", "strides", "mesh"))
 def _stats_count_mesh(mesh, ids_tuple, strides, mask, num_buckets):
+    @jax.named_scope("stats")
     def shard_fn(ids, m):
         b = K.combine_ids(ids, strides)
         cnt = K.stats_count_local(b, m, num_buckets,
@@ -209,7 +212,11 @@ class MeshBatchRunner(BatchRunner):
         # do; string-staging row buckets do for power-of-two mesh sizes),
         # else replicate — correctness never depends on the placement.
         # row_axis=1: lane-major uint32[W/4, R] string staging.
-        if arr.shape[row_axis] % self.ndev == 0:
+        striped = arr.shape[row_axis] % self.ndev == 0
+        # a replicated array is handed to every device
+        self._bump("h2d_bytes_total",
+                   arr.nbytes * (1 if striped else self.ndev))
+        if striped:
             if row_axis == 0:
                 return jax.device_put(arr, self._row_sharding)
             return jax.device_put(
@@ -224,7 +231,8 @@ class MeshBatchRunner(BatchRunner):
         # block-axis arrays (bloom planes / keep-mask operands): every
         # shard probes the full block axis, so these never stripe —
         # matches the P() in_specs the fused mesh dispatch declares for
-        # non-row args
+        # non-row args.  Every device is handed its own copy.
+        self._bump("h2d_bytes_total", arr.nbytes * self.ndev)
         return jax.device_put(arr, self._replicated)
 
     def _trace_collective(self) -> None:
@@ -237,17 +245,16 @@ class MeshBatchRunner(BatchRunner):
             sp.add("mesh_collective_dispatches")
             sp.set("mesh_devices", self.ndev)
 
-    def _dispatch_fused(self, prog, strides, nb, n_values, nrows,
+    def _dispatch_fused(self, name, prog, strides, nb, n_values, nrows,
                         cand_packed, seg_map, ids_tuple, values_tuple,
                         args):
-        from ..tpu.fused import _fused_dispatch_mesh
+        from ..tpu.fused import fused_mesh_program
         self._trace_collective()
-        return _fused_dispatch_mesh(self.mesh, BLOCK_AXIS, prog, strides,
-                                    nb, n_values, nrows, cand_packed,
-                                    seg_map, ids_tuple, values_tuple,
-                                    args)
+        return fused_mesh_program(name)(
+            self.mesh, BLOCK_AXIS, prog, strides, nb, n_values, nrows,
+            cand_packed, seg_map, ids_tuple, values_tuple, args)
 
-    def _dispatch_filter(self, prog, nrows, cand_packed, args):
+    def _dispatch_filter(self, name, prog, nrows, cand_packed, args):
         # row-query fused filter under shard_map: each device evaluates
         # its row stripe, packed (definite, maybe) bits concatenate over
         # the row axis.  Layouts are padded to STATS_CHUNK * ndev rows
@@ -256,10 +263,10 @@ class MeshBatchRunner(BatchRunner):
         # padding).  The async window (tpu/pipeline.py) drives this
         # exactly like the single-chip runner: submission issues the
         # collective dispatch, harvest materializes in order.
-        from ..tpu.fused import _filter_dispatch_mesh
+        from ..tpu.fused import filter_mesh_program
         self._trace_collective()
-        return _filter_dispatch_mesh(self.mesh, BLOCK_AXIS, prog, nrows,
-                                     cand_packed, args)
+        return filter_mesh_program(name)(self.mesh, BLOCK_AXIS, prog,
+                                         nrows, cand_packed, args)
 
     def _dispatch_stats_count(self, ids_tuple, strides, mask, nb):
         return np.array(_stats_count_mesh(self.mesh, ids_tuple, strides,

@@ -21,7 +21,8 @@ slot/queue waits land in the obs.hist histograms and ?trace=1 trees.
 from __future__ import annotations
 
 from .admission import (AdmissionController, AdmissionShed, REASONS,
-                        admission_snapshots, note_rejected)
+                        admission_snapshots, note_rejected,
+                        rejected_total)
 from .admission import metrics_samples as _admission_metrics
 from .netfaults import (FaultProxy, clear_net_faults, inject_net_fault,
                         maybe_fail_net)
@@ -38,8 +39,8 @@ __all__ = [
     "check_balanced", "clear_faults", "clear_net_faults",
     "device_slots", "global_budget", "inject_fault", "inject_net_fault",
     "maybe_fail_net", "maybe_fail_submit", "metrics_samples",
-    "note_rejected", "sched_enabled", "scheduler", "set_tenant_weight",
-    "snapshot", "tenant_weight",
+    "note_rejected", "rejected_total", "sched_enabled", "scheduler",
+    "set_tenant_weight", "snapshot", "tenant_weight",
 ]
 
 

@@ -45,7 +45,7 @@ import time
 import weakref
 
 from .. import config
-from ..obs import events, hist
+from ..obs import events, hist, tracing
 
 REASONS = ("tenant_limit", "queue_full", "deadline", "cancelled")
 
@@ -121,6 +121,15 @@ def note_rejected(tenant: str, reason: str,
     with _acct_mu:
         key = (pool, reason, _capped_tenant(_rejected_tenants, tenant))
         _rejected[key] = _rejected.get(key, 0) + 1
+
+
+def rejected_total(pool: str = "select") -> int:
+    """Every shed of a pool so far.  The stall watch (obs/stallwatch.py)
+    takes the select pool's out of its count of finished queries (a shed
+    request registers and ends like any other): a burst of sheds is what
+    a stall looks like, not progress."""
+    with _acct_mu:
+        return sum(n for (p, _r, _t), n in _rejected.items() if p == pool)
 
 
 def _note_admitted(tenant: str, pool: str = "select") -> None:
@@ -368,6 +377,17 @@ class _Admission:
         return None
 
     def __enter__(self) -> "_Admission":
+        # arrival to admitted, on the request's trace (a no-op with
+        # tracing off and on the internal pool, which has no root)
+        with tracing.current_span().span("admission_wait") as sp:
+            if sp.enabled:
+                c = self._c
+                with c._cond:
+                    sp.set("queued_behind",
+                           sum(1 for w in c._queue if not w.dead))
+            return self._enter()
+
+    def _enter(self) -> "_Admission":
         c = self._c
         t0 = time.monotonic()
         deadline = None if self._deadline_s is None else \

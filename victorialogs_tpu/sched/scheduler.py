@@ -201,6 +201,12 @@ class DispatchScheduler:
         with self._mu:
             return self._in_flight == 0 and not self._flows
 
+    def in_flight(self) -> int:
+        """Leased slots, process-wide: dispatch units submitted and not
+        yet harvested (the trace's `device_queue_depth` reads this)."""
+        with self._mu:
+            return self._in_flight
+
     def snapshot(self) -> dict:
         with self._mu:
             flows = [{"key": str(f.key), "tenant": f.tenant,

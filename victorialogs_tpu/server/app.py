@@ -23,7 +23,8 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 
 from ..engine.searcher import QueryTimeoutError
-from ..obs import activity, events, hist, ingestledger, journal
+from ..obs import (activity, events, hist, ingestledger, journal,
+                   stallwatch, tracing)
 from ..storage.storage import Storage
 from ..utils.memory import QueryMemoryError
 from .. import sched
@@ -36,7 +37,8 @@ from .vlselect import (HTTPError, handle_explain, handle_facets,
                        handle_stats_query_range,
                        handle_stream_field_names, handle_stream_field_values,
                        handle_stream_ids, handle_streams, handle_tail,
-                       parse_common_args, query_timeout_s, want_explain)
+                       parse_common_args, query_timeout_s,
+                       request_trace_root, want_explain)
 
 
 def escape_label_value(v: str) -> str:
@@ -159,6 +161,10 @@ class Metrics:
         for base, labels, v in events.metrics_samples():
             add(metric_name(base, **labels), v)
         for base, labels, v in journal.metrics_samples():
+            add(metric_name(base, **labels), v)
+        # the always-on stall watch: stalls seen by its heartbeat and
+        # generation-2 gc pauses (obs/stallwatch.py)
+        for base, labels, v in stallwatch.metrics_samples():
             add(metric_name(base, **labels), v)
         # ingest conservation ledger: per-tenant accepted/forwarded/
         # stored/dropped{reason} rolls, derived in-flight rows and the
@@ -663,6 +669,9 @@ class VLServer(BaseHTTPApp):
         # (emit() structurally zero-cost).  Never behind admission: the
         # journal must not be shed by the overload it records.
         self.journal = journal.maybe_start(self.sink)
+        # the stall line that is always on (obs/stallwatch.py): one
+        # heartbeat thread and a gc hook for the whole process
+        stallwatch.start()
         # standing-query registry (engine/standing/manager.py):
         # resident merged state per distinct query fingerprint,
         # re-evaluated on flush/merge bus events, deltas fanned out to
@@ -681,6 +690,7 @@ class VLServer(BaseHTTPApp):
             # subscription + flush thread (nor the usage poll loop or
             # the standing registry's worker/bus subscription)
             self.standing.close()
+            stallwatch.close()
             if self.journal is not None:
                 self.journal.close()
             if self.clusterstats is not None:
@@ -876,8 +886,13 @@ class VLServer(BaseHTTPApp):
             # cancellable by qid; the handler reuses this record via
             # activity.reuse_or_track, so counters stay one-per-query
             tenant = get_tenant_id(headers, args)
-            with activity.track(path, args.get("query", ""),
-                                tenant) as act:
+            # the trace's root is made HERE, where the request arrives
+            # (None keeps the no-op path): admission wait, parse and
+            # the handler's `query` span hang under it, so one tree on
+            # one clock runs from the socket to the device
+            with tracing.activate(request_trace_root(path, args)), \
+                    activity.track(path, args.get("query", ""),
+                                   tenant) as act:
                 # resolve the partial-results mode HERE (explicit
                 # ?partial arg over the VL_PARTIAL_RESULTS default) and
                 # stamp it on the record: the scatter-gather reads it
@@ -887,6 +902,9 @@ class VLServer(BaseHTTPApp):
                 want_partial = netrobust.partial_requested(args)
                 if want_partial or "partial" in args:
                     act.set("partial_ok", 1 if want_partial else -1)
+                # the tree's top node carries the qid that slow-log
+                # lines and active_queries records correlate by
+                tracing.current_span().set("qid", act.qid)
                 act.set_phase("queued")
                 try:
                     with self.admission.admit(
@@ -1045,6 +1063,8 @@ class VLServer(BaseHTTPApp):
         # mid-poll node error can't race the teardown)
         if self.clusterstats is not None:
             self.clusterstats.close()
+        # the stall watch writes through the event bus: before the journal
+        stallwatch.close()
         # drain the journal FIRST (its flush writes through self.sink)
         if self.journal is not None:
             self.journal.close()

@@ -56,6 +56,11 @@ def _parse_time_arg(v: str, default: int, end: bool = False) -> int:
 
 
 def parse_common_args(storage, args, headers) -> tuple[Query, list]:
+    with tracing.current_span().span("parse"):
+        return _parse_common_args(args, headers)
+
+
+def _parse_common_args(args, headers) -> tuple[Query, list]:
     qs = args.get("query", "")
     if not qs:
         raise HTTPError(400, "missing query arg")
@@ -141,13 +146,36 @@ def want_trace(args) -> bool:
     return args.get("trace", "") in ("1", "true", "yes")
 
 
-def _trace_root(args, q: Query):
-    """A root span when the request asked for a trace OR the slow-query
-    log is armed (a slow query without a trace is exactly what the log
-    exists to avoid); None keeps the zero-cost no-op path."""
-    if want_trace(args) or slowlog.enabled():
-        return tracing.make_root("query", query=q.to_string())
+def _tracing_on(args) -> bool:
+    """The request asked for a trace OR the slow-query log is armed (a
+    slow query without a trace is exactly what the log exists to
+    avoid); False keeps the zero-cost no-op path."""
+    return want_trace(args) or slowlog.enabled()
+
+
+def request_trace_root(path: str, args):
+    """The `request` root server/app.py opens where a select request
+    arrives, before the admission gate; None when tracing is off."""
+    if _tracing_on(args):
+        return tracing.request_root("request", path=path)
     return None
+
+
+def _trace_roots(args, q: Query) -> tuple:
+    """(root, top): the handler's `query` span and the span whose tree
+    a ?trace=1 answer carries; (None, None) with tracing off.  Under a
+    served request `query` hangs beneath the ambient `request` root,
+    which is the top: it is still open while its own answer is written,
+    so its exported extent ends where the tree is serialized.  Called
+    without one (embedded use, tests) `query` is its own root."""
+    outer = tracing.current_span()
+    if outer.enabled:
+        return tracing.make_child(outer, "query",
+                                  query=q.to_string()), outer
+    if _tracing_on(args):
+        root = tracing.make_root("query", query=q.to_string())
+        return root, root
+    return None, None
 
 
 def _partial_block(act) -> dict | None:
@@ -169,7 +197,7 @@ def _run_collect_traced(storage, tenants, q, args, runner, endpoint,
     it and partial is the ``"partial"`` payload block (or None).
     Emits the slow-query line either way, with the qid correlating it
     to active_queries/traces."""
-    root = _trace_root(args, q)
+    root, top = _trace_roots(args, q)
     t0 = time.monotonic()
     # reuse the record the admission layer registered (server/app.py);
     # self-register when called without it (tests, embedded use)
@@ -191,7 +219,7 @@ def _run_collect_traced(storage, tenants, q, args, runner, endpoint,
             slowlog.maybe_log(endpoint, q.to_string(),
                               time.monotonic() - t0, root, qid=act.qid)
         partial = _partial_block(act)
-    tree = root.to_dict() if root is not None and want_trace(args) \
+    tree = top.to_dict() if top is not None and want_trace(args) \
         else None
     return result, tree, partial
 
@@ -277,7 +305,7 @@ def handle_query(storage, args, headers, runner=None):
         data = ndjson_block(br)
         return data if data else None
 
-    root = _trace_root(args, q)
+    root, top = _trace_roots(args, q)
     deadline = query_deadline(args)
 
     def gen():
@@ -333,8 +361,8 @@ def handle_query(storage, args, headers, runner=None):
                 yield json.dumps({"_partial": partial},
                                  ensure_ascii=False,
                                  separators=(",", ":")) + "\n"
-            if root is not None and want_trace(args):
-                yield json.dumps({"_trace": root.to_dict()},
+            if top is not None and want_trace(args):
+                yield json.dumps({"_trace": top.to_dict()},
                                  ensure_ascii=False,
                                  separators=(",", ":")) + "\n"
 

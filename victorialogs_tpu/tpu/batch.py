@@ -1009,6 +1009,9 @@ class BatchRunner:
         self.sched_slot_wait_s = 0.0   # time leasing dispatch slots from
         #                                the shared scheduler (sched/)
         self.inflight_auto_depth = 0   # VL_INFLIGHT=auto chosen depth
+        self.h2d_bytes_total = 0       # bytes handed to the device at the
+        #                                placement seams (_put /
+        #                                _put_replicated), once an array
         self.stats_shards = 1          # mesh runners stripe rows over >1
         # distinct dispatch shapes this runner has sent to the device —
         # the multichip dryrun asserts breadth here (verdict r4 weak #6)
@@ -1081,6 +1084,7 @@ class BatchRunner:
                 "host_sync_wait_s": self.host_sync_wait_s,
                 "sched_slot_wait_s": self.sched_slot_wait_s,
                 "inflight_auto_depth": self.inflight_auto_depth,
+                "h2d_bytes_total": self.h2d_bytes_total,
             }
         out.update({f"staging_cache_{k}": v
                     for k, v in self.cache.stats().items()})
@@ -1262,6 +1266,7 @@ class BatchRunner:
     # ---- device placement hook (MeshBatchRunner shards the row axis) ----
     def _put(self, arr, row_axis: int = 0):
         import jax.numpy as jnp
+        self._bump("h2d_bytes_total", arr.nbytes)
         return jnp.asarray(arr)
 
     def _put_replicated(self, arr):
@@ -1270,6 +1275,7 @@ class BatchRunner:
         array — a mesh runner replicates instead of striping (the block
         axis is not the sharded row axis)."""
         import jax.numpy as jnp
+        self._bump("h2d_bytes_total", arr.nbytes)
         return jnp.asarray(arr)
 
     def _stub(self, shape: tuple, dtype):
@@ -1285,23 +1291,25 @@ class BatchRunner:
         return got
 
     # ---- stats dispatch hooks (MeshBatchRunner shard_maps + psum-reduces)
-    def _dispatch_fused(self, prog, strides, nb, n_values, nrows,
+    # `name`: the program's name (fused.program_name), under which the
+    # one jitted callable of that kind is looked up
+    def _dispatch_fused(self, name, prog, strides, nb, n_values, nrows,
                         cand_packed, seg_map, ids_tuple, values_tuple,
                         args):
-        from .fused import _fused_dispatch
-        return _fused_dispatch(prog, strides, nb, n_values, nrows,
-                               cand_packed, seg_map, ids_tuple,
-                               values_tuple, args)
+        from .fused import fused_program
+        return fused_program(name)(prog, strides, nb, n_values, nrows,
+                                   cand_packed, seg_map, ids_tuple,
+                                   values_tuple, args)
 
-    def _dispatch_topk(self, prog, k, desc, nseg, nrows, cand_packed,
-                       seg_ids, seg_map, values, args):
-        from .fused import _topk_dispatch
-        return _topk_dispatch(prog, k, desc, nseg, nrows, cand_packed,
-                              seg_ids, seg_map, values, args)
+    def _dispatch_topk(self, name, prog, k, desc, nseg, nrows,
+                       cand_packed, seg_ids, seg_map, values, args):
+        from .fused import topk_program
+        return topk_program(name)(prog, k, desc, nseg, nrows, cand_packed,
+                                  seg_ids, seg_map, values, args)
 
-    def _dispatch_filter(self, prog, nrows, cand_packed, args):
-        from .fused import _filter_dispatch
-        return _filter_dispatch(prog, nrows, cand_packed, args)
+    def _dispatch_filter(self, name, prog, nrows, cand_packed, args):
+        from .fused import filter_program
+        return filter_program(name)(prog, nrows, cand_packed, args)
 
     def _dispatch_stats_count(self, ids_tuple, strides, mask, nb):
         # vlint: allow-jax-host-sync(result readback at dispatch boundary)
@@ -1425,13 +1433,6 @@ class BatchRunner:
     def _run_part_device(self, f, part, bss: dict) -> dict:
         """run_part past the host gate (run_part_submit's fused-decline
         fallback lands here directly — its gate already ran)."""
-        trace_dir = config.env("VL_XLA_TRACE_DIR")
-        if trace_dir:
-            # XLA profiler hook at the block-runner seam (SURVEY §5);
-            # inspect with tensorboard or xprof
-            import jax
-            with jax.profiler.trace(trace_dir):
-                return self._eval(f, part, bss, list(bss))
         return self._eval(f, part, bss, list(bss))
 
     def _eval(self, f, part, bss, alive) -> dict:

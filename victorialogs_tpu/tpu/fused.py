@@ -41,14 +41,16 @@ them bit-exactly over randomized query matrices).
 from __future__ import annotations
 
 import math
+import threading
 from dataclasses import dataclass
-from functools import partial
+from functools import lru_cache
 
 import jax
 import numpy as np
-from .. import config
+from .. import config, sched
 
 from ..logsql import filters as F
+from ..obs import tracing
 from ..storage.filterbank import bloom_keep_mask, filter_bank
 from ..storage.values_encoder import VT_DICT, VT_STRING
 from ..utils.hashing import cached_token_hashes
@@ -671,6 +673,124 @@ class _Planner:
         return ("ovfmaybe", oi)
 
 
+# ---------------- program names ----------------
+#
+# Every device program carries the name of what it scans, so a device
+# profile reads by program kind (`jit_fused_phrase__count/fusion`) and
+# not as one `jit__fused_dispatch`.  A name is a pure function of the
+# query's SHAPE: the leaf kinds of the planned tree and the reduction.
+# Never a literal, a size, a part or an id, so two parts of different
+# size run under one name and the vocabulary stays closed: a family, a
+# leaf part from _PRIMARY_LEAVES | _SECONDARY_LEAVES | {all, mixed},
+# and for the stats family a reduction from REDUCTIONS.
+
+_SCAN_MODE_NAMES = {K.MODE_PHRASE: "phrase", K.MODE_PREFIX: "prefix",
+                    K.MODE_SUBSTRING: "substr", K.MODE_EXACT: "exact",
+                    K.MODE_EXACT_PREFIX: "startswith"}
+# leaves that scan a staged column: they name the program
+_PRIMARY_LEAVES = ("phrase", "prefix", "substr", "exact", "startswith",
+                   "regex", "numrange", "lenrange")
+# per-row predicates over small planes: they name a program only when
+# it has no primary leaf
+_SECONDARY_LEAVES = ("time", "bloom", "mask", "empty", "ovf")
+_NODE_LEAF = {"pair": "regex", "numrange": "numrange",
+              "lenrange": "lenrange", "time": "time", "bloom": "bloom",
+              "bloom_sb": "bloom", "maskleaf": "mask",
+              "nonempty": "empty", "empty": "empty", "ovfmaybe": "ovf"}
+REDUCTIONS = ("count", "stats", "group", "bucket", "uniq", "quantile")
+FAMILIES = ("fused", "filter", "topk", "topk_seg")
+MAX_NAMED_LEAVES = 3
+
+
+def leaf_kind(node) -> str | None:
+    """The vocabulary word of one tree node; None for and/or/not and
+    the constants.  Also the node's named scope in the device profile."""
+    if node[0] == "scan":
+        return _SCAN_MODE_NAMES[node[7]]
+    return _NODE_LEAF.get(node[0])
+
+
+def _tree_leaves(node, out: list) -> None:
+    kind = leaf_kind(node)
+    if kind is not None:
+        out.append(kind)
+    elif node[0] == "not":
+        _tree_leaves(node[1], out)
+    elif node[0] in ("and", "or"):
+        for k in node[1]:
+            _tree_leaves(k, out)
+
+
+@lru_cache(maxsize=1024)
+def program_name(family: str, tree, reduction: str = "") -> str:
+    """`<family>_<leaves>[__<reduction>]`: the distinct primary leaf
+    kinds in vocabulary order (the secondary ones when there is no
+    primary leaf, `all` for a constant tree); a tree of more than
+    MAX_NAMED_LEAVES such leaves folds to `mixed`."""
+    leaves: list = []
+    _tree_leaves(tree, leaves)
+    for vocab in (_PRIMARY_LEAVES, _SECONDARY_LEAVES):
+        mine = [k for k in leaves if k in vocab]
+        if mine:
+            part = "mixed" if len(mine) > MAX_NAMED_LEAVES else \
+                "_".join(k for k in vocab if k in mine)
+            break
+    else:
+        part = "all"
+    name = f"{family}_{part}"
+    return f"{name}__{reduction}" if reduction else name
+
+
+def stats_reduction(spec, n_values: int) -> str:
+    """The reduction word of a fused stats dispatch, from the stats
+    spec's shape (the pack's segment axis is not a grouping)."""
+    if spec.uniq_fields:
+        return "uniq"
+    if spec.quantile_fields:
+        return "quantile"
+    by = [b.kind for b in spec.by if b.kind != "seg"]
+    if "time" in by:
+        return "bucket"
+    if by:
+        return "group"
+    return "stats" if n_values else "count"
+
+
+_programs: dict = {}
+_programs_mu = threading.Lock()
+
+
+def _program(name: str, body, jit):
+    """The one jitted callable of a program name: `body` under that
+    name, so the compiled module is `jit_<name>`.  Looked up on every
+    dispatch (a dict read); built once, under the lock.  Each callable
+    keeps the body's static keys, so what compiles is what compiled
+    under the single name: one program per (name, static key)."""
+    fn = _programs.get(name)
+    if fn is None:
+        with _programs_mu:
+            fn = _programs.get(name)
+            if fn is None:
+                def call(*args):
+                    return body(*args)
+                call.__name__ = call.__qualname__ = name
+                fn = _programs[name] = jit(call)
+    return fn
+
+
+def _launch(dispatch, *args):
+    """The jitted call, until it returns its async handles: the
+    `launch` child of the pipeline's `submit` span.  On a trace it
+    carries `device_queue_depth`: the scheduler's leased slots
+    (dispatch units submitted and not yet harvested, process-wide) at
+    this instant, this unit's own lease left out."""
+    with tracing.current_span().span("launch") as sp:
+        if sp.enabled:
+            sp.set("device_queue_depth",
+                   max(0, sched.scheduler().in_flight() - 1))
+        return dispatch(*args)
+
+
 # ---------------- the jitted program evaluator ----------------
 
 def _unpack_bits(packed, n):
@@ -680,7 +800,17 @@ def _unpack_bits(packed, n):
 
 
 def _eval_node(node, args, rlp):
-    """Recursive (definite, maybe) evaluation; maybe may be None (==0)."""
+    """Recursive (definite, maybe) evaluation; maybe may be None (==0).
+    Each leaf evaluates under a named scope of its kind (leaf_kind), so
+    the profile's op metadata says which leaf an operation belongs to."""
+    kind = leaf_kind(node)
+    if kind is None:
+        return _eval_tree_node(node, args, rlp)
+    with jax.named_scope(kind):
+        return _eval_tree_node(node, args, rlp)
+
+
+def _eval_tree_node(node, args, rlp):
     import jax.numpy as jnp
     kind = node[0]
     if kind == "true":
@@ -821,6 +951,31 @@ def _fused_local(prog, strides, nb, n_values, axis, nrows, cand_packed,
             idx = idx + jax.lax.axis_index(axis) * rl
         cand = idx < nrows
     d = d & cand
+    with jax.named_scope("stats"):
+        flat = _fused_reduce(strides, nb, n_values, axis, nseg,
+                             seg_pallas, seg_map, ids_tuple,
+                             values_tuple, d)
+    # the maybe-any flag rides INSIDE the stats download so the host can
+    # skip the packed-maybe transfer entirely in the common no-maybe case
+    if has_maybe and m is not None:
+        mc = m & cand
+        many = jnp.any(mc).astype(jnp.uint32)
+        if axis is not None:
+            many = jax.lax.psum(many, axis)    # nonzero iff any shard hit
+        mp = jnp.packbits(mc.astype(jnp.uint8))
+    else:
+        many = jnp.uint32(0)
+        mp = jnp.zeros(1, dtype=jnp.uint8)
+        if axis is not None:
+            mp = K._vary(mp, (axis,))
+    return jnp.concatenate([flat, many[None]]), mp
+
+
+def _fused_reduce(strides, nb, n_values, axis, nseg, seg_pallas, seg_map,
+                  ids_tuple, values_tuple, d):
+    """The stats reduction of _fused_local over the definite rows `d`:
+    the flat partials, count-only uint32[nb] or uint32[n_values*7*nb]."""
+    import jax.numpy as jnp
     vary = (axis,) if axis is not None else ()
     if nseg:
         from . import stats_seg as SS
@@ -878,26 +1033,13 @@ def _fused_local(prog, strides, nb, n_values, axis, nrows, cand_packed,
                 hi = jax.lax.pmax(hi, axis)
             outs.append(K.pack_stats(cnt, sums, lo, hi))
         flat = jnp.stack(outs, axis=0).reshape(-1)
-    # the maybe-any flag rides INSIDE the stats download so the host can
-    # skip the packed-maybe transfer entirely in the common no-maybe case
-    if has_maybe and m is not None:
-        mc = m & cand
-        many = jnp.any(mc).astype(jnp.uint32)
-        if axis is not None:
-            many = jax.lax.psum(many, axis)    # nonzero iff any shard hit
-        mp = jnp.packbits(mc.astype(jnp.uint8))
-    else:
-        many = jnp.uint32(0)
-        mp = jnp.zeros(1, dtype=jnp.uint8)
-        if axis is not None:
-            mp = K._vary(mp, (axis,))
-    return jnp.concatenate([flat, many[None]]), mp
+    return flat
 
 
-@partial(jax.jit, static_argnames=("prog", "strides", "nb", "n_values"))
 def _fused_dispatch(prog, strides, nb, n_values, nrows, cand_packed,
                     seg_map, ids_tuple, values_tuple, args):
     """One device call: filter tree -> stats partials (+ packed maybe).
+    Jitted under its program name by fused_program().
 
     prog: (tree, rlp, has_maybe, has_cand, arg_rows[, nseg,
     seg_pallas]) — static, hashable; arg_rows marks which leaf args are
@@ -917,8 +1059,11 @@ def _fused_dispatch(prog, strides, nb, n_values, nrows, cand_packed,
                         args)
 
 
-@partial(jax.jit, static_argnames=("prog", "strides", "nb", "n_values",
-                                   "mesh", "axis"))
+def fused_program(name: str):
+    return _program(name, _fused_dispatch, lambda fn: jax.jit(
+        fn, static_argnums=(0, 1, 2, 3)))
+
+
 def _fused_dispatch_mesh(mesh, axis, prog, strides, nb, n_values, nrows,
                          cand_packed, seg_map, ids_tuple, values_tuple,
                          args):
@@ -949,6 +1094,11 @@ def _fused_dispatch_mesh(mesh, axis, prog, strides, nb, n_values, nrows,
     return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=(P(), P(axis)))(
         nrows, cand_packed, seg_map, ids_tuple, values_tuple, args)
+
+
+def fused_mesh_program(name: str):
+    return _program(name + "_mesh", _fused_dispatch_mesh, lambda fn: jax.jit(
+        fn, static_argnums=(0, 1, 2, 3, 4, 5)))
 
 
 # ---------------- residue: host settles the maybe rows ----------------
@@ -1119,28 +1269,36 @@ def fused_stats_submit(runner, f, part, bss, spec, asm):
     layout = asm.layout
     if any(any(bi not in el for el in asm.eligibility) for bi in bss):
         return None
-    planner = _Planner(runner, part, bss, layout)
-    try:
-        tree = planner.plan(f)
-    except _NoFuse:
-        return None
+    # `args`: the host-side argument build (plan, staging lookups, the
+    # candidate mask and scalar puts); `launch` (_launch): the jitted
+    # call until it returns its async handles
+    with tracing.current_span().span("args"):
+        planner = _Planner(runner, part, bss, layout)
+        try:
+            tree = planner.plan(f)
+        except _NoFuse:
+            return None
 
-    handled = set(bss)
-    if tree == ("false",):
-        return _Ready(({}, handled, []))
+        handled = set(bss)
+        if tree == ("false",):
+            return _Ready(({}, handled, []))
 
-    cand_packed, has_cand = _stage_cand_mask(runner, part, bss, layout)
-    # prog slots 5/6: segment count of a packed super-dispatch and the
-    # VL_PALLAS gate for the seg-major count kernel — static, so the
-    # jitted program specializes per (pack size, gate) like every other
-    # static knob (stats_seg.py)
-    seg_pallas = bool(asm.nseg) and runner.pallas_enabled()
-    prog = (tree, layout.nrows_padded, planner.has_maybe, has_cand,
-            tuple(planner.arg_rows), asm.nseg, seg_pallas)
-    seg_map = runner._stage_seg_slots(part, layout).ids if asm.nseg \
-        else runner._stub((1, 1), np.int32)
-    values_tuple = tuple(asm.numerics[fld].values
-                         for fld in spec.value_fields)
+        cand_packed, has_cand = _stage_cand_mask(runner, part, bss,
+                                                 layout)
+        # prog slots 5/6: segment count of a packed super-dispatch and
+        # the VL_PALLAS gate for the seg-major count kernel — static, so
+        # the jitted program specializes per (pack size, gate) like
+        # every other static knob (stats_seg.py)
+        seg_pallas = bool(asm.nseg) and runner.pallas_enabled()
+        prog = (tree, layout.nrows_padded, planner.has_maybe, has_cand,
+                tuple(planner.arg_rows), asm.nseg, seg_pallas)
+        seg_map = runner._stage_seg_slots(part, layout).ids if asm.nseg \
+            else runner._stub((1, 1), np.int32)
+        values_tuple = tuple(asm.numerics[fld].values
+                             for fld in spec.value_fields)
+        name = program_name("fused", tree,
+                            stats_reduction(spec, len(values_tuple)))
+        nrows = jnp.int32(layout.nrows)
     runner._bump("device_calls")
     runner._bump("stats_dispatches")
     runner._bump("fused_dispatches")
@@ -1153,9 +1311,9 @@ def fused_stats_submit(runner, f, part, bss, spec, asm):
         runner._kind("fused_uniq")
     if spec.quantile_fields:
         runner._kind("fused_quantile")
-    flat, mp = runner._dispatch_fused(
-        prog, asm.strides, asm.nb, len(values_tuple),
-        jnp.int32(layout.nrows), cand_packed, seg_map, asm.ids_tuple,
+    flat, mp = _launch(
+        runner._dispatch_fused, name, prog, asm.strides, asm.nb,
+        len(values_tuple), nrows, cand_packed, seg_map, asm.ids_tuple,
         values_tuple, tuple(planner.args))
     return _StatsPending(runner, f, part, bss, spec, asm, handled, flat,
                          mp)
@@ -1165,7 +1323,6 @@ def fused_stats_submit(runner, f, part, bss, spec, asm):
 
 # ---------------- fused filter | sort-topk prefilter ----------------
 
-@partial(jax.jit, static_argnames=("prog", "k", "desc", "nseg"))
 def _topk_dispatch(prog, k, desc, nseg, nrows, cand_packed, seg_ids,
                    seg_map, values, args):
     """One device call: filter tree -> top-k threshold -> packed row sets.
@@ -1202,6 +1359,18 @@ def _topk_dispatch(prog, k, desc, nseg, nrows, cand_packed, seg_ids,
         cand = jnp.arange(rl, dtype=jnp.int32) < nrows
     d = d & cand
     mv = (m & cand) if (has_maybe and m is not None) else None
+    with jax.named_scope("topk"):
+        out_d, out_m = _topk_select(k, desc, nseg, seg_ids, seg_map,
+                                    values, d, mv)
+    return (jnp.packbits(out_d.astype(jnp.uint8)),
+            jnp.packbits(out_m.astype(jnp.uint8)))
+
+
+def _topk_select(k, desc, nseg, seg_ids, seg_map, values, d, mv):
+    """_topk_dispatch's reduction: (definite, maybe) rows at or above
+    the k-th best key among the definite matches `d`."""
+    import jax.numpy as jnp
+    rl = values.shape[0]
     v = values.astype(jnp.int32)
     if not desc:
         v = jnp.int32((1 << 31) - 2) - v   # ascending: reverse the order
@@ -1224,8 +1393,12 @@ def _topk_dispatch(prog, k, desc, nseg, nrows, cand_packed, seg_ids,
             out_m = mv & (v >= thr)
         else:
             out_m = jnp.zeros(rl, dtype=bool)
-    return (jnp.packbits(out_d.astype(jnp.uint8)),
-            jnp.packbits(out_m.astype(jnp.uint8)))
+    return out_d, out_m
+
+
+def topk_program(name: str):
+    return _program(name, _topk_dispatch, lambda fn: jax.jit(
+        fn, static_argnums=(0, 1, 2, 3)))
 
 
 def fused_topk_submit(runner, f, part, bss, spec):
@@ -1242,46 +1415,50 @@ def fused_topk_submit(runner, f, part, bss, spec):
     dispatch each."""
     import jax.numpy as jnp
     from .stats_device import MAX_ABS_TIMES_ROWS, MAX_STAT_ROWS
-    layout = runner._stats_layout(part)
-    if layout.nrows > MAX_STAT_ROWS:
-        return None
-    sn = runner._stage_numeric(part, spec.field, layout,
-                               MAX_ABS_TIMES_ROWS)
-    if sn is None or any(bi not in sn.eligible for bi in bss):
-        return None
-    if sn.vmax - sn.vmin > (1 << 31) - 2:
-        return None                # int32 score space
-    k = min(spec.k, layout.nrows_padded)
-    nseg = 0
-    seg_ids = runner._stub((1,), np.int32)
-    seg_map = runner._stub((1, 1), np.int32)
-    if getattr(part, "num_segments", 0) > 1:
-        sg = runner._stage_segments(part, layout)
-        if sg is None:
+    with tracing.current_span().span("args"):
+        layout = runner._stats_layout(part)
+        if layout.nrows > MAX_STAT_ROWS:
             return None
-        nseg = len(sg.values)
-        seg_ids = sg.ids
-        # the slot grid needs >= k slots per member for the batched
-        # k-selection (padding slots carry the -1 sentinel)
-        seg_map = runner._stage_seg_slots(part, layout, min_len=k).ids
-    planner = _Planner(runner, part, bss, layout)
-    try:
-        tree = planner.plan(f)
-    except _NoFuse:
-        return None
-    if tree == ("false",):
-        return _Ready({bi: np.zeros(bss[bi].nrows, dtype=bool)
-                       for bi in bss})
+        sn = runner._stage_numeric(part, spec.field, layout,
+                                   MAX_ABS_TIMES_ROWS)
+        if sn is None or any(bi not in sn.eligible for bi in bss):
+            return None
+        if sn.vmax - sn.vmin > (1 << 31) - 2:
+            return None                # int32 score space
+        k = min(spec.k, layout.nrows_padded)
+        nseg = 0
+        seg_ids = runner._stub((1,), np.int32)
+        seg_map = runner._stub((1, 1), np.int32)
+        if getattr(part, "num_segments", 0) > 1:
+            sg = runner._stage_segments(part, layout)
+            if sg is None:
+                return None
+            nseg = len(sg.values)
+            seg_ids = sg.ids
+            # the slot grid needs >= k slots per member for the batched
+            # k-selection (padding slots carry the -1 sentinel)
+            seg_map = runner._stage_seg_slots(part, layout, min_len=k).ids
+        planner = _Planner(runner, part, bss, layout)
+        try:
+            tree = planner.plan(f)
+        except _NoFuse:
+            return None
+        if tree == ("false",):
+            return _Ready({bi: np.zeros(bss[bi].nrows, dtype=bool)
+                           for bi in bss})
 
-    cand_packed, has_cand = _stage_cand_mask(runner, part, bss, layout)
-    prog = (tree, layout.nrows_padded, planner.has_maybe, has_cand,
-            tuple(planner.arg_rows))
+        cand_packed, has_cand = _stage_cand_mask(runner, part, bss,
+                                                 layout)
+        prog = (tree, layout.nrows_padded, planner.has_maybe, has_cand,
+                tuple(planner.arg_rows))
+        name = program_name("topk_seg" if nseg else "topk", tree)
+        nrows = jnp.int32(layout.nrows)
     runner._bump("device_calls")
     runner._bump("topk_dispatches")
     runner._kind("topk_seg" if nseg else "topk")
-    dm, mm = runner._dispatch_topk(
-        prog, k, spec.desc, nseg, jnp.int32(layout.nrows), cand_packed,
-        seg_ids, seg_map, sn.values, tuple(planner.args))
+    dm, mm = _launch(
+        runner._dispatch_topk, name, prog, k, spec.desc, nseg, nrows,
+        cand_packed, seg_ids, seg_map, sn.values, tuple(planner.args))
     # the maybe vector is only meaningful when the program proved maybe
     # rows can exist; _FilterPending's harvest applies the same residue
     # discipline as the fused stats/filter paths
@@ -1326,7 +1503,6 @@ def _filter_local(prog, axis, nrows, cand_packed, args, rl):
     return jnp.packbits(d.astype(jnp.uint8)), mp
 
 
-@partial(jax.jit, static_argnames=("prog",))
 def _filter_dispatch(prog, nrows, cand_packed, args):
     """One device call: the WHOLE filter tree -> bit-packed (definite,
     maybe) row vectors — the row-query analogue of _fused_dispatch.
@@ -1341,7 +1517,11 @@ def _filter_dispatch(prog, nrows, cand_packed, args):
     return _filter_local(prog, None, nrows, cand_packed, args, prog[1])
 
 
-@partial(jax.jit, static_argnames=("prog", "mesh", "axis"))
+def filter_program(name: str):
+    return _program(name, _filter_dispatch, lambda fn: jax.jit(
+        fn, static_argnums=(0,)))
+
+
 def _filter_dispatch_mesh(mesh, axis, prog, nrows, cand_packed, args):
     """The filter-only program under shard_map: each device evaluates
     its row stripe, the packed (definite, maybe) vectors concatenate
@@ -1361,6 +1541,11 @@ def _filter_dispatch_mesh(mesh, axis, prog, nrows, cand_packed, args):
     return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=(P(axis), P(axis)))(
         nrows, cand_packed, args)
+
+
+def filter_mesh_program(name: str):
+    return _program(name + "_mesh", _filter_dispatch_mesh, lambda fn: jax.jit(
+        fn, static_argnums=(0, 1, 2)))
 
 
 class _FilterPending:
@@ -1424,27 +1609,31 @@ def fused_filter_submit(runner, f, part, bss):
     from .stats_device import MAX_STAT_ROWS
     if not fused_filter_enabled():
         return None
-    layout = runner._stats_layout(part)
-    if layout.nrows > MAX_STAT_ROWS:
-        return None
-    planner = _Planner(runner, part, bss, layout)
-    try:
-        tree = planner.plan(f)
-    except _NoFuse:
-        return None
-    if tree == ("false",):
-        return _Ready({bi: np.zeros(bss[bi].nrows, dtype=bool)
-                       for bi in bss})
-    if tree == ("true",):
-        return _Ready({bi: np.ones(bss[bi].nrows, dtype=bool)
-                       for bi in bss})
-    cand_packed, has_cand = _stage_cand_mask(runner, part, bss, layout)
-    prog = (tree, layout.nrows_padded, planner.has_maybe, has_cand,
-            tuple(planner.arg_rows))
+    with tracing.current_span().span("args"):
+        layout = runner._stats_layout(part)
+        if layout.nrows > MAX_STAT_ROWS:
+            return None
+        planner = _Planner(runner, part, bss, layout)
+        try:
+            tree = planner.plan(f)
+        except _NoFuse:
+            return None
+        if tree == ("false",):
+            return _Ready({bi: np.zeros(bss[bi].nrows, dtype=bool)
+                           for bi in bss})
+        if tree == ("true",):
+            return _Ready({bi: np.ones(bss[bi].nrows, dtype=bool)
+                           for bi in bss})
+        cand_packed, has_cand = _stage_cand_mask(runner, part, bss,
+                                                 layout)
+        prog = (tree, layout.nrows_padded, planner.has_maybe, has_cand,
+                tuple(planner.arg_rows))
+        name = program_name("filter", tree)
+        nrows = jnp.int32(layout.nrows)
     runner._bump("device_calls")
     runner._bump("filter_dispatches")
     runner._kind("fused_filter")
-    dm, mm = runner._dispatch_filter(prog, jnp.int32(layout.nrows),
-                                     cand_packed, tuple(planner.args))
+    dm, mm = _launch(runner._dispatch_filter, name, prog, nrows,
+                     cand_packed, tuple(planner.args))
     return _FilterPending(runner, f, part, bss, layout, dm, mm,
                           planner.has_maybe)

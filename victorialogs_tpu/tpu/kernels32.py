@@ -134,6 +134,7 @@ def _shifted(ext: jnp.ndarray, a: int, n: int) -> jnp.ndarray:
 
 @partial(jax.jit, static_argnames=("pat_len", "mode", "starts_tok",
                                    "ends_tok", "fold"))
+@jax.named_scope("match_scan")
 def match_scan_t(lanes_t: jnp.ndarray, lengths: jnp.ndarray,
                  pattern: jnp.ndarray, pat_len: int, mode: int,
                  starts_tok: bool, ends_tok: bool,
@@ -240,6 +241,7 @@ def _window_hits(ext: jnp.ndarray, nl: int, pattern: jnp.ndarray,
 
 
 @partial(jax.jit, static_argnames=("len_a", "len_b"))
+@jax.named_scope("match_pair")
 def match_ordered_pair_t(lanes_t: jnp.ndarray, lengths: jnp.ndarray,
                          pat_a: jnp.ndarray, len_a: int,
                          pat_b: jnp.ndarray, len_b: int):
