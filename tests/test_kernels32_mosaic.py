@@ -1,0 +1,125 @@
+"""The plane kernels compiled for the chip, without the chip.
+
+The TPU's compiler is installed here and compiles for a v5e that is
+described, not attached: what Mosaic refuses (a slice off the tiling, a
+loop state it cannot carry, too much VMEM) it refuses in these tests,
+where interpret mode (tests/test_kernels32.py) passes.  Nothing runs, so
+nothing here is a result or a time.  The topology is described inside a
+fixture of THIS file only: the process that describes it holds the TPU
+library until it exits (on-chip-measurement guide, section 2).
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from victorialogs_tpu.tpu import kernels as K
+from victorialogs_tpu.tpu import kernels32 as K32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described device's programs cannot be read back from the cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def as_on_the_chip(monkeypatch):
+    """The launcher asks for the backend; the chip's process sees tpu."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _shapes(width, rows, sharding, lens_sharding=None):
+    return (jax.ShapeDtypeStruct((width // 4, rows // 128, 128),
+                                 jnp.uint32, sharding=sharding),
+            jax.ShapeDtypeStruct((rows,), jnp.int32,
+                                 sharding=lens_sharding or sharding))
+
+
+SCANS = [(17, K.MODE_PHRASE, True, True, False),
+         (4, K.MODE_PREFIX, True, False, False),
+         (8, K.MODE_SUBSTRING, False, False, False),
+         (17, K.MODE_PHRASE, True, True, True),
+         (9, K.MODE_EXACT, False, False, False),
+         (64, K.MODE_PHRASE, True, True, False)]
+
+
+# the benchmark's `_msg` (W=128, parts of millions of rows), the
+# narrowest and the widest column, and the smallest row bucket
+@pytest.mark.parametrize("width,rows", [(128, 1 << 21), (32, 1 << 21),
+                                        (2048, 1 << 16), (64, 1024)])
+def test_every_leaf_kind_compiles_through_mosaic(topo, as_on_the_chip,
+                                                 width, rows):
+    one = SingleDeviceSharding(topo.devices[0])
+    lanes, lens = _shapes(width, rows, one)
+    for pat_len, mode, st, et, fold in SCANS:
+        if pat_len > width - 1:
+            continue
+        pat = jax.ShapeDtypeStruct((pat_len,), jnp.uint8, sharding=one)
+        text = K32.match_scan_t.lower(
+            lanes, lens, pat, pat_len, mode, st, et, fold
+        ).compile().as_text()
+        assert text.count("tpu_custom_call") == 1, (pat_len, mode)
+    pa = jax.ShapeDtypeStruct((4,), jnp.uint8, sharding=one)
+    pb = jax.ShapeDtypeStruct((8,), jnp.uint8, sharding=one)
+    text = K32.match_ordered_pair_t.lower(lanes, lens, pa, 4, pb,
+                                          8).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    for op in ("reverse(", " sort("):
+        assert op not in text, op
+
+
+def test_a_stripe_under_a_mesh_axis_runs_the_body_directly(
+        topo, as_on_the_chip):
+    """MeshBatchRunner's fused programs: under shard_map the launcher
+    must leave Mosaic out (the body is plain XLA a device)."""
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("blocks",))
+    lanes, lens = _shapes(128, 1 << 20,
+                          NamedSharding(mesh, P(None, "blocks")),
+                          NamedSharding(mesh, P("blocks")))
+    pat = jax.ShapeDtypeStruct((17,), jnp.uint8,
+                               sharding=NamedSharding(mesh, P()))
+
+    def local(l, n, p):
+        hit = K32.match_scan_t(l, n, p, 17, K.MODE_PHRASE, True, True)
+        d, v = K32.match_ordered_pair_t(l, n, p[:4], 4, p[4:12], 8)
+        return jax.lax.psum(jnp.sum(hit & ~d | v, dtype=jnp.int32),
+                            "blocks")
+
+    text = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(P(None, "blocks"), P("blocks"), P()),
+        out_specs=P())).lower(lanes, lens, pat).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert "all-reduce" in text
+
+
+def test_a_column_xla_partitions_is_told_apart(topo, as_on_the_chip):
+    """The unfused mesh path hands the jitted scan a column striped
+    over devices: Mosaic cannot be partitioned, so the packed entry
+    points look at the placed array and ask for the direct launcher."""
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("blocks",))
+    lanes, lens = _shapes(128, 1 << 20,
+                          NamedSharding(mesh, P(None, "blocks")),
+                          NamedSharding(mesh, P("blocks")))
+    pat = jax.ShapeDtypeStruct((17,), jnp.uint8,
+                               sharding=NamedSharding(mesh, P()))
+    args = (lanes, lens, pat, 17, K.MODE_PHRASE, True, True, False)
+    text = K32._scan_packed.lower(*args, True).compile().as_text()
+    assert "tpu_custom_call" not in text
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        K32._scan_packed.lower(*args, False)

@@ -388,6 +388,29 @@ def test_h2d_bytes_count_once_an_array(served):
     assert re.search(rb"^vl_tpu_h2d_bytes_total \d+$", text, re.M)
 
 
+@pytest.mark.parametrize("query,leaves", [
+    ("error | stats count() c", 1),
+    ('error OR "dead beef" | stats count() c', 2),
+    ('_msg:~"dead.*beef" NOT ok | stats count() c', 2),
+    ("* | stats count_uniq(app) u", 0),
+])
+def test_plane_scan_leaves_counted_where_the_dispatch_is_issued(
+        served, query, leaves):
+    """vl_tpu_plane_scan_leaves: the scan and `A.*B` leaves of every
+    dispatch, bumped on the host beside device_calls."""
+    srv, storage, runner = served
+    before = runner.stats()
+    run_query_collect(storage, [TEN], query, runner=runner)
+    after = runner.stats()
+    calls = after["device_calls"] - before["device_calls"]
+    assert calls > 0
+    assert after["plane_scan_leaves"] - before["plane_scan_leaves"] \
+        == leaves * calls
+    status, text = _req(srv, "/metrics")
+    assert status == 200
+    assert re.search(rb"^vl_tpu_plane_scan_leaves \d+$", text, re.M)
+
+
 @pytest.fixture
 def stall_lines():
     lines = []

@@ -211,7 +211,8 @@ class MeshBatchRunner(BatchRunner):
         # shard the row axis when it divides evenly (stats layouts always
         # do; string-staging row buckets do for power-of-two mesh sizes),
         # else replicate — correctness never depends on the placement.
-        # row_axis=1: lane-major uint32[W/4, R] string staging.
+        # row_axis=1: the uint32[W/4, R/128, 128] planes of the string
+        # staging, striped by whole rows of 128.
         striped = arr.shape[row_axis] % self.ndev == 0
         # a replicated array is handed to every device
         self._bump("h2d_bytes_total",
@@ -221,7 +222,8 @@ class MeshBatchRunner(BatchRunner):
                 return jax.device_put(arr, self._row_sharding)
             return jax.device_put(
                 arr, NamedSharding(self.mesh, P(None, BLOCK_AXIS)))
-        # never on a power-of-two mesh (row buckets are 128-multiples);
+        # never on a power-of-two mesh of up to eight devices (row
+        # buckets are 1024-multiples, eight rows of 128 a plane);
         # counted so a mesh that does replicate its rows says so on
         # /metrics instead of quietly holding ndev copies
         self._bump("replicated_row_puts")

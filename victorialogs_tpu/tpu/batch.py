@@ -242,7 +242,7 @@ def _contains_plan(f, require_all: bool) -> LeafPlan | None:
 
 @dataclass
 class StagedPart:
-    rows: object                   # jax uint32[W/4, Rb] lane-major (kernels32)
+    rows: object                   # jax uint32[W/4, Rb/128, 128] planes (kernels32)
     lengths: object                # jax int32[Rb]
     lengths_np: np.ndarray         # host copy (truncated at W-1)
     nrows: int                     # real staged rows
@@ -308,7 +308,9 @@ def stage_part_column(part, field: str,
     if not cols:
         return None
     w = row_width_bucket(max_len)
-    rb = pad_bucket(max(total, 1), minimum=1024)
+    # whole (8, 128) tiles of rows a plane (layout.to_lanes32), which also
+    # stripe over a mesh of up to eight devices
+    rb = -(-pad_bucket(max(total, 1), minimum=1024) // 1024) * 1024
     if rb * (w + 4) > max_bytes:
         return None
     mat = np.full((rb, w), 0xFF, dtype=np.uint8)
@@ -978,6 +980,9 @@ class BatchRunner:
         self.cost = CostModel()
         self._scan_sigs: set = set()   # jit signatures already compiled
         self.device_calls = 0          # every dispatch issued to the device
+        self.plane_scan_leaves = 0     # scan and `A.*B` leaves those
+        #                                dispatches ran through the plane
+        #                                kernel (tpu/kernels32.py)
         self.cpu_fallbacks = 0
         self.gated_host_parts = 0
         self.stats_dispatches = 0
@@ -1061,6 +1066,7 @@ class BatchRunner:
         with self._counter_mu:
             out = {
                 "device_calls": self.device_calls,
+                "plane_scan_leaves": self.plane_scan_leaves,
                 "cpu_fallbacks": self.cpu_fallbacks,
                 "gated_host_parts": self.gated_host_parts,
                 "stats_dispatches": self.stats_dispatches,
@@ -2091,6 +2097,7 @@ class BatchRunner:
         if max(len(a), len(b)) >= spc.width:
             return np.zeros(spc.nrows, dtype=bool), None
         self._bump("device_calls")
+        self._bump("plane_scan_leaves")
         self._kind("scan_pair")
         # vlint: allow-jax-host-sync(bit-packed survivor download)
         packed = np.array(K32.match_ordered_pair_t_packed(
@@ -2130,6 +2137,7 @@ class BatchRunner:
             # re-checked from the full values by the caller
             return np.zeros(spc.nrows, dtype=bool)
         self._bump("device_calls")
+        self._bump("plane_scan_leaves")
         self._kind(f"scan:m{op.mode}" + (":fold" if op.fold else ""))
         import time
         # calls of a not-yet-compiled jit signature pay (or block on a

@@ -68,7 +68,7 @@ from .layout import (row_width_bucket, rows_with_multibyte, to_fixed_width,
 @dataclass
 class FusedField:
     """One column staged over EVERY block of a part, layout coords."""
-    rows: object                   # jax uint32[W/4, RLp] lane-major
+    rows: object                   # jax uint32[W/4, RLp/128, 128] planes
     lengths: object                # jax int32[RLp]
     width: int
     ovf_packed: object | None      # jax uint8[RLp//8] bit-packed overflow
@@ -292,8 +292,8 @@ class _Planner:
         """Register a dynamic input; row marks row-aligned arrays that a
         mesh dispatch shards — recorded explicitly so sharding never
         relies on shape coincidences.  row=1 (or True): the row axis is
-        axis 0 (RLp or RLp/8 leading dim); row=2: axis 1 (the lane-major
-        uint32[W/4, RLp] string staging)."""
+        axis 0 (RLp or RLp/8 leading dim); row=2: axis 1 (the
+        uint32[W/4, RLp/128, 128] planes of the string staging)."""
         self.args.append(a)
         self.arg_rows.append(int(row))
         return len(self.args) - 1
@@ -739,6 +739,16 @@ def program_name(family: str, tree, reduction: str = "") -> str:
         part = "all"
     name = f"{family}_{part}"
     return f"{name}__{reduction}" if reduction else name
+
+
+@lru_cache(maxsize=1024)
+def plane_scan_leaves(tree) -> int:
+    """How many of the tree's leaves run the plane kernel
+    (tpu/kernels32.py): the window scans and the `A.*B` pairs."""
+    leaves: list = []
+    _tree_leaves(tree, leaves)
+    return sum(k == "regex" or k in _SCAN_MODE_NAMES.values()
+               for k in leaves)
 
 
 def stats_reduction(spec, n_values: int) -> str:
@@ -1300,6 +1310,7 @@ def fused_stats_submit(runner, f, part, bss, spec, asm):
                             stats_reduction(spec, len(values_tuple)))
         nrows = jnp.int32(layout.nrows)
     runner._bump("device_calls")
+    runner._bump("plane_scan_leaves", plane_scan_leaves(tree))
     runner._bump("stats_dispatches")
     runner._bump("fused_dispatches")
     runner._bump_max("stats_onehot_width",
@@ -1454,6 +1465,7 @@ def fused_topk_submit(runner, f, part, bss, spec):
         name = program_name("topk_seg" if nseg else "topk", tree)
         nrows = jnp.int32(layout.nrows)
     runner._bump("device_calls")
+    runner._bump("plane_scan_leaves", plane_scan_leaves(tree))
     runner._bump("topk_dispatches")
     runner._kind("topk_seg" if nseg else "topk")
     dm, mm = _launch(
@@ -1631,6 +1643,7 @@ def fused_filter_submit(runner, f, part, bss):
         name = program_name("filter", tree)
         nrows = jnp.int32(layout.nrows)
     runner._bump("device_calls")
+    runner._bump("plane_scan_leaves", plane_scan_leaves(tree))
     runner._bump("filter_dispatches")
     runner._kind("fused_filter")
     dm, mm = _launch(runner._dispatch_filter, name, prog, nrows,

@@ -1,35 +1,52 @@
-"""u32-lane scan kernels: the bandwidth-efficient device string scan.
+"""The device string scan: one sweep over a staged column's planes.
 
-The round-3 kernel (kernels.match_scan) tested every window offset with
-`pat_len` byte-plane compares over a uint8[R, W] matrix.  On TPU every
-uint8 lane occupies a full 32-bit VPU lane, so that design pays
-~2*pat_len lane-ops per byte scanned — measured at ~6% of v5e HBM
-bandwidth (PERF.md round-3 dissection).  This module is the round-4
-rewrite; the same semantics (bit-identical vs logsql.matchers and
-kernels.match_scan, which stays as the oracle) at ~4-8x fewer lane-ops:
+Semantics are bit-identical to logsql.matchers and to kernels.match_scan
+/ match_ordered_pair (the u8 kernels, which stay as the oracle of
+tests/test_kernels32.py): word and phrase match at filter_phrase.go:61-111,
+filter_exact.go, filter_prefix.go, the tokenizer's word table at
+tokenizer.go:34-148.  Answers are exact; nothing is sampled or skipped.
 
-- **u32 chunks**: the staged column is a uint32[W/4, R] matrix (4 bytes
-  per lane, transposed so the ROW axis rides the 128-wide lane
-  dimension and is shardable over a mesh).  A pattern compare tests 4
-  bytes per lane-op: window starts split by alignment a in 0..3, and a
-  window at s=4q+a matches iff ceil(pat_len/4) masked u32 compares hit.
-- **SWAR byte predicates**: word-char table, ASCII case fold and
-  newline detection run as parallel-per-byte bit tricks on u32 lanes
-  (4 bytes/lane-op) instead of byte-plane compares.
-- **exact/exact-prefix collapse**: whole-value equality only inspects
-  window 0 — ceil(L/4) compares on (R,) vectors, no window matrix.
+Layout contract (tpu/layout.py to_lanes32).  A staged column is
+uint32[W/4, R/128, 128]: planes[q, r // 128, r % 128] is the
+little-endian word of bytes 4q..4q+3 of row r.  Plane q, word q of every
+row, is one (R/128, 128) array whose rows fill the sublanes AND the
+lanes of the vector registers; q is a leading, untiled index, so reading
+a plane is an address and never a relayout.  W is a multiple of 4, R of
+1024 (whole (8, 128) tiles a plane); the mesh stripes axis 1.  Tail
+padding is 0xFF: never valid UTF-8 and not a word char, so a window
+that runs into it cannot match; a value is cut at W-1 bytes (longer
+ones are re-checked on the host), so the last byte of a row is padding.
+Patterns hold no 0xFF byte (the planner takes ASCII only).  Pattern
+chunk words are built with the SAME in-trace bitcast as the data, so
+their byte order agrees on any backend; the byte shifts assume a
+little-endian target (x86-64 and the TPU are; a test asserts it).
 
-Layout contract (tpu/layout.py to_lanes32): lanes_t[q, r] is the
-little-endian uint32 of bytes rows[r, 4q:4q+4]; tail padding is 0xFF
-(never valid UTF-8, so padded windows cannot match and 0xFF is not a
-word char).  Pattern chunk constants are built with the SAME in-trace
-bitcast as the data, so data/pattern byte order always agree; the
-byte-shift helpers assume a little-endian target (every XLA backend we
-run — CPU x86-64, TPU — is little-endian; tests assert it).
+The sweep.  A window at byte 4q+a matches iff its ceil(pat_len/4) chunk
+words equal the words at alignment a of planes q, q+1, ...; the word at
+alignment a of plane q is (p[q] >> 8a) | (p[q+1] << (32-8a)), plane to
+plane.  One loop over q reads each plane ONCE, derives its aligned words
+and its word-char mask (SWAR: four bytes a lane-op) once, carries them
+for the few steps that use them, and keeps per-row state only: one hit
+word (phrase, prefix, substring, folded or not), or the first A as a
+running minimum and the last B as a running maximum plus the newline OR
+(`A.*B`).  No [planes, rows] intermediate exists and nothing is written
+but the per-row result.  MODE_EXACT / MODE_EXACT_PREFIX look at window 0
+only.
 
-Reference semantics anchored at filter_phrase.go:61-111 (word/phrase
-match), filter_exact.go, filter_prefix.go; the tokenizer word table at
-tokenizer.go:34-148.
+Two launchers, one body (_launch).  On the TPU a Pallas call tiles the
+rows through VMEM blocks and runs the body a register tile at a time; on
+every other backend, and for a stripe under a mesh axis or a column
+XLA partitions over devices, the body runs directly as jax.numpy over
+the whole column.  The choice follows what the code can observe, never
+an environment switch.
+
+Measured on a v5e, R = 2M, W = 128 (tools/bench_kernels32.py; my chip
+run, PR 27), ns a row and share of 819 GB/s: phrase (17 B, both token
+boundaries) 0.81 / 20%, prefix 0.44 / 37%, substring 0.38 / 43%, folded
+phrase 0.98 / 17%, `A.*B` pair 0.40 / 40%; the sublane formulation this
+replaced read 7.75, 2.37, 3.37, 8.14 and 9.47 ns a row.  Every kind is
+bound by its lane-ops, not by memory: a plain read of the column takes
+0.21 ns a row.
 """
 
 from __future__ import annotations
@@ -46,8 +63,10 @@ from .kernels import (MODE_EXACT, MODE_EXACT_PREFIX, MODE_PHRASE,
 _U32 = jnp.uint32
 
 
-def _c(v: int) -> jnp.ndarray:
-    return _U32(v & 0xFFFFFFFF)
+def _c(v: int) -> np.uint32:
+    """A u32 constant of the trace: a numpy scalar, which costs the
+    tracer nothing (a jnp scalar is a placed array each time)."""
+    return np.uint32(v & 0xFFFFFFFF)
 
 
 # ---------------- SWAR byte predicates on u32 lanes ----------------
@@ -104,7 +123,7 @@ def any_byte_eq(x: jnp.ndarray, byte: int) -> jnp.ndarray:
 # ---------------- pattern chunking ----------------
 
 def _pattern_chunks(pattern: jnp.ndarray, pat_len: int):
-    """(chunk u32[nc], static mask ints): chunk c covers pattern bytes
+    """(chunks u32[nc], static mask ints): chunk c covers pattern bytes
     [4c, 4c+4); the last chunk's mask zeroes bytes past pat_len.  Built
     with the same bitcast the data layout uses, so byte order agrees on
     any backend."""
@@ -119,173 +138,322 @@ def _pattern_chunks(pattern: jnp.ndarray, pat_len: int):
     if rem:
         mb = np.array([0xFF] * rem + [0] * (4 - rem), dtype=np.uint8)
         masks[-1] = int(mb.view("<u4")[0])
-    return pc, masks, nc
+    return pc, masks
 
 
-def _shifted(ext: jnp.ndarray, a: int, n: int) -> jnp.ndarray:
-    """u32 at byte offset 4q+a for lane rows q in [0, n): little-endian
-    combine of ext[q] and ext[q+1].  ext: u32[>=n+1, R]."""
-    if a == 0:
-        return ext[:n]
-    return (ext[:n] >> _U32(8 * a)) | (ext[1:n + 1] << _U32(32 - 8 * a))
+# ---------------- planes ----------------
+#
+# A plane is word q of every row of a tile, a (rows/128, 128) array: the
+# rows fill the sublanes and the lanes of the vector registers, and q
+# indexes the column's leading, untiled axis, so reading a plane is an
+# address and never a relayout.  A scan is ONE sweep over the planes
+# that keeps only per-row state: the aligned words and word masks of
+# the few planes a window spans, and what the question asks of each row.
+
+_PAD = 0xFFFFFFFF
+
+
+def _aligned4(p, nxt):
+    """The u32 at byte offsets 0..3 of plane p: the little-endian
+    combine with the next plane, plane to plane, never stored."""
+    return (p,) + tuple((p >> _c(8 * a)) | (nxt << _c(32 - 8 * a))
+                        for a in (1, 2, 3))
+
+
+def _window_eq(al, a: int, pc, c0: int, masks):
+    """Whether the window at byte a of the sweep's plane equals the
+    pattern: its chunk c, pc[c0 + c], against al[c][a], the aligned
+    word c planes on."""
+    acc = None
+    for c, m in enumerate(masks):
+        # a short last chunk: its pattern word is zero past pat_len
+        t = (al[c][a] == pc[c0 + c]) if m == _PAD else \
+            ((al[c][a] & _c(m)) == pc[c0 + c])
+        acc = t if acc is None else acc & t
+    return acc
+
+
+def _sweep(load, nl: int, look: int, steps: int, fold: bool, words: bool,
+           visit, state):
+    """state = visit(q, al, wm, state) for q = 0 .. steps-1, in order.
+
+    al[c] = _aligned4 of plane q+c for c < look (the planes a window of
+    `look` chunks spans); wm[i] = the word mask of plane q-1+i for
+    i < look+2 (None unless `words`).  load(q) reads plane q of the
+    tile (q an int or a traced index).  Planes outside the column are
+    0xFF padding (not a word char, never equal to a pattern byte), so
+    the byte before a string and the bytes past its width need no
+    special case.  The sweep rolls: a step reads ONE new plane, q+look,
+    and derives its aligned words and word mask once; the loop carries
+    them for the `look` steps that use them.  Needs look <= nl."""
+    def plane(q):
+        if isinstance(q, int):
+            p = load(q)
+        else:
+            # only the sweep's last steps reach past the column
+            p = load(jnp.minimum(q, nl - 1)) \
+                | jnp.where(q < nl, _c(0), _c(_PAD))
+        return fold_ascii32(p) if fold else p
+
+    raw = [plane(q) for q in range(look)]
+    al = [_aligned4(raw[c], raw[c + 1]) for c in range(look - 1)]
+    # before the string: the rows' shape (and sharding), no word char
+    wm = [raw[0] & _c(0)] + [word_hibits(p) for p in raw] if words else None
+
+    def step(q, carry):
+        last, al, wm, state = carry
+        new = plane(q + look)
+        al = al + [_aligned4(last, new)]
+        if words:
+            wm = wm + [word_hibits(new)]
+        state = visit(q, al, wm, state)
+        return new, al[1:], wm[1:] if words else None, state
+
+    return jax.lax.fori_loop(0, steps, step,
+                             (raw[-1], al, wm, state))[3]
+
+
+# ---------------- the two bodies: one tile of rows ----------------
+
+def _scan_rows(load, nl: int, lens, pc, masks, pat_len: int, mode: int,
+               need_start: bool, need_end: bool, fold: bool):
+    """match_scan_t over one tile of rows: load(q) is word q of each,
+    lens their lengths, pc the pattern's chunk words (indexable)."""
+    nc = len(masks)
+    if mode in (MODE_EXACT, MODE_EXACT_PREFIX):
+        # window 0 only: the first chunks of each row
+        al = [(fold_ascii32(load(c)) if fold else load(c),)
+              for c in range(nc)]
+        hit = _window_eq(al, 0, pc, 0, masks)
+        return hit & ((lens == pat_len) if mode == MODE_EXACT
+                      else (lens >= pat_len))
+
+    m, r = divmod(pat_len, 4)
+
+    def visit(q, al, wm, hit):
+        """OR the windows that start in plane q into one word a row."""
+        # hi bit of byte a set: the window at byte a has a word char
+        # right before it (byte a-1 of plane q, byte 3 of plane q-1) or
+        # right after it (byte a+r of plane q+m, running into q+m+1)
+        edge = None
+        if need_start:
+            edge = (wm[1] << _c(8)) | (wm[0] >> _c(24))
+        if need_end:
+            nxt = wm[m + 1]
+            if r:
+                nxt = (nxt >> _c(8 * r)) | (wm[m + 2] << _c(32 - 8 * r))
+            edge = nxt if edge is None else edge | nxt
+        any_a = None
+        for a in range(4):
+            acc = _window_eq(al, a, pc, 0, masks)
+            if edge is not None:
+                acc = acc & ((edge & _c(0x80 << (8 * a))) == 0)
+            any_a = acc if any_a is None else any_a | acc
+        # a word a row: Mosaic loops carry no booleans
+        return hit | any_a.astype(jnp.int32)
+
+    # the last plane a window can start in, and still lie inside the
+    # column, is nl - nc: past it every window runs into the padding
+    hit = _sweep(load, nl, nc, nl - nc + 1, fold,
+                 need_start or need_end, visit, lens & 0)
+    return (hit != 0) & (lens >= pat_len)
+
+
+def _pair_rows(load, nl: int, lens, pc, masks_a, masks_b, len_a: int,
+               len_b: int):
+    """match_ordered_pair_t over one tile of rows: the first window
+    that equals A as a running minimum, the last that equals B as a
+    running maximum (byte offsets), the newline bytes OR-ed.  pc holds
+    A's chunk words, then B's."""
+    nowhere = np.int32(4 * nl)         # past every window start
+    nca, ncb = len(masks_a), len(masks_b)
+
+    def visit(q, al, _wm, state):
+        first_a, last_b, newline = state
+        newline = newline | any_byte_eq(al[0][0], 0x0A)
+        for a in range(4):
+            pos = 4 * q + np.int32(a)
+            first_a = jnp.minimum(first_a, jnp.where(
+                _window_eq(al, a, pc, 0, masks_a), pos, nowhere))
+            # offsets ascend: the last hit is the latest write
+            last_b = jnp.where(_window_eq(al, a, pc, nca, masks_b), pos,
+                               last_b)
+        return first_a, last_b, newline
+
+    # sweeps every plane (a newline may sit in the last); windows that
+    # run past the column compare against the padding
+    zero = lens & 0
+    first_a, last_b, newline = _sweep(
+        load, nl, max(nca, ncb), nl, False, False, visit,
+        (zero + nowhere, zero - 1, zero.astype(_U32)))
+    ordered = (first_a < nowhere) & (last_b >= 0) \
+        & (lens >= max(len_a, len_b)) & (first_a + len_a <= last_b)
+    has_nl = newline != 0
+    return ordered & ~has_nl, ordered & has_nl
+
+
+# ---------------- the two launchers ----------------
+
+_SUBLANES = 8               # rows of 128 in one vector register
+# rows of 128 a sweep holds a value of: two registers a value measured
+# best on the v5e for every kind but the plain substring (PERF.md §6)
+_SWEEP_ROWS = 2 * _SUBLANES
+_BLOCK_BYTES = 2 << 20      # of a column, in VMEM at a time
+
+
+def _on_tpu_alone(lanes_t) -> bool:
+    """Whether the Pallas launcher serves this column: the TPU backend,
+    whole register tiles of rows, and not a stripe under a mesh axis
+    (there XLA's own partitioning runs the body)."""
+    return (jax.default_backend() == "tpu"
+            and not jax.typeof(lanes_t).vma
+            and lanes_t.shape[1] % _SUBLANES == 0)
+
+
+def _launch(body, nout: int, lanes_t, lengths, pc, spmd: bool = False):
+    """body(load, lens, pc) -> nout bool arrays over a tile of rows;
+    returns them over every row, bool[R] each.  On the TPU the tiles
+    are register-sized slices of VMEM blocks (Pallas); elsewhere, and
+    for a column striped over devices (spmd), the tile is the column."""
+    lens = lengths.reshape(lanes_t.shape[1:])
+    if spmd or not _on_tpu_alone(lanes_t):
+        out = body(lambda q: jax.lax.dynamic_index_in_dim(
+            lanes_t, q, 0, keepdims=False), lens, pc)
+    else:
+        code = _launch_pallas(body, lanes_t, lens, pc)
+        out = [(code >> i) & 1 != 0 for i in range(nout)]
+    return [o.reshape(-1) for o in out]
+
+
+def _launch_pallas(body, lanes_t, lens, pc, interpret: bool = False):
+    """The body over (W/4, TS, 128) blocks of rows, _SWEEP_ROWS x 128
+    rows at a time; int32[R/128, 128], bit i = the body's i-th
+    result."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    nl, s, lanes = lanes_t.shape
+    ts = next(t for t in (256, 128, 64, 32, 16, _SUBLANES)
+              if s % t == 0 and (t == _SUBLANES
+                                 or nl * t * lanes * 4 <= _BLOCK_BYTES))
+    sub = min(ts, _SWEEP_ROWS)
+
+    def kernel(pc_ref, lanes_ref, lens_ref, out_ref):
+        def tile(t, carry):
+            rows = pl.ds(pl.multiple_of(t * sub, sub), sub)
+            out = body(lambda q: lanes_ref[q, rows, :],
+                       lens_ref[rows, :], pc_ref)
+            out_ref[rows, :] = sum(o.astype(jnp.int32) << i
+                                   for i, o in enumerate(out))
+            return carry
+        jax.lax.fori_loop(0, ts // sub, tile, 0)
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((s, lanes), jnp.int32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(s // ts,),
+            in_specs=[pl.BlockSpec((nl, ts, lanes),
+                                   lambda i, pc: (0, i, 0)),
+                      pl.BlockSpec((ts, lanes), lambda i, pc: (i, 0))],
+            out_specs=pl.BlockSpec((ts, lanes), lambda i, pc: (i, 0))),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret)(pc, lanes_t, lens)
 
 
 # ---------------- the scan ----------------
 
 @partial(jax.jit, static_argnames=("pat_len", "mode", "starts_tok",
-                                   "ends_tok", "fold"))
+                                   "ends_tok", "fold", "spmd"))
 @jax.named_scope("match_scan")
 def match_scan_t(lanes_t: jnp.ndarray, lengths: jnp.ndarray,
                  pattern: jnp.ndarray, pat_len: int, mode: int,
                  starts_tok: bool, ends_tok: bool,
-                 fold: bool = False) -> jnp.ndarray:
-    """Per-row match bitmap over a lane-major staged string column.
+                 fold: bool = False, spmd: bool = False) -> jnp.ndarray:
+    """Per-row match bitmap over a staged string column.
 
-    lanes_t: uint32[W/4, R] (layout.to_lanes32); lengths: int32[R] true
-    byte lengths (truncated at W-1; overflow rows re-checked on host);
-    pattern: uint8[pat_len], pre-lowered when fold=True.
-    Semantics identical to kernels.match_scan (the oracle); returns
-    bool[R].
+    lanes_t: uint32[W/4, R/128, 128] planes (layout.to_lanes32);
+    lengths: int32[R] true byte lengths (truncated at W-1; overflow
+    rows re-checked on host); pattern: uint8[pat_len], pre-lowered when
+    fold=True.  Semantics identical to kernels.match_scan (the oracle);
+    returns bool[R].
     """
-    nl, r = lanes_t.shape
-    pc, masks, nc = _pattern_chunks(pattern, pat_len)
-    if fold:
-        lanes_t = fold_ascii32(lanes_t)
-
-    if mode in (MODE_EXACT, MODE_EXACT_PREFIX):
-        # window 0 only: compare the first nc lanes of each row
-        acc = None
-        for c in range(nc):
-            lane = lanes_t[c] if c < nl else _c(0xFFFFFFFF)
-            if masks[c] == 0xFFFFFFFF:
-                t = lane == pc[c]
-            else:
-                t = ((lane ^ pc[c]) & _c(masks[c])) == 0
-            acc = t if acc is None else acc & t
-        if mode == MODE_EXACT:
-            return acc & (lengths == pat_len)
-        return acc & (lengths >= pat_len)
-
-    # extension lanes of 0xFF padding: windows past the row width can
-    # never match (patterns are UTF-8 and contain no 0xFF byte)
-    ext = jnp.concatenate(
-        [lanes_t, jnp.full((nc, r), 0xFFFFFFFF, _U32)], axis=0)
-
+    nl = lanes_t.shape[0]
+    if pat_len > 4 * nl:
+        # no window fits the column's width
+        return lengths < 0
+    pc, masks = _pattern_chunks(pattern, pat_len)
     need_start = starts_tok and mode in (MODE_PHRASE, MODE_PREFIX)
     need_end = ends_tok and mode == MODE_PHRASE
-    wm = word_hibits(ext) if (need_start or need_end) else None
-    if need_start:
-        # wmp[q] = word mask of lane q-1 (lane -1 = before the string:
-        # a zero row, so window 0 always has a start boundary)
-        wmp = jnp.concatenate([jnp.zeros((1, r), _U32), wm], axis=0)
 
-    hit = None
-    for a in range(4):
-        s = _shifted(ext, a, nl + nc - 1)
-        acc = None
-        for c in range(nc):
-            lanes = s[c:c + nl]
-            if masks[c] == 0xFFFFFFFF:
-                t = lanes == pc[c]
-            else:
-                t = ((lanes ^ pc[c]) & _c(masks[c])) == 0
-            acc = t if acc is None else acc & t
-        if need_start:
-            # byte before window s=4q+a is byte (a-1) of lane q, or
-            # byte 3 of lane q-1 when a == 0
-            if a == 0:
-                pw = (wmp[:nl] >> _U32(31)) & _U32(1)
-            else:
-                pw = (wm[:nl] >> _U32(8 * (a - 1) + 7)) & _U32(1)
-            acc = acc & (pw == 0)
-        if need_end:
-            # byte after window is byte offset 4q + a + pat_len
-            t_off = a + pat_len
-            lq, lb = t_off // 4, t_off % 4
-            nw = (wm[lq:lq + nl] >> _U32(8 * lb + 7)) & _U32(1)
-            acc = acc & (nw == 0)
-        h = jnp.any(acc, axis=0)
-        hit = h if hit is None else hit | h
-    return hit & (lengths >= pat_len)
+    def body(load, lens, pc):
+        return [_scan_rows(load, nl, lens, pc, masks, pat_len, mode,
+                           need_start, need_end, fold)]
+
+    return _launch(body, 1, lanes_t, lengths, pc, spmd)[0]
 
 
-@partial(jax.jit, static_argnames=("pat_len", "mode", "starts_tok",
-                                   "ends_tok", "fold"))
+def _striped(lanes_t) -> bool:
+    """Whether a staged column (a placed array, not a tracer) is spread
+    over several devices: its jitted scan is then partitioned by XLA."""
+    return len(lanes_t.sharding.device_set) > 1
+
+
 def match_scan_t_packed(lanes_t, lengths, pattern, pat_len, mode,
                         starts_tok, ends_tok, fold=False):
     """match_scan_t with the bitmap bit-packed on device before download
     (8x fewer bytes over the host link)."""
+    return _scan_packed(lanes_t, lengths, pattern, pat_len, mode,
+                        starts_tok, ends_tok, fold, _striped(lanes_t))
+
+
+@partial(jax.jit, static_argnames=("pat_len", "mode", "starts_tok",
+                                   "ends_tok", "fold", "spmd"))
+def _scan_packed(lanes_t, lengths, pattern, pat_len, mode, starts_tok,
+                 ends_tok, fold, spmd):
     return jnp.packbits(match_scan_t(lanes_t, lengths, pattern, pat_len,
-                                     mode, starts_tok, ends_tok,
-                                     fold).astype(jnp.uint8))
+                                     mode, starts_tok, ends_tok, fold,
+                                     spmd).astype(jnp.uint8))
 
 
-def _window_hits(ext: jnp.ndarray, nl: int, pattern: jnp.ndarray,
-                 pat_len: int):
-    """Per-alignment window-equality masks: list of bool[nl, R] for
-    a in 0..3 (window start s = 4q + a)."""
-    pc, masks, nc = _pattern_chunks(pattern, pat_len)
-    out = []
-    for a in range(4):
-        s = _shifted(ext, a, nl + nc - 1)
-        acc = None
-        for c in range(nc):
-            lanes = s[c:c + nl]
-            if masks[c] == 0xFFFFFFFF:
-                t = lanes == pc[c]
-            else:
-                t = ((lanes ^ pc[c]) & _c(masks[c])) == 0
-            acc = t if acc is None else acc & t
-        out.append(acc)
-    return out
+# ---------------- the ordered pair ----------------
 
-
-@partial(jax.jit, static_argnames=("len_a", "len_b"))
+@partial(jax.jit, static_argnames=("len_a", "len_b", "spmd"))
 @jax.named_scope("match_pair")
 def match_ordered_pair_t(lanes_t: jnp.ndarray, lengths: jnp.ndarray,
                          pat_a: jnp.ndarray, len_a: int,
-                         pat_b: jnp.ndarray, len_b: int):
-    """`A.*B` decomposition on the lane-major layout: matches iff the
-    FIRST occurrence of A ends at or before the LAST occurrence of B.
+                         pat_b: jnp.ndarray, len_b: int,
+                         spmd: bool = False):
+    """`A.*B` decomposition over the planes: matches iff the FIRST
+    occurrence of A ends at or before the LAST occurrence of B.
     Rows containing a newline go to the needs-verify channel ('.' does
     not cross newlines).  Returns (definite bool[R], needs_verify
     bool[R]) — semantics identical to kernels.match_ordered_pair."""
-    nl, r = lanes_t.shape
-    nc_max = (max(len_a, len_b) + 3) // 4
-    ext = jnp.concatenate(
-        [lanes_t, jnp.full((nc_max, r), 0xFFFFFFFF, _U32)], axis=0)
-    big = jnp.int32(4 * nl + 8)
+    nl = lanes_t.shape[0]
+    if max(len_a, len_b) > 4 * nl:
+        # no window fits the column's width
+        return lengths < 0, lengths < 0
+    pc_a, masks_a = _pattern_chunks(pat_a, len_a)
+    pc_b, masks_b = _pattern_chunks(pat_b, len_b)
 
-    hits_a = _window_hits(ext, nl, pat_a, len_a)
-    hits_b = _window_hits(ext, nl, pat_b, len_b)
-    any_a = None
-    first_a = big
-    any_b = None
-    last_b = jnp.int32(-1)
-    for a in range(4):
-        ha, hb = hits_a[a], hits_b[a]
-        ra = jnp.any(ha, axis=0)
-        rb = jnp.any(hb, axis=0)
-        any_a = ra if any_a is None else any_a | ra
-        any_b = rb if any_b is None else any_b | rb
-        fq = jnp.argmax(ha, axis=0).astype(jnp.int32)       # first hit lane
-        pa = jnp.where(ra, 4 * fq + a, big)
-        first_a = jnp.minimum(first_a, pa)
-        lq = (nl - 1) - jnp.argmax(hb[::-1], axis=0).astype(jnp.int32)
-        pb = jnp.where(rb, 4 * lq + a, jnp.int32(-1))
-        last_b = jnp.maximum(last_b, pb)
-    any_a = any_a & (lengths >= len_a)
-    any_b = any_b & (lengths >= len_b)
-    ordered = any_a & any_b & (first_a + len_a <= last_b)
-    has_nl = jnp.any(any_byte_eq(lanes_t, 0x0A) != 0, axis=0)
-    return ordered & ~has_nl, ordered & has_nl
+    def body(load, lens, pc):
+        return _pair_rows(load, nl, lens, pc, masks_a, masks_b, len_a,
+                          len_b)
+
+    return tuple(_launch(body, 2, lanes_t, lengths,
+                         jnp.concatenate([pc_a, pc_b]), spmd))
 
 
-@partial(jax.jit, static_argnames=("len_a", "len_b"))
 def match_ordered_pair_t_packed(lanes_t, lengths, pat_a, len_a,
                                 pat_b, len_b):
     """Both result vectors packed into ONE uint8[2, R/8] download."""
+    return _pair_packed(lanes_t, lengths, pat_a, len_a, pat_b, len_b,
+                        _striped(lanes_t))
+
+
+@partial(jax.jit, static_argnames=("len_a", "len_b", "spmd"))
+def _pair_packed(lanes_t, lengths, pat_a, len_a, pat_b, len_b, spmd):
     definite, needsv = match_ordered_pair_t(lanes_t, lengths, pat_a,
-                                            len_a, pat_b, len_b)
+                                            len_a, pat_b, len_b, spmd)
     return jnp.stack([jnp.packbits(definite.astype(jnp.uint8)),
                       jnp.packbits(needsv.astype(jnp.uint8))], axis=0)

@@ -78,15 +78,18 @@ def to_fixed_width(arena_np: np.ndarray, offsets_np: np.ndarray,
 
 
 def to_lanes32(mat: np.ndarray) -> np.ndarray:
-    """(R, W) uint8 staging matrix -> (W/4, R) uint32 lane-major layout
-    for the u32-chunk kernels (tpu/kernels32.py): lanes[q, r] is the
-    little-endian word of bytes mat[r, 4q:4q+4].  Transposed so the row
-    axis rides the 128-wide TPU lane dimension (and shards over a mesh
-    along axis 1).  W is always a multiple of 4 (row_width_bucket)."""
+    """(R, W) uint8 staging matrix -> (W/4, R/128, 128) uint32 planes
+    for the u32-chunk kernels (tpu/kernels32.py): planes[q, r // 128,
+    r % 128] is the little-endian word of bytes mat[r, 4q:4q+4].  Word q
+    of every row is one (R/128, 128) array whose rows fill the sublanes
+    and the lanes of a TPU tile, and q is a leading, untiled index; the
+    row axis is axis 1 (and shards over a mesh there).  W is always a
+    multiple of 4 (row_width_bucket) and R of 128 (the row buckets)."""
     r, w = mat.shape
-    assert w % 4 == 0
+    assert w % 4 == 0 and r % 128 == 0
     return np.ascontiguousarray(
-        mat.reshape(r, w // 4, 4).view("<u4")[:, :, 0].T)
+        mat.reshape(r, w // 4, 4).view("<u4")[:, :, 0].T
+    ).reshape(w // 4, r // 128, 128)
 
 
 def rows_with_multibyte(arena_np: np.ndarray, offsets_np: np.ndarray,
