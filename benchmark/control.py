@@ -2,7 +2,7 @@
 """The control of "how `correct` is decided", at a cell's own size.
 
     python3 benchmark/control.py --workload <name> --seeds 1,2,3 \
-        --seconds 30 --unreadable fresh,part:3
+        --seconds 51 --unreadable fresh,part:3
 
 The system states no precision, so the control breaks one guarantee the
 configuration states, "every row the set-up wrote is readable": the rows
@@ -25,7 +25,6 @@ import sys
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
-import gen  # noqa: E402
 import reference  # noqa: E402
 import run  # noqa: E402
 import traffic as traffic_gen  # noqa: E402

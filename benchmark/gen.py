@@ -1,33 +1,70 @@
-"""Data from --seed: chip_smoke.py's HTTP-access rows, laid out by a config
-file.  (Not vlogsgenerator's field mix: see the config's `assumed`.)
+"""Data from --seed, laid out by a configuration file; rows by a schema module.
 
-Copied in shape from chip_smoke.py (`row_hash`/`row_fields`) and bench.py
-(columnar part build): every field of row i is a slice of one splitmix64
-hash of (i, seed), so any range of rows can be made independently and the
-child that builds the storage and the reference that checks the answers
-agree on the data without sharing a byte.  numpy only: the parent imports
-this, and the parent never touches jax.
+What is generic lives here: the splitmix64 row hash, the part table
+(`Layout`: days, parts, regions, which rows lie when), the arena form of a
+column, and the loader of the row schema a configuration names.  numpy and
+the standard library only: the parent imports this, and the parent never
+touches jax.
 
-Row i (global index, parts in the config's order):
+**The contract of a schema module**, `schemas/<name>.py` beside the
+`configs/` directory of the configuration whose `"schema"` key names it.
+It owns every field name, string table and stream rule; the child, its
+build workers, the reference, control.py, sweep.py and the tests' stand-in
+all reach it through `load_schema(config)`, once a process, over a
+configuration read with `load_config(path)`.  It imports
+numpy, the standard library and this module's generic helpers, nothing of
+the program.  Every field of row i derives from `row_hash(i, seed)`, so
+any range of rows can be made independently and the child that builds the
+storage and the reference that checks the answers agree on the data
+without sharing a byte.  All of it vectorised: no call a row.
+
+  STREAM_FIELDS            names of the stream fields, in column order
+  MESSAGE_FIELD            the field a two-element ["phrase", text] means
+  streams(config)          how many streams
+  stream_of(idx, config)   the stream (0..streams-1) of each row
+  stream_tags(k, config)   [(field, value)] of stream k
+  tenant(k, config)        (account, project) of stream k
+  row_fields(idx, seed, config)   {name: integer column} of the rows
+  Text(config)             the renderer, its tables built once a process:
+    .columns(idx, f)         every stored field but `_time` as fixed-width
+                             S arrays, in schema order (f: row_fields)
+    .text(field, idx, f, end)  a field a text filter may name, each row
+                             followed by `end`
+  row_token(field, row, seed, config)   the word a filter names to find
+                             that field's value in row `row`
+  absent_token(field, draw, config)     a word of that form no row holds;
+                             draw(n, off=0) is a whole number in [0, n)
+                             from the seed, the request and the placeholder;
+                             `off` makes a further, independent draw
+  selector(stream, config) {suffix: text}: a request's placeholder
+                             {name}_{suffix} that selects the stream
+  WHERE   {op: fn(blk, a, b, args, config) -> bool mask of rows [a, b)
+          of the block}; blk is row_fields' dict plus "idx"
+  STATS   {fn: class(config) with .add(blk, a, b, mask) and .value()}
+  PLACEHOLDERS (optional)  {kind: fn(name, params, draw, layout, seed)
+          -> {placeholder: text}}: traffic placeholder kinds of its own
+  ANSWERS (optional)  {kind: fn(reference, request, spec) -> normal-form
+          rows}: answer kinds of its own (see reference.Reference.matches)
+
+Row i (global index, parts in the config's order) has
   _time  day start + (i - first row of the day) * step of the day
-  app    "app<i % streams>"            (the one stream field)
-  _msg   "<VERB> /api/items/<item> status=<200|500> dur=<dur>ms msg=<WORD>"
-  trace  "tok<0..499999>"              dur  "<0..906>"      seq  "<i>"
+and the fields its schema gives it.
 """
 
+import importlib.util
 import json
+import os
 import time
 
 import numpy as np
 
 NS = 1_000_000_000
 REHEARSAL_SCALE = 0.01      # a rehearsal's share of every part's rows
-VERBS = ["GET", "POST", "PUT", "DELETE"]
-WORDS = ["ok", "cache miss", "retry", "connection reset by peer",
-         "deadline exceeded", "deadline extended", "flushed wal segment",
-         "request completed"]
-TRACE_CARD = 500_000
+SCHEMA_NAMES = ("STREAM_FIELDS", "MESSAGE_FIELD", "streams", "stream_of",
+                "stream_tags", "tenant", "row_fields", "Text", "row_token",
+                "absent_token", "selector", "WHERE", "STATS")
 _M64 = (1 << 64) - 1
+_schemas = {}               # path -> module: one load a process
 
 
 def row_hash(idx: np.ndarray, seed: int) -> np.ndarray:
@@ -40,27 +77,63 @@ def row_hash(idx: np.ndarray, seed: int) -> np.ndarray:
         return z ^ (z >> np.uint64(31))
 
 
-def row_fields(idx: np.ndarray, seed: int) -> dict:
-    """Integer columns of the rows `idx`: item, status500, dur, word, trace."""
-    h = row_hash(idx, seed)
-    u = np.uint64
-    return {"item": (h % u(99991)).astype(np.int64),
-            "status500": ((h >> u(17)) % u(7)) == 0,
-            "dur": ((h >> u(20)) % u(907)).astype(np.int64),
-            "word": ((h >> u(30)) % u(len(WORDS))).astype(np.int64),
-            "trace": ((h >> u(40)) % u(TRACE_CARD)).astype(np.int64)}
-
-
 def rfc3339(ns: int) -> str:
     return time.strftime("%Y-%m-%dT%H:%M:%S", time.gmtime(ns // NS)) \
         + f".{ns % NS:09d}Z"
 
 
+class SchemaError(ValueError):
+    """A configuration names no schema, or one that cannot be used."""
+
+
+def load_config(path: str) -> dict:
+    """A configuration file, the one way to read one: with the directory
+    its `configs/` lies in under "_dir", beside which its schema and its
+    traffic files are found.  A configuration made as a dict says "_dir"
+    itself; `load_schema` refuses one that has none."""
+    config = load_json(path)
+    config["_dir"] = os.path.dirname(os.path.dirname(os.path.abspath(path)))
+    return config
+
+
+def load_schema(config: dict):
+    """The schema module that `config` names (the contract: this file's
+    docstring).  No default, of the name or of the place: a configuration
+    says which rows it holds and (load_config) where its files lie."""
+    name = config.get("schema")
+    if not isinstance(name, str) or not name:
+        raise SchemaError(
+            f"configuration {config.get('name')!r} names no row schema: it "
+            f"needs a \"schema\" key, the name of a module under schemas/")
+    if "_dir" not in config:
+        raise SchemaError(
+            f"configuration {config.get('name')!r} does not say where its "
+            f"schemas/ directory lies: read it with gen.load_config, or "
+            f"give a dict \"_dir\"")
+    path = os.path.join(config["_dir"], "schemas", name + ".py")
+    mod = _schemas.get(path)
+    if mod is None:
+        if not os.path.isfile(path):
+            raise SchemaError(f"schema {name!r} of configuration "
+                              f"{config.get('name')!r}: no module at {path}")
+        spec = importlib.util.spec_from_file_location("schema_" + name, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        missing = [n for n in SCHEMA_NAMES if not hasattr(mod, n)]
+        if missing:
+            raise SchemaError(f"schema {name!r} at {path} lacks "
+                              f"{', '.join(missing)}")
+        _schemas[path] = mod
+    return mod
+
+
 class Layout:
-    """The config's part table: which rows lie in which part and when."""
+    """The config's part table: which rows lie in which part and when;
+    `schema` and `config` ride along for whoever makes or reads rows."""
 
     def __init__(self, config: dict, rows_scale: float = 1.0):
-        self.streams = int(config["streams"])
+        self.config, self.schema = config, load_schema(config)
+        self.streams = int(self.schema.streams(config))
         self.t0_ns = int(config["t0_unix_s"]) * NS
         self.parts = []          # dicts: day, lo, hi, t_min, t_max
         self.days = []           # dicts: day, lo, hi, start_ns, step_ns
@@ -121,38 +194,6 @@ class Layout:
         lo, hi = self.region(name)
         t = self.times(np.array([lo, hi - 1], dtype=np.int64))
         return int(t[0]), int(t[1])
-
-
-class Text:
-    """The string columns of rows, rendered with numpy tables: fixed-width
-    byte strings (dtype S, NUL padded), no Python string per row."""
-
-    def __init__(self):
-        items = np.arange(99991).astype("S5")
-        self.prefix = np.strings.add(
-            np.array([v + " /api/items/" for v in VERBS], "S18")[:, None],
-            items[None, :]).ravel()
-        self.durs = np.arange(907).astype("S3")
-        self.toks = np.strings.add(b"tok", np.arange(TRACE_CARD).astype("S6"))
-        self._suffix = {}
-
-    def msg(self, idx: np.ndarray, f: dict, end: str = "") -> np.ndarray:
-        """`_msg` of the rows, each followed by `end`."""
-        if end not in self._suffix:
-            self._suffix[end] = np.array(
-                [f" status={s} dur={d}ms msg={w}{end}"
-                 for s in (200, 500) for d in range(907) for w in WORDS], "S")
-        pi = (idx & 3) * 99991 + f["item"]
-        si = (f["status500"] * 907 + f["dur"]) * len(WORDS) + f["word"]
-        return np.strings.add(self.prefix[pi], self._suffix[end][si])
-
-    def columns(self, idx: np.ndarray, f: dict, streams: int) -> dict:
-        """Every stored field of the rows but `_time`, in schema order."""
-        return {"app": np.strings.add(b"app", (idx % streams).astype("S2")),
-                "_msg": self.msg(idx, f),
-                "trace": self.toks[f["trace"]],
-                "dur": self.durs[f["dur"]],
-                "seq": idx.astype("S10")}
 
 
 def arena(col: np.ndarray) -> tuple:

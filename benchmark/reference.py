@@ -1,14 +1,23 @@
 """The plain reference: the same questions asked of the same rows, in numpy.
 
 It imports nothing of the program and reads nothing the program wrote.  It
-makes the rows again from --seed (gen.row_fields, gen.Text: the
+makes the rows again from --seed (the configuration's row schema: the
 benchmark's own generator, the data that takes the place of weights), and
 answers a request from the class's "reference" entry in the traffic file:
 
-  where  [["time"], ["phrase", text], ["regex", pattern],
-          ["stream", "app3"], ["token", field, "tok123"]]   (all ANDed)
+  where  [["time"], ["phrase", text], ["phrase", field, text],
+          ["regex", pattern], ["regex", field, pattern],
+          [<an operator of the schema's WHERE>, args...]]   (all ANDed)
   by_time_s   bucket width of `stats by (_time:...)`, or absent
-  stats  [["count", alias], ["count_uniq_stream", alias]]
+  stats  [["count", alias], [<a function of the schema's STATS>, alias]]
+  answer      absent, or an answer kind of the schema's ANSWERS
+
+What is LogsQL and not schema lives here: the time range, the phrase and
+regex rules over a field's text (two elements: the schema's message
+field), the time buckets, `count`.  What knows a field, a stream or a
+token's form is the schema's, by name (`stream`, `token`,
+`count_uniq_stream` in access_line): the contract of a schema module is
+gen.py's docstring.
 
 LogsQL semantics kept: a phrase matches where the text occurs with no
 letter, digit or underscore directly before or after it; a regex matches
@@ -30,9 +39,10 @@ import re
 
 import numpy as np
 
-from gen import NS, Layout, Text, rfc3339, row_fields
+from gen import NS, Layout, rfc3339
 
 BLOCK = 1 << 20
+WHERE = ("time", "phrase", "regex")
 _WORD = np.zeros(256, dtype=bool)
 for _c in b"0123456789_abcdefghijklmnopqrstuvwxyzABCDEFGHIJKLMNOPQRSTUVWXYZ":
     _WORD[_c] = True
@@ -41,32 +51,35 @@ for _c in b"0123456789_abcdefghijklmnopqrstuvwxyzABCDEFGHIJKLMNOPQRSTUVWXYZ":
 class Reference:
     def __init__(self, layout: Layout, seed: int, unreadable=None):
         self.layout, self.seed = layout, seed
+        self.schema, self.config = layout.schema, layout.config
         self.rows = layout.rows
         self.unreadable = unreadable
         self._blocks = {}
-        self._render = Text()
+        self._render = self.schema.Text(self.config)
 
     # ---- rows ----
     def _block(self, b: int) -> dict:
-        """Rows [b*BLOCK, (b+1)*BLOCK): integer columns and the `_msg`
-        text, one fixed-width newline-ended row each."""
+        """Rows [b*BLOCK, (b+1)*BLOCK): the schema's integer columns,
+        "idx", and under "text:<field>" each text a filter has read, one
+        fixed-width newline-ended row each."""
         blk = self._blocks.get(b)
         if blk is None:
             idx = np.arange(b * BLOCK, min((b + 1) * BLOCK, self.rows),
                             dtype=np.int64)
-            blk = row_fields(idx, self.seed)
+            blk = self.schema.row_fields(idx, self.seed, self.config)
             blk["idx"] = idx
             self._blocks[b] = blk
         return blk
 
-    def _text(self, blk: dict) -> np.ndarray:
-        if "text" not in blk:
-            blk["text"] = self._render.msg(blk["idx"], blk, end="\n")
-        return blk["text"]
+    def _text(self, blk: dict, field: str) -> np.ndarray:
+        key = "text:" + field
+        if key not in blk:
+            blk[key] = self._render.text(field, blk["idx"], blk, end="\n")
+        return blk[key]
 
     # ---- filters: each returns a bool mask over rows [lo, hi) of a block
-    def _match_text(self, blk, a, b, op, arg) -> np.ndarray:
-        text = self._text(blk)[a:b]
+    def _match_text(self, blk, field, a, b, op, arg) -> np.ndarray:
+        text = self._text(blk, field)[a:b]
         width = text.dtype.itemsize
         buf = text.tobytes()
         lit = arg.encode()
@@ -97,31 +110,27 @@ class Reference:
                 t = self.layout.times(idx)
                 mask &= (t >= t0) & (t < t1)
             elif op in ("phrase", "regex"):
-                mask &= self._match_text(blk, a, b, op,
-                                         cond[1].format(**request["vals"]))
-            elif op == "stream":
-                app = cond[1].format(**request["vals"])
-                mask &= (idx % self.layout.streams) == int(app[3:])
-            elif op == "token":
-                # every `trace` value is one word "tok<n>", so a word
-                # filter on the field is equality with it
-                tok = cond[2].format(**request["vals"])
-                n = int(tok[3:]) if tok[3:].isdigit() else -1
-                mask &= blk[cond[1]][a:b] == n
+                field = cond[1] if len(cond) == 3 else \
+                    self.schema.MESSAGE_FIELD
+                mask &= self._match_text(blk, field, a, b, op,
+                                         cond[-1].format(**request["vals"]))
+            elif op in self.schema.WHERE:
+                args = [c.format(**request["vals"]) for c in cond[1:]]
+                mask &= self.schema.WHERE[op](blk, a, b, args, self.config)
             else:
                 raise ValueError(f"unknown reference filter {op!r}")
         return mask
 
     # ---- answers ----
-    def answer(self, request: dict, spec: dict) -> list:
-        """The request's answer in normal form (see `normal_form`)."""
+    def matches(self, request: dict, spec: dict):
+        """(blk, a, z, mask) for every stretch of rows that the request's
+        time range leaves: rows [a, z) of block `blk`, `mask` those that
+        pass every `where`."""
         where = spec.get("where", [])
         if request["t_range"] is not None and ["time"] in where:
             ranges = self.layout.row_range(*request["t_range"])
         else:
             ranges = [(0, self.rows)]
-        step = int(spec["by_time_s"]) * NS if "by_time_s" in spec else None
-        total, streams, buckets = 0, set(), {}
         for lo, hi in ranges:
             hi = min(hi, self.rows)
             for b in range(lo // BLOCK, (max(hi, lo + 1) - 1) // BLOCK + 1):
@@ -130,23 +139,51 @@ class Reference:
                     continue
                 blk = self._block(b)
                 a, z = a - b * BLOCK, z - b * BLOCK
-                mask = self._mask(blk, a, z, where, request)
-                total += int(mask.sum())
-                idx = blk["idx"][a:z][mask]
-                streams.update(np.unique(idx % self.layout.streams).tolist())
-                if step is not None:
-                    t = self.layout.times(idx) // step * step
-                    for k, c in zip(*np.unique(t, return_counts=True)):
-                        buckets[int(k)] = buckets.get(int(k), 0) + int(c)
+                yield blk, a, z, self._mask(blk, a, z, where, request)
+
+    def answer(self, request: dict, spec: dict) -> list:
+        """The request's answer in normal form (see `normal_form`)."""
+        if "answer" in spec:
+            return self.schema.ANSWERS[spec["answer"]](self, request, spec)
+        step = int(spec["by_time_s"]) * NS if "by_time_s" in spec else None
+        own = {fn: self.schema.STATS[fn](self.config)
+               for fn, _alias in spec["stats"] if fn != "count"}
+        total, buckets = 0, {}
+        for blk, a, z, mask in self.matches(request, spec):
+            total += int(mask.sum())
+            for acc in own.values():
+                acc.add(blk, a, z, mask)
+            if step is not None:
+                t = self.layout.times(blk["idx"][a:z][mask]) // step * step
+                for k, c in zip(*np.unique(t, return_counts=True)):
+                    buckets[int(k)] = buckets.get(int(k), 0) + int(c)
         names = {fn: alias.format(**request["vals"])
                  for fn, alias in spec["stats"]}
         if step is not None:
             rows = [{"_time": k, names["count"]: c}
                     for k, c in buckets.items()]
         else:
-            rows = [{alias: total if fn == "count" else len(streams)
+            rows = [{alias: total if fn == "count" else own[fn].value()
                      for fn, alias in names.items()}]
         return sorted(tuple(sorted(r.items())) for r in rows)
+
+
+def check(spec: dict, schema) -> None:
+    """Raises where a class's "reference" entry names an operator, a
+    stats function or an answer kind that neither this file nor the
+    schema has: before a child is started."""
+    for cond in spec.get("where", []):
+        if cond[0] not in WHERE and cond[0] not in schema.WHERE:
+            raise ValueError(f"no reference filter {cond[0]!r} here or in "
+                             f"schema {schema.__name__!r}")
+    for fn, _alias in spec["stats"]:
+        if fn != "count" and fn not in schema.STATS:
+            raise ValueError(f"no stats function {fn!r} here or in schema "
+                             f"{schema.__name__!r}")
+    if "answer" in spec and spec["answer"] not in getattr(schema, "ANSWERS",
+                                                          {}):
+        raise ValueError(f"no answer kind {spec['answer']!r} in schema "
+                         f"{schema.__name__!r}")
 
 
 def unreadable_rows(layout: Layout, what: str) -> tuple:

@@ -7,8 +7,9 @@ a process of their own, off the server's interpreter lock.  It never
 imports jax or anything under victorialogs_tpu; benchmark/serve.py, its
 one child, holds the chip(s).  Everything that belongs to one
 configuration, one traffic mix or one metric is a file found by the name
-in BENCHMARK.json: configs/<config>.json, traffic/<traffic>.json,
-metrics/<metric>.json, readers/<source kind>.py.
+in BENCHMARK.json: configs/<config>.json and the row schema it names,
+schemas/<schema>.py (the contract: gen.py's docstring),
+traffic/<traffic>.json, metrics/<metric>.json, readers/<source kind>.py.
 
 A run: start the child (set-up: data from --seed, server, warm-up of the
 window's own request shapes), offer the window's load, read counters and
@@ -314,23 +315,36 @@ def check_answers(records: list, unanswered: int, traffic: dict, layout,
 
 # ---------------- one run ----------------
 
-def load_cell(workload: str, rehearsal: bool = False) -> dict:
-    """What BENCHMARK.json and the data files say of one workload."""
-    bench = gen.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+def load_cell(workload: str, rehearsal: bool = False,
+              root: str = ROOT) -> dict:
+    """What BENCHMARK.json (in `root`) and the data files say of one
+    workload.  Whatever a file names and no code has, the schema module
+    first, fails here: before a child spends a set-up on it."""
+    bench = gen.load_json(os.path.join(root, "BENCHMARK.json"))
     cell = next((w for w in bench["workloads"] if w["name"] == workload),
                 None)
     if cell is None:
         fail(f"no workload {workload!r} in BENCHMARK.json")
     conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
-    config_path = os.path.join(ROOT, conf["file"])
-    config = gen.load_json(config_path)
-    traffic = gen.load_json(os.path.join(HERE, "traffic",
-                                         cell["traffic"] + ".json"))
+    config_path = os.path.join(root, conf["file"])
+    config = gen.load_config(config_path)
+    traffic_path = os.path.join(config["_dir"], "traffic",
+                                cell["traffic"] + ".json")
+    traffic = gen.load_json(traffic_path)
     if traffic["loop"] != "open":
         fail(f"traffic {traffic['name']!r}: no loop kind {traffic['loop']!r}")
-    layout = gen.Layout(config, gen.REHEARSAL_SCALE if rehearsal else 1.0)
+    try:
+        layout = gen.Layout(config,
+                            gen.REHEARSAL_SCALE if rehearsal else 1.0)
+        traffic_gen.check(traffic, layout.schema)
+        for cls in traffic["rotation"]:
+            reference.check(traffic["classes"][cls]["reference"],
+                            layout.schema)
+    except ValueError as e:         # gen.SchemaError among them
+        fail(str(e))
     return {"bench": bench, "cell": cell, "config_path": config_path,
-            "config": config, "traffic": traffic, "layout": layout,
+            "config": config, "traffic": traffic,
+            "traffic_path": traffic_path, "layout": layout,
             "peaks": gen.load_json(os.path.join(HERE, "peaks.json")),
             "stats_time": gen.rfc3339(layout.span("all")[1]
                                       + 86400 * gen.NS)}
@@ -385,9 +399,11 @@ def set_up(args, c: dict, serve_script: str, sched: list):
     return child, device, port
 
 
-def main(argv=None, serve_script=os.path.join(HERE, "serve.py")) -> int:
-    """One run.  `serve_script` is what tests replace, to drive a whole run
-    over a stand-in for the system under test."""
+def main(argv=None, serve_script=os.path.join(HERE, "serve.py"),
+         root: str = ROOT) -> int:
+    """One run.  `serve_script` and `root` are what tests replace, to
+    drive a whole run over a stand-in for the system under test and over a
+    BENCHMARK.json, configuration, schema and traffic of their own."""
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -398,7 +414,7 @@ def main(argv=None, serve_script=os.path.join(HERE, "serve.py")) -> int:
                          "control flow, prints no device metric, never a "
                          "chip result")
     args = ap.parse_args(argv)
-    c = load_cell(args.workload, args.rehearsal)
+    c = load_cell(args.workload, args.rehearsal, root)
     bench, cell, traffic = c["bench"], c["cell"], c["traffic"]
     sched = traffic_gen.schedule(traffic, args.seconds)
 

@@ -63,18 +63,18 @@ def build_runner(config: dict, rehearsal: bool):
 def build_data(storage, config: dict, layout: gen.Layout, seed: int,
                rows_scale: float) -> None:
     """Every part of the config's table: worker processes build the parts'
-    blocks, a stream of a part each (partbuild.py), and each day partition
-    buffers and flushes its parts in order, one file part for each entry."""
+    blocks, a set of streams of a part each (partbuild.py), and each day
+    partition buffers and flushes its parts in order, one file part for
+    each entry."""
     sids, tags = partbuild.stream_ids(layout)
-    # a part's blocks in the order its whole build gives: by stream id
-    by_sid = sorted(range(layout.streams), key=lambda k: sids[k])
+    config_json = json.dumps(config)
 
     def store_day(day: int, parts: list) -> None:
         pt = storage._get_partition(layout.t0_ns // gen.NS // 86400 + day)
         pt.idb.must_register_streams(list(zip(sids, tags)))
         for results in parts:
-            pt.ddb.must_add_blocks([b for k in by_sid
-                                    for b in results[k].get()])
+            pt.ddb.must_add_blocks(partbuild.in_build_order(
+                [b for r in results for b in r.get()]))
             pt.debug_flush()
 
     days = sorted({p["day"] for p in layout.parts})
@@ -82,8 +82,8 @@ def build_data(storage, config: dict, layout: gen.Layout, seed: int,
     with ctx.Pool(int(config["build_processes"])) as pool, \
             ThreadPoolExecutor(len(days)) as store:
         results = [(p["day"], [pool.apply_async(
-            partbuild.part_blocks, ((config, rows_scale, seed, i, k),))
-            for k in range(layout.streams)])
+            partbuild.part_blocks, ((config_json, rows_scale, seed, i, j),))
+            for j in range(partbuild.jobs(layout))])
             for i, p in enumerate(layout.parts)]
         for f in [store.submit(store_day, day,
                                [r for d, r in results if d == day])
@@ -113,7 +113,7 @@ def main() -> int:
     ap.add_argument("--trace-dir", default="")
     ap.add_argument("--rehearsal", action="store_true")
     args = ap.parse_args()
-    config = gen.load_json(args.config)
+    config = gen.load_config(args.config)
     rows_scale = gen.REHEARSAL_SCALE if args.rehearsal else 1.0
     layout = gen.Layout(config, rows_scale)
 
@@ -138,6 +138,12 @@ def main() -> int:
     build_data(storage, config, layout, args.seed, rows_scale)
     check_parts(storage, layout)
     build_s = time.monotonic() - t0
+    # the admission controller reads these two when it is made; a
+    # configuration that leaves them out gets the program's defaults
+    for key, env in (("tenant_max_concurrent", "VL_TENANT_MAX_CONCURRENT"),
+                     ("queue_max", "VL_QUEUE_MAX")):
+        if key in srv:
+            os.environ[env] = str(int(srv[key]))
     server = VLServer(storage, listen_addr="127.0.0.1", port=0,
                       runner=runner,
                       max_concurrent=int(srv["max_concurrent"]),

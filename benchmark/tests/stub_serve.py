@@ -36,7 +36,7 @@ def main() -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--fault", default="none")
     args, _rest = ap.parse_known_args()
-    config = gen.load_json(args.config)
+    config = gen.load_config(args.config)
     traffic = gen.load_json(args.traffic)
     layout = gen.Layout(config,
                         gen.REHEARSAL_SCALE if args.rehearsal else 1.0)
@@ -74,7 +74,8 @@ def main() -> int:
                 served[0] += 1
                 alter = args.fault == "altered" and served[0] % 5 == 0
             if alter:
-                rows = [tuple((k, v + 1 if k != "_time" else v)
+                rows = [tuple((k, v if k == "_time" else
+                               v + 1 if isinstance(v, int) else v + "x")
                               for k, v in row) for row in rows]
             self._send(reference.render(req["endpoint"], rows))
 

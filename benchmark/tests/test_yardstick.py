@@ -30,7 +30,8 @@ SCALE = gen.REHEARSAL_SCALE
 
 
 def load(kind: str, name: str) -> dict:
-    return gen.load_json(os.path.join(BENCH, kind, name + ".json"))
+    path = os.path.join(BENCH, kind, name + ".json")
+    return gen.load_config(path) if kind == "configs" else gen.load_json(path)
 
 
 # ---- the trace reduction ----
@@ -124,7 +125,8 @@ def test_schedule_is_the_files_own(name):
 # ---- bytes ----
 
 def test_bytes_on_a_two_part_table():
-    config = {"streams": 8, "t0_unix_s": 0,
+    config = {"schema": "access_line", "_dir": BENCH, "streams": 8,
+              "t0_unix_s": 0,
               "days": [{"day": 0, "span_s": 1000, "parts": [800, 200]}],
               "staged_width": {"_time": 8, "_msg": 128}}
     layout = gen.Layout(config)
@@ -148,14 +150,16 @@ def test_every_data_file_loads_and_is_named_well():
     bench = gen.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     listed_configs = {c["name"]: c for c in bench["configs"]}
     for path in glob.glob(os.path.join(BENCH, "configs", "*.json")):
-        config = gen.load_json(path)
+        config = gen.load_config(path)
         assert NAME.match(config["name"])
         assert os.path.basename(path) == config["name"] + ".json"
         c = listed_configs.get(config["name"])
         if c is not None:
             assert os.path.join(ROOT, c["file"]) == path
             assert set(c["reduced"]) == set(config["reduced"])
-        layout = gen.Layout(config)
+        layout = gen.Layout(config)     # loads the schema it names
+        assert os.path.exists(os.path.join(BENCH, "schemas",
+                                           config["schema"] + ".py"))
         assert layout.rows == config["rows"]
         assert max(sum(1 for p in layout.parts if p["day"] == d["day"])
                    for d in config["days"]) < 15   # DEFAULT_PARTS_TO_MERGE
@@ -193,8 +197,10 @@ def test_reference_on_rows_counted_by_hand():
     layout = gen.Layout(config, SCALE)
     import numpy as np
     idx = np.arange(layout.rows, dtype=np.int64)
-    f = gen.row_fields(idx, 5)
-    msgs = [m.decode() for m in gen.Text().msg(idx, f).tolist()]
+    schema = layout.schema
+    f = schema.row_fields(idx, 5, config)
+    msgs = [m.decode() for m in
+            schema.Text(config).text("_msg", idx, f).tolist()]
     ref = reference.Reference(layout, 5)
     req = {"t_range": None, "vals": {"phrase": "deadline exceeded",
                                      "alias": "c"}}
@@ -222,15 +228,17 @@ def test_reference_on_rows_counted_by_hand():
         set((inside // (300 * gen.NS) * 300 * gen.NS).tolist()))
 
 
-def drive(workload: str, fault: str, seed: int = 77) -> dict:
-    """A whole run of run.py over the stand-in child."""
-    bench = gen.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    cell = next(w for w in bench["workloads"] if w["name"] == workload)
+def drive(workload: str, fault: str, seed: int = 77, root: str = ROOT,
+          scratch: str = HERE) -> dict:
+    """A whole run of run.py over the stand-in child, for a workload of
+    the BENCHMARK.json in `root`; the stand-in's wrapper is written to
+    `scratch`."""
+    traffic_path = run.load_cell(workload, True, root)["traffic_path"]
     stub = os.path.join(HERE, "stub_serve.py")
-    wrapper = os.path.join(HERE, f".stub_{fault}.py")
+    wrapper = os.path.join(scratch, f".stub_{fault}.py")
     with open(wrapper, "w") as f:
         f.write("import sys, runpy\n"
-                f"sys.argv += ['--traffic', {os.path.join(BENCH, 'traffic', cell['traffic'] + '.json')!r}, "
+                f"sys.argv += ['--traffic', {traffic_path!r}, "
                 f"'--seconds', '3', '--fault', {fault!r}]\n"
                 f"runpy.run_path({stub!r}, run_name='__main__')\n")
     out = io.StringIO()
@@ -238,7 +246,7 @@ def drive(workload: str, fault: str, seed: int = 77) -> dict:
         with redirect_stdout(out):
             rc = run.main(["--workload", workload, "--seed", str(seed),
                            "--seconds", "3", "--trace", "0", "--rehearsal"],
-                          serve_script=wrapper)
+                          serve_script=wrapper, root=root)
     finally:
         os.remove(wrapper)
     assert rc == 0
