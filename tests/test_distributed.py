@@ -207,3 +207,34 @@ def test_mesh_runner_staged_arrays_are_sharded(tmp_path):
         assert not sharding.is_fully_replicated  # really split, axis 0
     finally:
         s.close()
+
+
+# ---------------- the operand block under the mesh ----------------
+
+@pytest.fixture(scope="module")
+def operand_storage(tmp_path_factory):
+    import operand_cases as OC
+    s = OC.make_storage(str(tmp_path_factory.mktemp("mesh_operands")))
+    yield s
+    s.close()
+
+
+@pytest.fixture(scope="module")
+def mesh_runner():
+    from victorialogs_tpu.parallel.distributed import MeshBatchRunner
+    return MeshBatchRunner(make_mesh(8))
+
+
+def _operand_cases():
+    import operand_cases as OC
+    return pytest.mark.parametrize("case", OC.CASES, ids=OC.CASE_IDS)
+
+
+@_operand_cases()
+def test_mesh_operand_block_every_leaf_kind(operand_storage, mesh_runner,
+                                            monkeypatch, case):
+    """tests/test_fused.py's operand-block cases on the 8-device mesh:
+    the block is the dispatch's one host operand, replicated (`P()`)
+    to every shard; answers equal the host path's."""
+    import operand_cases as OC
+    OC.check_case(operand_storage, mesh_runner, monkeypatch, case)

@@ -257,29 +257,8 @@ def test_fused_topk_declines_cleanly(storage):
 def multipart_storage(tmp_path_factory):
     """The FUSED_QUERIES corpus spread over several small parts, so the
     async pipeline's window and small-part packing engage."""
-    path = str(tmp_path_factory.mktemp("fusedmp"))
-    s = Storage(path, retention_days=100000, flush_interval=3600)
-    words = ["deadline exceeded", "connection reset", "ok", "retry later",
-             "cache miss", "flushed"]
-    n = 0
-    for _pp in range(6):
-        lr = LogRows(stream_fields=["app"])
-        for _i in range(1500):
-            i = n
-            n += 1
-            msg = f"GET /api/x{i % 71} {words[i % 6]} dur={i % 351}ms"
-            if i % 37 == 0:
-                msg = f"GÉT /äpi/x{i % 71} {words[i % 6]} ⏱={i % 351}"
-            if i % 97 == 0:
-                msg = f"GET /api\nlate {words[i % 6]} tail"
-            lr.add(TEN, T0 + i * 200_000_000, [
-                ("app", f"app{i % 4}"),
-                ("_msg", msg),
-                ("lvl", ["info", "warn", "error"][i % 3]),
-                ("dur", str(i % 351)),
-            ])
-        s.must_add_rows(lr)
-        s.debug_flush()
+    import operand_cases as OC
+    s = OC.make_storage(str(tmp_path_factory.mktemp("fusedmp")))
     yield s
     s.close()
 
@@ -328,3 +307,123 @@ def test_fused_truncation_overflow(tmp_path):
         assert int(cpu[0]["c"]) > 0
     finally:
         s.close()
+
+
+# ---------------- the operand block (one host operand a dispatch) ------
+
+def _operand_cases():
+    import operand_cases as OC
+    return pytest.mark.parametrize("case", OC.CASES, ids=OC.CASE_IDS)
+
+
+@_operand_cases()
+def test_operand_block_every_leaf_kind(multipart_storage, monkeypatch,
+                                        case):
+    """Every leaf kind that carries a host operand (time and range
+    bounds, scan / pair / in patterns), a packed super-dispatch, a topk
+    and a row-filter dispatch: answers bit-identical to the host path,
+    the jitted call's operands hold exactly one leaf that is not a
+    jax.Array (the int32 block), and `operand_blocks` grows by the
+    `device_calls` delta."""
+    import operand_cases as OC
+    OC.check_case(multipart_storage, BatchRunner(), monkeypatch, case)
+
+
+def test_operand_block_layout():
+    """The block's own contract: word 0 is the live row count, offsets
+    follow registration order, a byte string rides four bytes a
+    little-endian word, a uint32 bound keeps its bits, and the length
+    is a power of two (32 words at least) whatever the literals are."""
+    from types import SimpleNamespace
+    from victorialogs_tpu.tpu import fused
+    pl = fused._Planner(None, None, {}, SimpleNamespace(nrows=1234))
+    assert pl.host_words(7, -3) == 1
+    assert pl.host_bytes(b"deadline exceeded") == 3       # 17 B: 5 words
+    assert pl.host_words((1 << 32) - 1, 1 << 31) == 8
+    blk = pl.block()
+    assert blk.dtype == np.int32 and blk.shape == (32,)
+    assert blk[fused.BLOCK_NROWS] == 1234
+    assert list(blk[1:3]) == [7, -3]
+    assert blk[3:8].tobytes()[:17] == b"deadline exceeded"
+    assert blk[3:8].tobytes()[17:] == b"\0\0\0"
+    assert list(blk[8:10].view(np.uint32)) == [(1 << 32) - 1, 1 << 31]
+    assert not blk[10:].any()
+    # what the program reads back (_block_bytes) is the pattern
+    import jax
+    got = jax.jit(lambda b: fused._block_bytes(b, 3, 17))(blk)
+    assert bytes(np.asarray(got)) == b"deadline exceeded"
+    # 33 words registered: the next bucket
+    pl.host_bytes(b"x" * 92)
+    assert pl.block().shape == (64,)
+
+
+# the five classes of the benchmark's adhoc_scan cell
+# (benchmark/traffic/adhoc_scan.json), with two literals of the same
+# length and two time windows each
+def _adhoc_scan_queries(phrase, tail, t0, t1):
+    w = f"_time:[2025-07-28T00:{t0}:00Z, 2025-07-28T00:{t1}:00Z)"
+    return [f'{w} "{phrase}" | stats count() c',
+            f'{w} "{phrase}" | stats by (_time:5m) count() c',
+            f'_msg:~"dead.*{tail}" | stats count() c',
+            "* | stats count() c, count_uniq(_stream_id) u"]
+
+
+# distinct (program name, static key, operand shapes) the five classes
+# need over one part, counted on the parent tree (commit 9c190cc, where
+# every scalar and pattern was an operand of its own): the block must
+# not add one
+PARENT_ADHOC_SCAN_PROGRAMS = 4
+
+
+def test_operand_block_adds_no_program(storage, monkeypatch):
+    """Other literals of the same lengths and other time windows run
+    the programs the first round compiled (`vl_tpu_jit_compiles_total`
+    does not move), and the benchmark's five adhoc_scan classes over
+    one part need as many programs as on the parent."""
+    import jax
+    import operand_cases as OC
+    from victorialogs_tpu.tpu import compile_stats
+    runner = BatchRunner()
+    seen = OC.record_launches(monkeypatch)
+
+    def run(queries):
+        for qs in queries:
+            cpu = run_query_collect(storage, [TEN], qs, timestamp=T0)
+            dev = run_query_collect(storage, [TEN], qs, timestamp=T0,
+                                    runner=runner)
+            assert _norm(cpu) == _norm(dev), qs
+
+    extra = ["dur:range[100, 200] | stats count() c",
+             "_msg:len_range(10, 30) | stats count() c",
+             "lvl:in(error, warn) | stats count() c",
+             '"GET" | sort by (dur desc) limit 7 | fields dur',
+             '"deadline exceeded" dur:>300 | fields _msg, app']
+    run(_adhoc_scan_queries("deadline exceeded", "exceeded", "02", "17")
+        + extra)
+    first = len(seen)
+    assert first >= 9
+
+    def keys():
+        return {tuple(a if not hasattr(a, "shape")
+                      else (a.shape, str(a.dtype))
+                      for a in jax.tree_util.tree_leaves(
+                          args, is_leaf=lambda x: x is None))
+                for args in seen}
+
+    programs = keys()
+    adhoc = {k for k in programs if k[0].startswith("fused_")
+             and not k[0].startswith(("fused_numrange", "fused_lenrange",
+                                      "fused_exact"))}
+    assert len(adhoc) == PARENT_ADHOC_SCAN_PROGRAMS, sorted(
+        k[0] for k in adhoc)
+    compiles = compile_stats()["jit_compiles_total"]
+    other = ["dur:range[150, 340] | stats count() c",
+             "_msg:len_range(20, 60) | stats count() c",
+             "lvl:in(trace, info) | stats count() c",
+             '"POS" | sort by (dur desc) limit 7 | fields dur',
+             '"connection reseted" dur:>200 | fields _msg, app']
+    run(_adhoc_scan_queries("deadline extended", "extended", "05", "30")
+        + other)
+    assert len(seen) > first
+    assert keys() == programs
+    assert compile_stats()["jit_compiles_total"] == compiles

@@ -123,3 +123,44 @@ def test_a_column_xla_partitions_is_told_apart(topo, as_on_the_chip):
     assert "tpu_custom_call" not in text
     with pytest.raises(NotImplementedError, match="shard_map"):
         K32._scan_packed.lower(*args, False)
+
+
+@pytest.mark.parametrize("query,kernels", [
+    ('_time:[2025-07-28T00:02:00Z, 2025-07-28T00:17:00Z) "deadline '
+     'exceeded" | stats by (_time:5m) count() c', 1),
+    ('_msg:~"dead.*exceeded" | stats count() c', 1),
+    ('lvl:in(error, warn) "GET" | stats count() c', 3)],
+    ids=["time_phrase", "pair", "in_and_phrase"])
+def test_a_fused_program_reads_its_operand_block_on_the_chip(
+        topo, tmp_path, monkeypatch, query, kernels):
+    """A whole fused program as the chip's process compiles it: the
+    operand block (int32, the call's one host operand) feeds the time
+    bounds and, through a static slice and a bitcast, the Pallas call's
+    scalar-prefetched pattern chunks.  Operand shapes are those of a
+    real dispatch on jax-CPU, placed on the described chip."""
+    import operand_cases as OC
+    from victorialogs_tpu.engine.searcher import run_query_collect
+    from victorialogs_tpu.tpu import fused
+    from victorialogs_tpu.tpu.batch import BatchRunner
+    one = SingleDeviceSharding(topo.devices[0])
+    s = OC.make_storage(str(tmp_path))
+    try:
+        seen = OC.record_launches(monkeypatch)
+        run_query_collect(s, [OC.TEN], query, timestamp=OC.T0,
+                          runner=BatchRunner())
+    finally:
+        s.close()
+    # the launcher asks for the backend (as_on_the_chip, after the
+    # jax-CPU run); that run traced the scan's inner jit with the direct
+    # launcher, and a trace is cached by shapes, not by backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()
+    _name, *args = seen[0]
+    (blk,) = OC.host_operands(args)
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+        if hasattr(a, "shape") else a, args)
+    text = jax.jit(fused._fused_dispatch, static_argnums=(0, 1, 2, 3)) \
+        .lower(*shapes).compile().as_text()
+    assert blk.dtype == np.int32
+    assert text.count("tpu_custom_call") == kernels

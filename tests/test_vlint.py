@@ -324,6 +324,90 @@ def test_jit_closure_flagged_and_clean():
     assert "jax-jit-closure" not in checkers(out)
 
 
+_EAGER_SUBMIT_CASES = [
+    # (case, source, path, flagged)
+    ("scalar_in_submit", """
+        import jax.numpy as jnp
+        def fused_stats_submit(runner, layout):
+            nrows = jnp.int32(layout.nrows)
+            return nrows
+     """, "victorialogs_tpu/tpu/fused.py", True),
+    ("asarray_in_planner_method", """
+        import jax.numpy as jnp
+        class _Planner:
+            def _scan_leaf(self, pat):
+                return jnp.asarray(pat)
+     """, "victorialogs_tpu/tpu/fused.py", True),
+    ("array_in_launch_dotted_module", """
+        import jax
+        def _launch(runner, dispatch, x):
+            return dispatch(jax.numpy.array(x))
+     """, "victorialogs_tpu/tpu/fused.py", True),
+    ("scalar_in_pipeline_nested_refill", """
+        import jax.numpy as jnp
+        def scan_device_stream(items):
+            def refill():
+                return jnp.uint32(len(items))
+            return refill
+     """, "victorialogs_tpu/tpu/pipeline.py", True),
+    ("scalar_in_pipeline_submit", """
+        import jax.numpy as jnp
+        def _submit_pack_stats(runner, unit):
+            return jnp.float32(1.0)
+     """, "victorialogs_tpu/tpu/pipeline.py", True),
+    ("jitted_body_is_not_submit_path", """
+        import jax.numpy as jnp
+        def _eval_tree_node(node, args, blk, rlp):
+            return jnp.uint32(0), jnp.asarray(args[0])
+        def _fused_local(prog, blk):
+            return jnp.int32(0)
+     """, "victorialogs_tpu/tpu/fused.py", False),
+    ("numpy_and_block_are_clean", """
+        import numpy as np
+        import jax.numpy as jnp
+        def fused_stats_submit(runner, planner, layout):
+            off = planner.host_words(layout.nrows)
+            return np.int32(off), planner.block(), jnp.zeros
+     """, "victorialogs_tpu/tpu/fused.py", False),
+    ("other_file_is_out_of_scope", """
+        import jax.numpy as jnp
+        def fused_stats_submit(runner, layout):
+            return jnp.int32(layout.nrows)
+     """, "victorialogs_tpu/tpu/batch.py", False),
+    ("annotated_site_is_allowed", """
+        import jax.numpy as jnp
+        def fused_stats_submit(runner, layout):
+            # vlint: allow-jax-eager-submit(one-off probe, not per dispatch)
+            return jnp.int32(layout.nrows)
+     """, "victorialogs_tpu/tpu/fused.py", False),
+]
+
+
+@pytest.mark.parametrize(
+    "src,path,flagged", [c[1:] for c in _EAGER_SUBMIT_CASES],
+    ids=[c[0] for c in _EAGER_SUBMIT_CASES])
+def test_eager_submit_flagged_and_clean(src, path, flagged):
+    """An eager jnp scalar/asarray/array on the submit path of
+    tpu/fused.py or tpu/pipeline.py is an error; jitted bodies, numpy,
+    other files and annotated sites are not."""
+    out = lint(src, path=path)
+    assert ("jax-eager-submit" in checkers(out)) is flagged
+
+
+def test_eager_submit_clean_on_the_real_submit_path():
+    """The shipped tpu/fused.py and tpu/pipeline.py hold no eager jnp
+    constructor on the submit path, and no allow annotation hides one."""
+    from tools.vlint import hotpath
+    from tools.vlint.core import SourceFile
+    for rel in ("victorialogs_tpu/tpu/fused.py",
+                "victorialogs_tpu/tpu/pipeline.py"):
+        sf = SourceFile.parse(os.path.join(REPO, rel), display_path=rel)
+        assert hotpath._submit_path_re(rel) is not None
+        assert not [f for f in hotpath.check(sf)
+                    if f.checker == "jax-eager-submit"], rel
+        assert "allow-jax-eager-submit" not in sf.text
+
+
 def test_static_arg_flagged_and_clean():
     out = lint("""
         import jax

@@ -15,6 +15,14 @@ flag the statically detectable cases:
   int/str literals (or tuples thereof) — unstable or unhashable
   statics retrigger compilation per call (the EWMA-poisoning
   compile-timing class of bug from the cost-gate hardening).
+- jax-eager-submit: an eager `jnp.<scalar type>(...)`, `jnp.asarray(...)`
+  or `jnp.array(...)` in a submit-path function of tpu/fused.py or
+  tpu/pipeline.py (SUBMIT_PATH).  Outside a jitted body each is a
+  host-to-device put plus an eagerly dispatched program on every
+  dispatch (`jnp.int32(layout.nrows)` was the largest single item of
+  the submit path, PERF.md PR 31); a small host operand rides the
+  dispatch's operand block (_Planner.host_words / host_bytes) instead.
+  A deliberate site carries `# vlint: allow-jax-eager-submit(<why>)`.
 - per-row-emit (server/ and engine/ scope): json.dumps calls or
   dict-literal .append()s inside a loop — the per-row emit shape the
   columnar path (engine/emit.ndjson_block + BlockResult.emit_columns)
@@ -48,6 +56,53 @@ EMIT_SCOPE_RE = re.compile(r"(^|/)(server|engine)(/|$)")
 _DEVICE_MODULE_HINTS = ("kernels", "fused", "stats_device", "sort_device")
 
 _SYNC_CASTS = {"float", "int", "bool"}
+
+# The submit path: what runs on the query's thread between a unit's
+# lease and its jitted call.  By name, a file at a time: the planner's
+# methods and the three submit entries with what they call
+# (tpu/fused.py), the unit stream and the submit/refill half of the
+# window (tpu/pipeline.py).  Jitted bodies (_eval_tree_node,
+# _fused_local, ...) are not on it: a jnp call there is traced once.
+SUBMIT_PATH = {
+    "tpu/fused.py": re.compile(
+        r"^(_Planner\.\w+|fused_\w+_submit|try_fused_topk"
+        r"|_stage_cand_mask|_launch)$"),
+    "tpu/pipeline.py": re.compile(
+        r"^(_submit\w*|_host_members|_count_pack|_get_pack"
+        r"|_unit_stream(\.\w+)*|scan_device_stream(\.\w+)*)$"),
+}
+_EAGER_CALLS = {"asarray", "array", "bool_", "int8", "int16", "int32",
+                "int64", "uint8", "uint16", "uint32", "uint64", "float16",
+                "bfloat16", "float32", "float64"}
+
+
+def _submit_path_re(path: str):
+    for suffix, rx in SUBMIT_PATH.items():
+        if path.endswith("/" + suffix) or path == suffix:
+            return rx
+    return None
+
+
+def _check_eager_submit(fnode, sf, symbol, findings) -> None:
+    """Eager jnp constructors in one submit-path function (nested defs
+    are visited under their own symbol)."""
+    stack = list(fnode.body)
+    while stack:
+        node = stack.pop()
+        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef,
+                             ast.ClassDef)):
+            continue
+        stack.extend(ast.iter_child_nodes(node))
+        if not isinstance(node, ast.Call):
+            continue
+        d = _dotted(node.func)
+        mod, _, attr = d.rpartition(".")
+        if mod in ("jnp", "jax.numpy") and attr in _EAGER_CALLS:
+            findings.append(Finding(
+                "jax-eager-submit", sf.path, node.lineno, symbol,
+                f"eager {d}() on the submit path: a device put and a "
+                f"dispatched program on every call; ship host scalars "
+                f"in the operand block (_Planner.host_words)"))
 
 
 def _device_module_aliases(tree: ast.Module) -> set:
@@ -326,6 +381,7 @@ def check(sf: SourceFile) -> list[Finding]:
     tree = sf.tree
     jit_names = _module_jit_names(tree)
     dev_modules = _device_module_aliases(tree)
+    submit_re = _submit_path_re(sf.path)
     # module-level mutable literals (jit closures over them go stale)
     module_mutables: set = set()
     for node in tree.body:
@@ -345,6 +401,8 @@ def check(sf: SourceFile) -> list[Finding]:
                 scope = _FuncScope(sf, sym, jit_names, dev_modules,
                                    findings)
                 scope.run(child.body)
+                if submit_re is not None and submit_re.match(sym):
+                    _check_eager_submit(child, sf, sym, findings)
                 if _jit_decorated(child):
                     _check_jit_closure(child, sf, sym, module_mutables,
                                        findings)

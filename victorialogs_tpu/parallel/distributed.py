@@ -247,16 +247,16 @@ class MeshBatchRunner(BatchRunner):
             sp.add("mesh_collective_dispatches")
             sp.set("mesh_devices", self.ndev)
 
-    def _dispatch_fused(self, name, prog, strides, nb, n_values, nrows,
+    def _dispatch_fused(self, name, prog, strides, nb, n_values, blk,
                         cand_packed, seg_map, ids_tuple, values_tuple,
                         args):
         from ..tpu.fused import fused_mesh_program
         self._trace_collective()
         return fused_mesh_program(name)(
-            self.mesh, BLOCK_AXIS, prog, strides, nb, n_values, nrows,
+            self.mesh, BLOCK_AXIS, prog, strides, nb, n_values, blk,
             cand_packed, seg_map, ids_tuple, values_tuple, args)
 
-    def _dispatch_filter(self, name, prog, nrows, cand_packed, args):
+    def _dispatch_filter(self, name, prog, blk, cand_packed, args):
         # row-query fused filter under shard_map: each device evaluates
         # its row stripe, packed (definite, maybe) bits concatenate over
         # the row axis.  Layouts are padded to STATS_CHUNK * ndev rows
@@ -268,7 +268,7 @@ class MeshBatchRunner(BatchRunner):
         from ..tpu.fused import filter_mesh_program
         self._trace_collective()
         return filter_mesh_program(name)(self.mesh, BLOCK_AXIS, prog,
-                                         nrows, cand_packed, args)
+                                         blk, cand_packed, args)
 
     def _dispatch_stats_count(self, ids_tuple, strides, mask, nb):
         return np.array(_stats_count_mesh(self.mesh, ids_tuple, strides,

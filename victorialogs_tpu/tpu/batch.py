@@ -983,6 +983,9 @@ class BatchRunner:
         self.plane_scan_leaves = 0     # scan and `A.*B` leaves those
         #                                dispatches ran through the plane
         #                                kernel (tpu/kernels32.py)
+        self.operand_blocks = 0        # fused/topk/filter dispatches that
+        #                                shipped their host operands as
+        #                                one block (tpu/fused.py:_launch)
         self.cpu_fallbacks = 0
         self.gated_host_parts = 0
         self.stats_dispatches = 0
@@ -1067,6 +1070,7 @@ class BatchRunner:
             out = {
                 "device_calls": self.device_calls,
                 "plane_scan_leaves": self.plane_scan_leaves,
+                "operand_blocks": self.operand_blocks,
                 "cpu_fallbacks": self.cpu_fallbacks,
                 "gated_host_parts": self.gated_host_parts,
                 "stats_dispatches": self.stats_dispatches,
@@ -1299,23 +1303,23 @@ class BatchRunner:
     # ---- stats dispatch hooks (MeshBatchRunner shard_maps + psum-reduces)
     # `name`: the program's name (fused.program_name), under which the
     # one jitted callable of that kind is looked up
-    def _dispatch_fused(self, name, prog, strides, nb, n_values, nrows,
+    def _dispatch_fused(self, name, prog, strides, nb, n_values, blk,
                         cand_packed, seg_map, ids_tuple, values_tuple,
                         args):
         from .fused import fused_program
-        return fused_program(name)(prog, strides, nb, n_values, nrows,
+        return fused_program(name)(prog, strides, nb, n_values, blk,
                                    cand_packed, seg_map, ids_tuple,
                                    values_tuple, args)
 
-    def _dispatch_topk(self, name, prog, k, desc, nseg, nrows,
+    def _dispatch_topk(self, name, prog, k, desc, nseg, blk,
                        cand_packed, seg_ids, seg_map, values, args):
         from .fused import topk_program
-        return topk_program(name)(prog, k, desc, nseg, nrows, cand_packed,
+        return topk_program(name)(prog, k, desc, nseg, blk, cand_packed,
                                   seg_ids, seg_map, values, args)
 
-    def _dispatch_filter(self, name, prog, nrows, cand_packed, args):
+    def _dispatch_filter(self, name, prog, blk, cand_packed, args):
         from .fused import filter_program
-        return filter_program(name)(prog, nrows, cand_packed, args)
+        return filter_program(name)(prog, blk, cand_packed, args)
 
     def _dispatch_stats_count(self, ids_tuple, strides, mask, nb):
         # vlint: allow-jax-host-sync(result readback at dispatch boundary)

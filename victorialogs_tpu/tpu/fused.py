@@ -41,6 +41,7 @@ them bit-exactly over randomized query matrices).
 from __future__ import annotations
 
 import math
+import struct
 import threading
 from dataclasses import dataclass
 from functools import lru_cache
@@ -271,9 +272,23 @@ class _NoFuse(Exception):
     pass
 
 
+# The operand block: every small host-side operand of a dispatch (the
+# live row count, time and range bounds, scan patterns) travels in ONE
+# int32 numpy array, the jitted program's single host operand, so a
+# dispatch makes one host-to-device transfer whatever the query holds.
+# A tree node keeps the STATIC word offset of what it registered (the
+# order of registration, so a function of the query's shape alone) and
+# the program reads static slices of the block; pattern lengths are
+# static in the tree already.  The length is bucketed, so no literal
+# and no pattern length adds a shape to a program key.
+BLOCK_NROWS = 0            # word 0 of every block: the layout's live rows
+_BLOCK_MIN_WORDS = 32
+
+
 class _Planner:
     """Walks the filter tree, staging what it needs and emitting a
-    hashable program plus the parallel dynamic-argument list."""
+    hashable program, the parallel list of device-resident arguments
+    and the operand block of the host-side ones."""
 
     def __init__(self, runner, part, bss, layout):
         self.runner = runner
@@ -282,6 +297,7 @@ class _Planner:
         self.layout = layout
         self.args: list = []
         self.arg_rows: list = []
+        self._block = bytearray(struct.pack("<i", layout.nrows))
         self.field_slots: dict[str, int] = {}
         self.fields: list[FusedField] = []
         self._slot_args: list = []
@@ -297,6 +313,33 @@ class _Planner:
         self.args.append(a)
         self.arg_rows.append(int(row))
         return len(self.args) - 1
+
+    def host_words(self, *words: int) -> int:
+        """Register host scalars in the operand block, each as the low
+        32 bits of its two's complement (an int32 reads back as itself,
+        a uint32 bound through a bitcast); returns the static word
+        offset of the first."""
+        off = len(self._block) // 4
+        self._block += struct.pack(f"<{len(words)}I",
+                                   *(w & 0xFFFFFFFF for w in words))
+        return off
+
+    def host_bytes(self, b: bytes) -> int:
+        """Register a short byte string (a scan pattern), four bytes a
+        little-endian word, zero-padded to a whole word; returns its
+        static word offset (_block_bytes reads it back)."""
+        off = len(self._block) // 4
+        self._block += b
+        self._block += bytes(-len(b) % 4)
+        return off
+
+    def block(self) -> np.ndarray:
+        """The dispatch's operand block: int32[bucket], the bucket the
+        power of two that holds the registered words (32 at least)."""
+        nwords = len(self._block) // 4
+        size = max(_BLOCK_MIN_WORDS, 1 << (nwords - 1).bit_length())
+        return np.frombuffer(self._block + bytes(4 * (size - nwords)),
+                             dtype="<i4")
 
     def field_slot(self, field: str) -> tuple[int, FusedField]:
         slot = self.field_slots.get(field)
@@ -390,10 +433,10 @@ class _Planner:
         hi_off = f.max_ts - ts.base
         if hi_off < 0 or lo_off >= (1 << 47):
             return ("false",)
-        b = [self.arg(np.int32(x)) for x in
-             (*_split_bound(lo_off),
-              *_split_bound(min(hi_off, (1 << 47) - 1)))]
-        return ("time", self.ts_slot[0], self.ts_slot[1], *b)
+        bounds = self.host_words(
+            *_split_bound(lo_off),
+            *_split_bound(min(hi_off, (1 << 47) - 1)))
+        return ("time", self.ts_slot[0], self.ts_slot[1], bounds)
 
     def _block_uniform_leaf(self, f):
         """Per-block-constant filters (stream filters after candidate
@@ -480,8 +523,8 @@ class _Planner:
             if max(len(a), len(b)) >= ff.width:
                 return self._with_bloom(bloom_node, self._ovf_only(oi))
             self.has_maybe = True
-            pa = self.arg(np.frombuffer(a, dtype=np.uint8))
-            pb = self.arg(np.frombuffer(b, dtype=np.uint8))
+            pa = self.host_bytes(a)
+            pb = self.host_bytes(b)
             return self._with_bloom(
                 bloom_node, ("pair", ri, li, oi, pa, len(a), pb, len(b)))
         # case-fold leaves: non-ASCII rows diverge from the byte fold in
@@ -505,7 +548,7 @@ class _Planner:
             elif len(op.pattern) >= ff.width:
                 kids.append(self._ovf_only(oi))
             else:
-                pi = self.arg(np.frombuffer(op.pattern, dtype=np.uint8))
+                pi = self.host_bytes(op.pattern)
                 kids.append(("scan", ri, li, oi,
                              mb_mi if op.fold else -1, pi,
                              len(op.pattern), op.mode, op.starts_tok,
@@ -604,9 +647,7 @@ class _Planner:
         lo_off = max(0, lo_off)
         hi_off = min(hi_off, (1 << 32) - 1)
         vi = self.arg(sn.values, row=True)
-        a = self.arg(np.uint32(lo_off))
-        b = self.arg(np.uint32(hi_off))
-        return ("numrange", vi, a, b)
+        return ("numrange", vi, self.host_words(lo_off, hi_off))
 
     def _lenrange_leaf(self, f: F.FilterLenRange):
         """len_range(lo, hi): rune counts equal byte lengths for pure
@@ -626,16 +667,16 @@ class _Planner:
         mbm = self.runner._stage_multibyte(self.part, field, self.layout)
         mi = self.arg(mbm.packed, row=True) if mbm.any else -1
         imax = (1 << 31) - 1
-        a = self.arg(np.int32(min(max(0, f.min_len), imax)))
-        b = self.arg(np.int32(min(f.max_len, imax)))
-        b4 = self.arg(np.int32(min(4 * f.max_len, imax)))
+        bounds = self.host_words(min(max(0, f.min_len), imax),
+                                 min(f.max_len, imax),
+                                 min(4 * f.max_len, imax))
         # overflow rows whose true length must exceed 4*hi are
         # definitively false (their staged length W-1 > hi keeps d false)
         if ff.width - 1 > min(4 * f.max_len, imax):
             oi = -1
         if mi >= 0 or oi >= 0:
             self.has_maybe = True
-        return ("lenrange", li, oi, mi, a, b, b4)
+        return ("lenrange", li, oi, mi, bounds)
 
     def _in_leaf(self, f: F.FilterIn):
         """`lvl:in(a, b, ...)` = OR of exact scans over the materialized
@@ -659,7 +700,7 @@ class _Planner:
             if len(v) >= ff.width:
                 kids.append(self._ovf_only(oi))
                 continue
-            pi = self.arg(np.frombuffer(v.encode(), dtype=np.uint8))
+            pi = self.host_bytes(v.encode())
             kids.append(("scan", ri, li, oi, -1, pi, len(v),
                          K.MODE_EXACT, False, False, False))
         return self._combine("or", kids)
@@ -788,17 +829,22 @@ def _program(name: str, body, jit):
     return fn
 
 
-def _launch(dispatch, *args):
+def _launch(runner, dispatch, *args):
     """The jitted call, until it returns its async handles: the
-    `launch` child of the pipeline's `submit` span.  On a trace it
-    carries `device_queue_depth`: the scheduler's leased slots
-    (dispatch units submitted and not yet harvested, process-wide) at
-    this instant, this unit's own lease left out."""
+    `launch` child of the pipeline's `submit` span.  The operand block
+    among `args` is the call's one host operand, transferred inside it
+    (as numpy, by jit's own argument path); the runner counts the call
+    as one `operand_blocks`.  On a trace the span carries
+    `device_queue_depth`: the scheduler's leased slots (dispatch units
+    submitted and not yet harvested, process-wide) at this instant,
+    this unit's own lease left out."""
     with tracing.current_span().span("launch") as sp:
         if sp.enabled:
             sp.set("device_queue_depth",
                    max(0, sched.scheduler().in_flight() - 1))
-        return dispatch(*args)
+        out = dispatch(*args)
+    runner._bump("operand_blocks")
+    return out
 
 
 # ---------------- the jitted program evaluator ----------------
@@ -809,18 +855,30 @@ def _unpack_bits(packed, n):
     return bits[:n].astype(jnp.bool_)
 
 
-def _eval_node(node, args, rlp):
+def _block_bytes(blk, off: int, n: int):
+    """The uint8[n] byte string the planner registered at word `off` of
+    the operand block (_Planner.host_bytes): a static slice, the words
+    bitcast back to their little-endian bytes."""
+    import jax.numpy as jnp
+    words = blk[off:off + (n + 3) // 4]
+    return jax.lax.bitcast_convert_type(words, jnp.uint8).reshape(-1)[:n]
+
+
+def _eval_node(node, args, blk, rlp):
     """Recursive (definite, maybe) evaluation; maybe may be None (==0).
-    Each leaf evaluates under a named scope of its kind (leaf_kind), so
-    the profile's op metadata says which leaf an operation belongs to."""
+    args: the device-resident arguments, blk: the operand block (the
+    host-side scalars and patterns, at the static offsets the nodes
+    carry).  Each leaf evaluates under a named scope of its kind
+    (leaf_kind), so the profile's op metadata says which leaf an
+    operation belongs to."""
     kind = leaf_kind(node)
     if kind is None:
-        return _eval_tree_node(node, args, rlp)
+        return _eval_tree_node(node, args, blk, rlp)
     with jax.named_scope(kind):
-        return _eval_tree_node(node, args, rlp)
+        return _eval_tree_node(node, args, blk, rlp)
 
 
-def _eval_tree_node(node, args, rlp):
+def _eval_tree_node(node, args, blk, rlp):
     import jax.numpy as jnp
     kind = node[0]
     if kind == "true":
@@ -850,13 +908,14 @@ def _eval_tree_node(node, args, rlp):
         keep = plane_keep_sb(args[pi], args[ii], args[mi], args[ni])
         return keep[args[bidi]], None
     if kind == "lenrange":
-        _, li, oi, mi, a, b, b4 = node
+        _, li, oi, mi, bounds = node
         lens = args[li]
-        d = (lens >= args[a]) & (lens <= args[b])
+        lo, hi, hi4 = blk[bounds], blk[bounds + 1], blk[bounds + 2]
+        d = (lens >= lo) & (lens <= hi)
         may = None
         if mi >= 0:
             multibyte = _unpack_bits(args[mi], rlp)
-            may = multibyte & (lens >= args[a]) & (lens <= args[b4])
+            may = multibyte & (lens >= lo) & (lens <= hi4)
         if oi >= 0:
             ov = _unpack_bits(args[oi], rlp)
             may = ov if may is None else may | ov
@@ -864,19 +923,22 @@ def _eval_tree_node(node, args, rlp):
             return d, None
         return d & ~may, may
     if kind == "numrange":
-        _, vi, a, b = node
+        _, vi, bounds = node
         v = args[vi]
-        return (v >= args[a]) & (v <= args[b]), None
+        lo, hi = jax.lax.bitcast_convert_type(blk[bounds:bounds + 2],
+                                              jnp.uint32)
+        return (v >= lo) & (v <= hi), None
     if kind == "time":
-        _, hi_i, lo_i, a, b, c, d = node
+        _, hi_i, lo_i, bounds = node
         hi, lo = args[hi_i], args[lo_i]
-        lo_hi, lo_lo, hi_hi, hi_lo = args[a], args[b], args[c], args[d]
+        lo_hi, lo_lo, hi_hi, hi_lo = blk[bounds:bounds + 4]
         ge = (hi > lo_hi) | ((hi == lo_hi) & (lo >= lo_lo))
         le = (hi < hi_hi) | ((hi == hi_hi) & (lo <= hi_lo))
         return ge & le, None
     if kind == "scan":
         _, ri, li, oi, mi, pi, plen, mode, st, et, fold = node
-        m = K32.match_scan_t(args[ri], args[li], args[pi], plen, mode, st,
+        m = K32.match_scan_t(args[ri], args[li],
+                             _block_bytes(blk, pi, plen), plen, mode, st,
                              et, fold)
         may = None
         if oi >= 0:
@@ -889,9 +951,9 @@ def _eval_tree_node(node, args, rlp):
         return m & ~may, may
     if kind == "pair":
         _, ri, li, oi, pa, la, pb, lb = node
-        definite, needsv = K32.match_ordered_pair_t(args[ri], args[li],
-                                                    args[pa], la,
-                                                    args[pb], lb)
+        definite, needsv = K32.match_ordered_pair_t(
+            args[ri], args[li], _block_bytes(blk, pa, la), la,
+            _block_bytes(blk, pb, lb), lb)
         may = needsv
         if oi >= 0:
             ov = _unpack_bits(args[oi], rlp)
@@ -899,12 +961,12 @@ def _eval_tree_node(node, args, rlp):
             may = may | ov
         return definite, may
     if kind == "not":
-        d, m = _eval_node(node[1], args, rlp)
+        d, m = _eval_node(node[1], args, blk, rlp)
         if m is None:
             return ~d, None
         return ~(d | m), m
     # and / or
-    kids = [_eval_node(k, args, rlp) for k in node[1]]
+    kids = [_eval_node(k, args, blk, rlp) for k in node[1]]
     if kind == "and":
         d = kids[0][0]
         pos = d if kids[0][1] is None else d | kids[0][1]
@@ -931,7 +993,7 @@ def _seg_base_ids(ids_tuple, strides):
     return K.combine_ids(ids_tuple[1:], strides[1:])
 
 
-def _fused_local(prog, strides, nb, n_values, axis, nrows, cand_packed,
+def _fused_local(prog, strides, nb, n_values, axis, blk, cand_packed,
                  seg_map, ids_tuple, values_tuple, args):
     """The fused program body, single-device or per-shard.
 
@@ -952,14 +1014,14 @@ def _fused_local(prog, strides, nb, n_values, axis, nrows, cand_packed,
     nseg = prog[5] if len(prog) > 5 else 0
     seg_pallas = prog[6] if len(prog) > 6 else False
     rl = ids_tuple[0].shape[0]         # LOCAL rows (== global w/o axis)
-    d, m = _eval_node(tree, args, rl)
+    d, m = _eval_node(tree, args, blk, rl)
     if has_cand:
         cand = _unpack_bits(cand_packed, rl)
     else:
         idx = jnp.arange(rl, dtype=jnp.int32)
         if axis is not None:
             idx = idx + jax.lax.axis_index(axis) * rl
-        cand = idx < nrows
+        cand = idx < blk[BLOCK_NROWS]
     d = d & cand
     with jax.named_scope("stats"):
         flat = _fused_reduce(strides, nb, n_values, axis, nseg,
@@ -1046,7 +1108,7 @@ def _fused_reduce(strides, nb, n_values, axis, nseg, seg_pallas, seg_map,
     return flat
 
 
-def _fused_dispatch(prog, strides, nb, n_values, nrows, cand_packed,
+def _fused_dispatch(prog, strides, nb, n_values, blk, cand_packed,
                     seg_map, ids_tuple, values_tuple, args):
     """One device call: filter tree -> stats partials (+ packed maybe).
     Jitted under its program name by fused_program().
@@ -1055,8 +1117,10 @@ def _fused_dispatch(prog, strides, nb, n_values, nrows, cand_packed,
     seg_pallas]) — static, hashable; arg_rows marks which leaf args are
     row-aligned (mesh sharding); nseg > 0 marks a packed super-dispatch
     (seg-major reduction, tpu/stats_seg.py).
-    nrows: dynamic scalar (rows < nrows are live when cand_packed is
-    None-shaped); cand_packed: uint8[RLp/8] or zeros(1) when unused;
+    blk: the operand block (_Planner.block), the call's one host
+    operand: word BLOCK_NROWS is the live row count (rows below it are
+    live when cand_packed is None-shaped), the rest the tree's scalars
+    and patterns; cand_packed: uint8[RLp/8] or zeros(1) when unused;
     seg_map: the pack's int32[S, Lp] slot grid (zeros(1, 1) stub when
     nseg == 0).
     Returns (flat, maybe_packed): flat is uint32[nb + 1] for count-only
@@ -1064,7 +1128,7 @@ def _fused_dispatch(prog, strides, nb, n_values, nrows, cand_packed,
     maybe-any flag; maybe_packed is uint8[RLp/8] (zeros(1) when the
     program proves no maybe rows exist) and is only worth downloading
     when the flag is nonzero."""
-    return _fused_local(prog, strides, nb, n_values, None, nrows,
+    return _fused_local(prog, strides, nb, n_values, None, blk,
                         cand_packed, seg_map, ids_tuple, values_tuple,
                         args)
 
@@ -1074,7 +1138,7 @@ def fused_program(name: str):
         fn, static_argnums=(0, 1, 2, 3)))
 
 
-def _fused_dispatch_mesh(mesh, axis, prog, strides, nb, n_values, nrows,
+def _fused_dispatch_mesh(mesh, axis, prog, strides, nb, n_values, blk,
                          cand_packed, seg_map, ids_tuple, values_tuple,
                          args):
     """The fused program under shard_map: each device evaluates the tree
@@ -1088,22 +1152,23 @@ def _fused_dispatch_mesh(mesh, axis, prog, strides, nb, n_values, nrows,
     from jax.sharding import PartitionSpec as P
     has_cand = prog[3]
     arg_rows = prog[4]
-    # roles are explicit: the planner marked row-aligned leaf args;
-    # ids/values axes are always row-aligned; cand is row-aligned only
-    # when a real candidate mask was shipped (else it is a zeros(1) stub)
+    # roles are explicit: the operand block is replicated; the planner
+    # marked row-aligned leaf args; ids/values axes are always
+    # row-aligned; cand is row-aligned only when a real candidate mask
+    # was shipped (else it is a zeros(1) stub)
     in_specs = (P(), P(axis) if has_cand else P(), P(None, None),
                 tuple(P(axis) for _ in ids_tuple),
                 tuple(P(axis) for _ in values_tuple),
                 tuple(P(None, axis) if r == 2 else
                       (P(axis) if r else P()) for r in arg_rows))
 
-    def fn(nrows, cp, sm, ids, vals, leaf_args):
-        return _fused_local(prog, strides, nb, n_values, axis, nrows,
+    def fn(b, cp, sm, ids, vals, leaf_args):
+        return _fused_local(prog, strides, nb, n_values, axis, b,
                             cp, sm, ids, vals, leaf_args)
 
     return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=(P(), P(axis)))(
-        nrows, cand_packed, seg_map, ids_tuple, values_tuple, args)
+        blk, cand_packed, seg_map, ids_tuple, values_tuple, args)
 
 
 def fused_mesh_program(name: str):
@@ -1275,13 +1340,13 @@ def fused_stats_submit(runner, f, part, bss, spec, asm):
     asm: the runner's assembled stats axes (AxesAssembly).  Requires
     every candidate block to be stats-eligible (the fused path never
     routes blocks through the row pipeline)."""
-    import jax.numpy as jnp
     layout = asm.layout
     if any(any(bi not in el for el in asm.eligibility) for bi in bss):
         return None
     # `args`: the host-side argument build (plan, staging lookups, the
-    # candidate mask and scalar puts); `launch` (_launch): the jitted
-    # call until it returns its async handles
+    # candidate mask, the operand block: numpy and bytes only, no jax
+    # op); `launch` (_launch): the jitted call until it returns its
+    # async handles
     with tracing.current_span().span("args"):
         planner = _Planner(runner, part, bss, layout)
         try:
@@ -1308,7 +1373,7 @@ def fused_stats_submit(runner, f, part, bss, spec, asm):
                              for fld in spec.value_fields)
         name = program_name("fused", tree,
                             stats_reduction(spec, len(values_tuple)))
-        nrows = jnp.int32(layout.nrows)
+        blk = planner.block()
     runner._bump("device_calls")
     runner._bump("plane_scan_leaves", plane_scan_leaves(tree))
     runner._bump("stats_dispatches")
@@ -1323,18 +1388,16 @@ def fused_stats_submit(runner, f, part, bss, spec, asm):
     if spec.quantile_fields:
         runner._kind("fused_quantile")
     flat, mp = _launch(
-        runner._dispatch_fused, name, prog, asm.strides, asm.nb,
-        len(values_tuple), nrows, cand_packed, seg_map, asm.ids_tuple,
+        runner, runner._dispatch_fused, name, prog, asm.strides, asm.nb,
+        len(values_tuple), blk, cand_packed, seg_map, asm.ids_tuple,
         values_tuple, tuple(planner.args))
     return _StatsPending(runner, f, part, bss, spec, asm, handled, flat,
                          mp)
 
 
-
-
 # ---------------- fused filter | sort-topk prefilter ----------------
 
-def _topk_dispatch(prog, k, desc, nseg, nrows, cand_packed, seg_ids,
+def _topk_dispatch(prog, k, desc, nseg, blk, cand_packed, seg_ids,
                    seg_map, values, args):
     """One device call: filter tree -> top-k threshold -> packed row sets.
 
@@ -1363,11 +1426,11 @@ def _topk_dispatch(prog, k, desc, nseg, nrows, cand_packed, seg_ids,
     import jax.numpy as jnp
     tree, _rlp, has_maybe, has_cand = prog[:4]
     rl = values.shape[0]
-    d, m = _eval_node(tree, args, rl)
+    d, m = _eval_node(tree, args, blk, rl)
     if has_cand:
         cand = _unpack_bits(cand_packed, rl)
     else:
-        cand = jnp.arange(rl, dtype=jnp.int32) < nrows
+        cand = jnp.arange(rl, dtype=jnp.int32) < blk[BLOCK_NROWS]
     d = d & cand
     mv = (m & cand) if (has_maybe and m is not None) else None
     with jax.named_scope("topk"):
@@ -1424,7 +1487,6 @@ def fused_topk_submit(runner, f, part, bss, spec):
     stage like the stats seg axis and the dispatch k-selects per
     member, so flush-sized parts under `sort | head` stop paying one
     dispatch each."""
-    import jax.numpy as jnp
     from .stats_device import MAX_ABS_TIMES_ROWS, MAX_STAT_ROWS
     with tracing.current_span().span("args"):
         layout = runner._stats_layout(part)
@@ -1463,14 +1525,15 @@ def fused_topk_submit(runner, f, part, bss, spec):
         prog = (tree, layout.nrows_padded, planner.has_maybe, has_cand,
                 tuple(planner.arg_rows))
         name = program_name("topk_seg" if nseg else "topk", tree)
-        nrows = jnp.int32(layout.nrows)
+        blk = planner.block()
     runner._bump("device_calls")
     runner._bump("plane_scan_leaves", plane_scan_leaves(tree))
     runner._bump("topk_dispatches")
     runner._kind("topk_seg" if nseg else "topk")
     dm, mm = _launch(
-        runner._dispatch_topk, name, prog, k, spec.desc, nseg, nrows,
-        cand_packed, seg_ids, seg_map, sn.values, tuple(planner.args))
+        runner, runner._dispatch_topk, name, prog, k, spec.desc, nseg,
+        blk, cand_packed, seg_ids, seg_map, sn.values,
+        tuple(planner.args))
     # the maybe vector is only meaningful when the program proved maybe
     # rows can exist; _FilterPending's harvest applies the same residue
     # discipline as the fused stats/filter paths
@@ -1492,19 +1555,19 @@ def try_fused_topk(runner, f, part, bss, spec):
 
 # ---------------- fused filter-only dispatch (row queries) ----------------
 
-def _filter_local(prog, axis, nrows, cand_packed, args, rl):
+def _filter_local(prog, axis, blk, cand_packed, args, rl):
     """Whole-filter-tree evaluation body: bit-packed (definite, maybe)
     row vectors.  axis/rl as in _fused_local (rl is this shard's rows)."""
     import jax.numpy as jnp
     tree, _rlp, has_maybe, has_cand = prog[:4]
-    d, m = _eval_node(tree, args, rl)
+    d, m = _eval_node(tree, args, blk, rl)
     if has_cand:
         cand = _unpack_bits(cand_packed, rl)
     else:
         idx = jnp.arange(rl, dtype=jnp.int32)
         if axis is not None:
             idx = idx + jax.lax.axis_index(axis) * rl
-        cand = idx < nrows
+        cand = idx < blk[BLOCK_NROWS]
     d = d & cand
     if has_maybe and m is not None:
         mp = jnp.packbits((m & cand).astype(jnp.uint8))
@@ -1515,7 +1578,7 @@ def _filter_local(prog, axis, nrows, cand_packed, args, rl):
     return jnp.packbits(d.astype(jnp.uint8)), mp
 
 
-def _filter_dispatch(prog, nrows, cand_packed, args):
+def _filter_dispatch(prog, blk, cand_packed, args):
     """One device call: the WHOLE filter tree -> bit-packed (definite,
     maybe) row vectors — the row-query analogue of _fused_dispatch.
 
@@ -1526,7 +1589,7 @@ def _filter_dispatch(prog, nrows, cand_packed, args):
     packed vectors, which is what makes the dispatch window's
     submit/harvest split (tpu/pipeline.py) worthwhile: one async
     handle per part instead of a host sync per leaf."""
-    return _filter_local(prog, None, nrows, cand_packed, args, prog[1])
+    return _filter_local(prog, None, blk, cand_packed, args, prog[1])
 
 
 def filter_program(name: str):
@@ -1534,7 +1597,7 @@ def filter_program(name: str):
         fn, static_argnums=(0,)))
 
 
-def _filter_dispatch_mesh(mesh, axis, prog, nrows, cand_packed, args):
+def _filter_dispatch_mesh(mesh, axis, prog, blk, cand_packed, args):
     """The filter-only program under shard_map: each device evaluates
     its row stripe, the packed (definite, maybe) vectors concatenate
     along the row axis (rl per shard is a multiple of 8, so the bit
@@ -1547,12 +1610,12 @@ def _filter_dispatch_mesh(mesh, axis, prog, nrows, cand_packed, args):
                 tuple(P(None, axis) if r == 2 else
                       (P(axis) if r else P()) for r in arg_rows))
 
-    def fn(nrows, cp, leaf_args):
-        return _filter_local(prog, axis, nrows, cp, leaf_args, rl)
+    def fn(b, cp, leaf_args):
+        return _filter_local(prog, axis, b, cp, leaf_args, rl)
 
     return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=(P(axis), P(axis)))(
-        nrows, cand_packed, args)
+        blk, cand_packed, args)
 
 
 def filter_mesh_program(name: str):
@@ -1617,7 +1680,6 @@ def fused_filter_submit(runner, f, part, bss):
     _Ready result for constant trees, or None when the shape declines
     (caller falls back to the per-leaf run_part path).  Kill-switch:
     VL_FUSED_FILTER=0 restores the round-3 per-leaf behavior."""
-    import jax.numpy as jnp
     from .stats_device import MAX_STAT_ROWS
     if not fused_filter_enabled():
         return None
@@ -1641,12 +1703,12 @@ def fused_filter_submit(runner, f, part, bss):
         prog = (tree, layout.nrows_padded, planner.has_maybe, has_cand,
                 tuple(planner.arg_rows))
         name = program_name("filter", tree)
-        nrows = jnp.int32(layout.nrows)
+        blk = planner.block()
     runner._bump("device_calls")
     runner._bump("plane_scan_leaves", plane_scan_leaves(tree))
     runner._bump("filter_dispatches")
     runner._kind("fused_filter")
-    dm, mm = _launch(runner._dispatch_filter, name, prog, nrows,
+    dm, mm = _launch(runner, runner._dispatch_filter, name, prog, blk,
                      cand_packed, tuple(planner.args))
     return _FilterPending(runner, f, part, bss, layout, dm, mm,
                           planner.has_maybe)
