@@ -199,25 +199,6 @@ def test_unforced_gate_routes_to_device_when_cheap(storage, monkeypatch):
     assert runner.gated_host_parts == 0
 
 
-def test_first_scan_timing_is_discarded(storage, monkeypatch):
-    # ADVICE r4: the first call of a jit signature includes compilation;
-    # it must NOT seed dev_bytes_per_s.  The EWMA is fed by the per-leaf
-    # scan path — pin it on (row queries default to the fused filter
-    # dispatch since the async pipeline round, which never calls _scan)
-    monkeypatch.setenv("VL_FUSED_FILTER", "0")
-    monkeypatch.setenv("VL_COST_FORCE", "")
-    monkeypatch.setenv("VL_COST_RTT_MS", "0")
-    monkeypatch.setenv("VL_COST_HOST_MROWS", "0.001")  # route to device
-    runner = BatchRunner()
-    assert runner.cost.dev_bytes_per_s is None
-    _hits(storage, "timeout", runner)
-    first_sigs = set(runner._scan_sigs)
-    assert first_sigs                         # a scan dispatched
-    assert runner.cost.dev_bytes_per_s is None  # first timing discarded
-    _hits(storage, "timeout", runner)         # same signature, warm now
-    assert runner.cost.dev_bytes_per_s is not None
-
-
 def test_prefetch_gate_matches_eval_gate(tmp_path, monkeypatch):
     # ADVICE r4: prefetch used (n_dispatch=1, cold=0) while run_part
     # accounted both — they now share _gate_host_est by construction;
@@ -258,7 +239,7 @@ def test_prefetch_gate_matches_eval_gate(tmp_path, monkeypatch):
         assert calls, "submit_prefetch did not consult _gate_host_est"
         assert all(r is True for *_, r in calls)
         # the gate declined, so nothing was staged for that part
-        assert not runner.cache.contains((parts[1].uid, "_msg"))
+        assert not runner.cache.contains((parts[1].uid, "#fl", "_msg"))
         # eval side agrees bit-for-bit on the same decision inputs
         got = run_query_collect(s, [TEN], "error", runner=runner)
         assert len(got) == 4000

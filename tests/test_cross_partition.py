@@ -2,13 +2,12 @@
 day partitions, packed sort-topk, segment-major packed stats.
 
 Pins the three tentpole behaviors against the serial CPU walk:
-- parity matrix (packed/serial x VL_FUSED_FILTER on/off x mesh runner)
+- parity matrix (packed/serial x mesh runner)
   for sort-topk and wide (>=64 groups) group-by over a 3-day fixture,
   row order and hit sets bit-identical;
 - the in-flight window survives partition boundaries (inflight_hwm
-  reaches VL_INFLIGHT on a 3-partition run — the prefetch/window depth
-  the per-partition drain used to lose at every boundary, still
-  observable under VL_CROSS_PARTITION=0);
+  reaches VL_INFLIGHT on a 3-partition run, more units than any one
+  day holds);
 - packed sort-topk dispatches engage (counter) and packed wide
   group-bys stop widening the bucket one-hot by pack size;
 - cancellation mid-partition drains the window with zero downstream
@@ -81,19 +80,16 @@ def _norm(rows):
     return sorted(tuple(sorted(r.items())) for r in rows)
 
 
-@pytest.mark.parametrize("pack,fused_filter",
-                         [("1", "1"), ("8", "1"), ("1", "0"),
-                          ("8", "0")])
-def test_parity_matrix(storage, monkeypatch, pack, fused_filter):
+@pytest.mark.parametrize("pack", ["1", "8"])
+def test_parity_matrix(storage, monkeypatch, pack):
     monkeypatch.setenv("VL_INFLIGHT", "4")
     monkeypatch.setenv("VL_PACK_PARTS", pack)
-    monkeypatch.setenv("VL_FUSED_FILTER", fused_filter)
     runner = BatchRunner()
     for qs in MATRIX_QUERIES:
         cpu = run_query_collect(storage, [TEN], qs, timestamp=T0)
         dev = run_query_collect(storage, [TEN], qs, timestamp=T0,
                                 runner=runner)
-        assert _norm(cpu) == _norm(dev), (qs, pack, fused_filter)
+        assert _norm(cpu) == _norm(dev), (qs, pack)
     if pack != "1":
         assert runner.packed_dispatches > 0
         # packs really crossed a day boundary (consecutive parts of
@@ -121,45 +117,36 @@ def test_row_order_matches_serial_across_partitions(storage,
                                                     monkeypatch):
     """Downstream block order across the 3-day walk is part of the
     contract: the global window must yield rows in the exact order of
-    the per-partition serial walk (not just as a set)."""
+    the serial walk (not just as a set): of the window at depth one
+    without packing, and of the host executor on one thread."""
     qs = 'err | fields _time, dur'
     monkeypatch.setenv("VL_INFLIGHT", "1")
     monkeypatch.setenv("VL_PACK_PARTS", "1")
-    monkeypatch.setenv("VL_CROSS_PARTITION", "0")
-    # concurrency=1: VL_CROSS_PARTITION=0 also restores the
-    # thread-per-partition fan-out, which is not a serial walk
-    serial = run_query_collect(storage, [TEN],
-                               "options(concurrency=1) " + qs,
-                               timestamp=T0, runner=BatchRunner())
+    serial = run_query_collect(storage, [TEN], qs, timestamp=T0,
+                               runner=BatchRunner())
+    # concurrency=1: the host executor otherwise scans day partitions
+    # on concurrent threads, which is not a serial walk
+    host = run_query_collect(storage, [TEN],
+                             "options(concurrency=1) " + qs,
+                             timestamp=T0)
     monkeypatch.setenv("VL_INFLIGHT", "4")
     monkeypatch.setenv("VL_PACK_PARTS", "8")
-    monkeypatch.setenv("VL_CROSS_PARTITION", "1")
     windowed = run_query_collect(storage, [TEN], qs, timestamp=T0,
                                  runner=BatchRunner())
-    assert serial == windowed
+    assert serial == windowed == host
 
 
 def test_window_depth_survives_partition_boundary(storage, monkeypatch):
-    """THE satellite pin: submit_prefetch/window depth was lost at
-    every partition boundary (the window drained to zero before the
-    next day started).  With the global window, a 3-partition run must
-    fill the whole VL_INFLIGHT window; the per-partition drain
-    (VL_CROSS_PARTITION=0) provably cannot exceed the per-day unit
-    count."""
+    """The window does not drain at a partition boundary: a day holds
+    PARTS_PER_DAY/2 = 2 units here, so a window that fills to
+    VL_INFLIGHT = 4 holds units of more than one day at once."""
     qs = 'err | stats count() c'
     monkeypatch.setenv("VL_INFLIGHT", "4")
     monkeypatch.setenv("VL_PACK_PARTS", "2")   # 2 units per partition
-    monkeypatch.setenv("VL_CROSS_PARTITION", "0")
-    drained = BatchRunner()
-    run_query_collect(storage, [TEN], qs, timestamp=T0, runner=drained)
-    # per-partition drain: at most PARTS_PER_DAY/2 units ever in flight
-    assert drained.inflight_hwm <= PARTS_PER_DAY // 2
-    monkeypatch.setenv("VL_CROSS_PARTITION", "1")
     globed = BatchRunner()
     run_query_collect(storage, [TEN], qs, timestamp=T0, runner=globed)
-    # 6 units through a 4-window: the window FILLS to VL_INFLIGHT —
-    # the boundary no longer drains it
-    assert globed.inflight_hwm == 4 > drained.inflight_hwm
+    # 6 units through a 4-window: the window FILLS to VL_INFLIGHT
+    assert globed.inflight_hwm == 4 > PARTS_PER_DAY // 2
     assert _norm(run_query_collect(storage, [TEN], qs, timestamp=T0,
                                    runner=globed)) == \
         _norm(run_query_collect(storage, [TEN], qs, timestamp=T0))
@@ -295,32 +282,6 @@ def test_explain_units_span_partitions(storage, monkeypatch):
     assert tree["mode"] == "analyze"
     assert tree["actual"]["dispatches_submitted"] == len(units)
     assert any("actual" in u for u in units)
-
-
-def test_explain_analyze_compat_mode_grafts_per_partition(storage,
-                                                          monkeypatch):
-    """VL_CROSS_PARTITION=0 restarts the executed unit sequence per
-    partition: analyze must fall back to per-partition span matching
-    (a partition's i-th planned unit is its i-th executed unit) and
-    still graft actuals instead of dropping them all on the seq
-    collisions."""
-    from victorialogs_tpu.logsql.parser import parse_query
-    from victorialogs_tpu.obs import explain
-    monkeypatch.setenv("VL_INFLIGHT", "4")
-    monkeypatch.setenv("VL_PACK_PARTS", "1")
-    monkeypatch.setenv("VL_CROSS_PARTITION", "0")
-    runner = BatchRunner()
-    q = parse_query('err | stats count() c', T0)
-    tree = explain.build_plan(storage, [TEN], q, runner=runner)
-    explain.analyze(storage, [TEN], q, tree, runner=runner)
-    for pnode in tree["partitions"]:
-        units = pnode["units"]
-        assert units
-        # every partition's units carry grafted actuals, first included
-        assert all("actual" in u for u in units), pnode["day"]
-        assert all("dispatch_rtt_s" in u["actual"] or
-                   u["actual"].get("host_unit") or "rows" in u["actual"]
-                   for u in units)
 
 
 def test_filter_index_rebuild(tmp_path, monkeypatch):

@@ -2,8 +2,8 @@
 (native/vlnative.cpp vl_emit_ndjson over BlockResult.emit_columns) must
 be BYTE-IDENTICAL to the per-row path (dict per row + json.dumps with
 ensure_ascii=False and (",", ":") separators) on every storage column
-type and every escape class — VL_NATIVE_EMIT=0/1 x VL_FUSED_FILTER=0/1
-matrix over the HTTP query path, plus randomized round-trips through
+type and every escape class — VL_NATIVE_EMIT=0/1 over the HTTP query
+path, plus randomized round-trips through
 json.loads."""
 
 import json
@@ -94,12 +94,10 @@ QUERIES = [
 ]
 
 
-@pytest.mark.parametrize("fused", ["1", "0"])
-def test_native_vs_python_http_matrix(storage, monkeypatch, fused):
-    """Acceptance matrix: byte-identical NDJSON under VL_NATIVE_EMIT=0/1
-    and VL_FUSED_FILTER=0/1, CPU executor and device runner."""
+def test_native_vs_python_http_matrix(storage, monkeypatch):
+    """Acceptance matrix: byte-identical NDJSON under VL_NATIVE_EMIT=0/1,
+    CPU executor and device runner."""
     from victorialogs_tpu.tpu.batch import BatchRunner
-    monkeypatch.setenv("VL_FUSED_FILTER", fused)
     runner = BatchRunner()
     for q in QUERIES:
         outs = {}

@@ -108,23 +108,6 @@ def test_a_stripe_under_a_mesh_axis_runs_the_body_directly(
     assert "all-reduce" in text
 
 
-def test_a_column_xla_partitions_is_told_apart(topo, as_on_the_chip):
-    """The unfused mesh path hands the jitted scan a column striped
-    over devices: Mosaic cannot be partitioned, so the packed entry
-    points look at the placed array and ask for the direct launcher."""
-    mesh = Mesh(np.array(topo.devices).reshape(4), ("blocks",))
-    lanes, lens = _shapes(128, 1 << 20,
-                          NamedSharding(mesh, P(None, "blocks")),
-                          NamedSharding(mesh, P("blocks")))
-    pat = jax.ShapeDtypeStruct((17,), jnp.uint8,
-                               sharding=NamedSharding(mesh, P()))
-    args = (lanes, lens, pat, 17, K.MODE_PHRASE, True, True, False)
-    text = K32._scan_packed.lower(*args, True).compile().as_text()
-    assert "tpu_custom_call" not in text
-    with pytest.raises(NotImplementedError, match="shard_map"):
-        K32._scan_packed.lower(*args, False)
-
-
 @pytest.mark.parametrize("query,kernels", [
     ('_time:[2025-07-28T00:02:00Z, 2025-07-28T00:17:00Z) "deadline '
      'exceeded" | stats by (_time:5m) count() c', 1),

@@ -3,7 +3,7 @@ pipeline, bit-identical results with tracing on/off, no open spans on
 cancellation/deadline unwinds, ?trace=1 JSON round-trips over HTTP,
 Prometheus exposition validity (parsed), occupancy/cost gauges, the
 slow-query log, and the disabled path's zero-span/zero-ish overhead
-bound (under VL_FUSED_FILTER on and off)."""
+bound."""
 
 import json
 import http.client
@@ -186,10 +186,7 @@ def test_trace_no_open_spans_after_deadline(storage, runner):
 
 # ---------------- disabled-path overhead ----------------
 
-@pytest.mark.parametrize("fused", ["1", "0"])
-def test_disabled_trace_is_zero_span_and_cheap(storage, runner, fused,
-                                               monkeypatch):
-    monkeypatch.setenv("VL_FUSED_FILTER", fused)
+def test_disabled_trace_is_zero_span_and_cheap(storage, runner):
     q = 'error | fields _time'
     run_query_collect(storage, [TEN], q, runner=runner)  # warm
     before = tracing.spans_created()

@@ -86,7 +86,7 @@ def test_prefetch_stages_next_part(storage):
     runner = BatchRunner()
     runner.submit_prefetch(part, q.filter, spec)
     runner._prefetch_pool.shutdown(wait=True)
-    assert runner.cache.contains((part.uid, "_msg"))
+    assert runner.cache.contains((part.uid, "#fl", "_msg"))
     assert runner.cache.contains((part.uid, "#num", "dur"))
     assert any(k[:2] == (part.uid, "#tb")
                for k in runner.cache._lru)

@@ -262,21 +262,6 @@ def test_pack_declines_fall_back_per_member(storage, monkeypatch):
     assert runner.packed_dispatches == 0
 
 
-def test_fused_filter_killswitch(storage, monkeypatch):
-    """VL_FUSED_FILTER=0 restores the per-leaf row path inside each
-    unit; results stay identical and no filter dispatch is counted."""
-    monkeypatch.setenv("VL_INFLIGHT", "4")
-    monkeypatch.setenv("VL_PACK_PARTS", "1")
-    monkeypatch.setenv("VL_FUSED_FILTER", "0")
-    runner = BatchRunner()
-    for qs in ROW_QUERIES:
-        cpu = run_query_collect(storage, [TEN], qs, timestamp=T0)
-        dev = run_query_collect(storage, [TEN], qs, timestamp=T0,
-                                runner=runner)
-        assert _norm(cpu) == _norm(dev), qs
-    assert runner.filter_dispatches == 0
-
-
 def test_pipeline_mesh_runner(storage, monkeypatch):
     """The windowed/packed pipeline over the 8-device CPU mesh: packed
     super-dispatches run SPMD (shard_map filter + psum stats) with the

@@ -65,7 +65,7 @@ _SYNC_CASTS = {"float", "int", "bool"}
 # _fused_local, ...) are not on it: a jnp call there is traced once.
 SUBMIT_PATH = {
     "tpu/fused.py": re.compile(
-        r"^(_Planner\.\w+|fused_\w+_submit|try_fused_topk"
+        r"^(_Planner\.\w+|fused_\w+_submit"
         r"|_stage_cand_mask|_launch)$"),
     "tpu/pipeline.py": re.compile(
         r"^(_submit\w*|_host_members|_count_pack|_get_pack"

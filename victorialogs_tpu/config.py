@@ -189,21 +189,11 @@ declare_env(
     "so cost grows with pack_size * k); `0` = sort-topk packing off "
     "(`tpu/pipeline.py`)")
 declare_env(
-    "VL_CROSS_PARTITION", "1", "flag",
-    "`0` = kill-switch for the cross-partition dispatch window: the "
-    "device pipeline drains at every day-partition boundary like "
-    "pre-PR-15 (per-partition prefetch depth, no boundary-spanning "
-    "packs — `engine/searcher.py`, `tpu/pipeline.py`)")
-declare_env(
     "VL_PACK_MAX_ROWS", None, "int",
     "parts above this many rows never pack; default scales with the "
     "measured dispatch RTT (floor 16k rows, cap 1M — flush-sized parts "
     "always pack, big parts only when the RTT dwarfs their scan)",
     display="adaptive")
-declare_env(
-    "VL_FUSED_FILTER", "1", "flag",
-    "`0` = row queries use the round-3 per-leaf dispatch path instead "
-    "of the single fused filter program")
 declare_env(
     "VL_DEVICE_BLOOM", "1", "flag",
     "`0` = bloom keep-masks stay host-side instead of probing "

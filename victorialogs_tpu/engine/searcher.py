@@ -7,8 +7,9 @@ through the filter tree, and feeds resulting batches through the pipe
 processor chain with per-pipe cancellation (storage_search.go:102-185,
 1035-1121).
 
-The per-block scan dispatches to the TPU runner when enabled (tpu/batch.py);
-this module stays the correctness oracle and the fallback path.
+With a runner the selected parts go through the device dispatch window
+(tpu/pipeline.py); this module stays the correctness oracle and the host
+executor every gated or declined part falls back to.
 """
 
 from __future__ import annotations
@@ -267,15 +268,14 @@ def _run_query_guarded(storage, tenants, q, write_block, timestamp,
     # per-bucket partials straight into the stats processor
     # (tpu/stats_device.py; reference pipe_stats.go:354-377)
     stats_spec = None
-    if runner is not None and hasattr(runner, "run_part_stats"):
+    if runner is not None:
         from ..tpu.stats_device import device_stats_spec
         stats_spec = device_stats_spec(q)
 
     # device sort-topk prefilter: `<filter> | sort by (f) limit N` keeps
     # only rows at-or-above each part's k-th best key (tpu/sort_device.py)
     sort_spec = None
-    if stats_spec is None and runner is not None and \
-            hasattr(runner, "run_part_topk"):
+    if stats_spec is None and runner is not None:
         from ..tpu.sort_device import device_sort_spec
         sort_spec = device_sort_spec(q)
 
@@ -300,12 +300,11 @@ def _run_query_guarded(storage, tenants, q, write_block, timestamp,
     token_leaves = list(iter_and_path_token_leaves(q.filter))
 
     tenant_set = set(tenants)
-    batch = runner is not None and hasattr(runner, "run_part")
     # CPU-path block workers (reference spawns GetConcurrency() workers
     # over a 64-block channel — storage_search.go:1035-1067; numpy/zstd
     # release the GIL, so threads overlap real work).  One pool is SHARED
     # across partitions so total workers stay bounded.
-    nworkers = 1 if batch else q.get_concurrency()
+    nworkers = 1 if runner is not None else q.get_concurrency()
     pool = None
     if nworkers > 1:
         from concurrent.futures import ThreadPoolExecutor
@@ -322,22 +321,20 @@ def _run_query_guarded(storage, tenants, q, write_block, timestamp,
                 if not allowed_sids:
                     psp.set("pruned_by_stream_filter", True)
                     return
-            _scan_parts(pt, q, sink_head, runner, batch, tenant_set,
-                        allowed_sids, min_ts, max_ts, ctx, needed,
-                        deadline, pool, stats_spec, sort_spec,
+            _scan_parts(pt, q, sink_head, tenant_set, allowed_sids,
+                        min_ts, max_ts, ctx, needed, deadline, pool,
                         token_leaves, qcache)
 
     try:
         pts = storage.select_partitions(min_ts, max_ts)
-        if batch and _cross_partition_enabled():
+        if runner is not None:
             # device path: ONE dispatch window across every selected
             # partition (tpu/pipeline.scan_device_stream) — parts from
             # partition N+1 submit while partition N harvests, packs
             # may span the day boundary, and prefetch depth survives
             # it.  The window IS the parallelism here (dispatches from
             # several partitions overlap on the one device), so the
-            # thread-per-partition fan-out below stays host-only.
-            # VL_CROSS_PARTITION=0 restores the per-partition drain.
+            # thread-per-partition fan-out below is the host executor's.
             _scan_partitions_device(
                 pts, q, head, runner, tenants, tenant_set, sfs, min_ts,
                 max_ts, needed, deadline, stats_spec, sort_spec,
@@ -421,11 +418,6 @@ def _scan_partitions_parallel(pts, scan_partition, head, npw) -> None:
         list(ex.map(run_one, pts))
     if errors:
         raise errors[0]
-
-
-def _cross_partition_enabled() -> bool:
-    from ..tpu.pipeline import cross_partition_enabled
-    return cross_partition_enabled()
 
 
 def _make_cand_fn(tenant_set, allowed_sids, min_ts, max_ts):
@@ -520,28 +512,16 @@ def _absorb_stats_partials(head, q, spec, partials) -> None:
         head.absorb_partials(key, states)
 
 
-def _scan_parts(pt, q, head, runner, batch, tenant_set, allowed_sids,
-                min_ts, max_ts, ctx, needed, deadline, pool,
-                stats_spec=None, sort_spec=None,
-                token_leaves=None, qcache=None) -> None:
+def _scan_parts(pt, q, head, tenant_set, allowed_sids, min_ts, max_ts,
+                ctx, needed, deadline, pool, token_leaves,
+                qcache) -> None:
+    """The host executor's walk over one partition's parts."""
     from ..storage.filterbank import (maplet_prune_candidates,
                                       part_aggregate_prunes)
     parts = [p for p in pt.ddb.snapshot_parts()
              if p.num_rows and p.min_ts <= max_ts and p.max_ts >= min_ts]
     cand_block_idxs = _make_cand_fn(tenant_set, allowed_sids, min_ts,
                                     max_ts)
-
-    if batch:
-        # async device pipeline: dispatches for up to VL_INFLIGHT units
-        # stay outstanding, small parts pack into super-dispatches, and
-        # results harvest in submission order — block order and stats
-        # absorb granularity are identical to this serial walk
-        # (tpu/pipeline.py)
-        from ..tpu.pipeline import scan_parts_device
-        scan_parts_device(parts, q, head, runner, cand_block_idxs, ctx,
-                          needed, deadline, stats_spec, sort_spec,
-                          token_leaves, qcache)
-        return
 
     sp = tracing.current_span()
     sp.set("parts", len(parts))
@@ -604,11 +584,8 @@ def _scan_parts(pt, q, head, runner, batch, tenant_set, allowed_sids,
             if pool is not None:
                 cand[bi] = bs
                 continue
-            if runner is not None:
-                bm = runner.apply_filter(q.filter, bs)
-            else:
-                bm = new_bitmap(bs.nrows)
-                q.filter.apply_to_block(bs, bm)
+            bm = new_bitmap(bs.nrows)
+            q.filter.apply_to_block(bs, bm)
             collected[bi] = bm
             if not bm.any():
                 continue

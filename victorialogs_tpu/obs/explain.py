@@ -90,20 +90,16 @@ def _walk(storage, tenants, q, runner, detail: bool) -> dict:
     tenant_set = set(tenants)
     min_ts, max_ts = q.get_time_range()
 
-    batch = runner is not None and hasattr(runner, "run_part")
+    batch = runner is not None
     peek = runner.cost.peek() if batch else dict(_HOST_ONLY_PEEK)
     stats_spec = sort_spec = None
     plans = []
-    fused = False
     if batch:
         from ..tpu.batch import device_plans
-        from ..tpu.fused import fused_filter_enabled
+        from ..tpu.stats_device import device_stats_spec
         plans = device_plans(q.filter)
-        fused = fused_filter_enabled() and runner.fused_enabled
-        if hasattr(runner, "run_part_stats"):
-            from ..tpu.stats_device import device_stats_spec
-            stats_spec = device_stats_spec(q)
-        if stats_spec is None and hasattr(runner, "run_part_topk"):
+        stats_spec = device_stats_spec(q)
+        if stats_spec is None:
             from ..tpu.sort_device import device_sort_spec
             sort_spec = device_sort_spec(q)
     shape = "stats" if stats_spec is not None else \
@@ -126,7 +122,7 @@ def _walk(storage, tenants, q, runner, detail: bool) -> dict:
         "query": q.to_string(),
         "shape": shape,
         "executor": "device" if batch else "host",
-        "fused_filter": bool(fused),
+        "fused_filter": batch,
         "inflight_depth": depth,
         "time_range": {
             "min_ts": None if min_ts == MIN_TS else min_ts,
@@ -152,8 +148,6 @@ def _walk(storage, tenants, q, runner, detail: bool) -> dict:
     qcache = QueryCache.for_query(q, tenants, stats_spec, sort_spec,
                                   min_ts, max_ts)
 
-    from ..tpu import pipeline as _pipeline
-    cross = batch and _pipeline.cross_partition_enabled()
     active_pts = 0
     retained_all: list = []   # (pnode, part, bis, rows_cand, bytes_est)
     for pt in storage.select_partitions(min_ts, max_ts):
@@ -173,11 +167,9 @@ def _walk(storage, tenants, q, runner, detail: bool) -> dict:
     # execution planner — packs may span a day boundary, and the unit
     # seq is global (it matches the window's submit/harvest span
     # numbering, which _graft keys on).  A unit node hangs off the
-    # partition of its FIRST member.  VL_CROSS_PARTITION=0 groups per
-    # partition like the old drain-at-boundary walk did.
+    # partition of its FIRST member.
     _price_units(retained_all, runner, batch, peek, plans, shape,
-                 fused, sort_spec, depth, detail, tot, cost,
-                 per_partition=not cross)
+                 sort_spec, depth, detail, tot, cost)
 
     if not detail:
         tree.pop("partitions")
@@ -187,7 +179,7 @@ def _walk(storage, tenants, q, runner, detail: bool) -> dict:
     # divides by the effective partition parallelism.  The device
     # path's cross-partition window overlaps round trips ACROSS
     # partitions already (depth folded above): no extra parallelism.
-    npw = 1 if cross else max(1, min(active_pts, q.get_concurrency()))
+    npw = 1 if batch else max(1, min(active_pts, q.get_concurrency()))
     duration = sum(cost.values()) / npw
     tree["predicted"] = dict(tot)
     tree["predicted"].update({k: round(v, 6) for k, v in cost.items()})
@@ -393,15 +385,12 @@ def _walk_partition(pt, tenants, tenant_set, min_ts, max_ts, sfs,
 
 
 def _price_units(retained_all, runner, batch, peek, plans, shape,
-                 fused, sort_spec, depth, detail, tot, cost,
-                 per_partition: bool) -> None:
+                 sort_spec, depth, detail, tot, cost) -> None:
     """Group the retained-part stream into planned dispatch units and
     price each one.  retained_all: (pnode, part, bis, rows, bytes)
     tuples in partition-walk order — grouping runs over the WHOLE
-    stream (cross-partition window) or restarts at each partition
-    boundary (per_partition=True, the VL_CROSS_PARTITION=0 walk); the
-    unit seq is global either way, matching the execution window's
-    submit/harvest span numbering."""
+    stream (cross-partition window) and the unit seq is global,
+    matching the execution window's submit/harvest span numbering."""
     from ..tpu import pipeline
     if not retained_all:
         return
@@ -418,35 +407,16 @@ def _price_units(retained_all, runner, batch, peek, plans, shape,
         def groups_of(items):
             return ([it] for it in items)
 
-    def runs():
-        if not per_partition:
-            yield [(p, b) for _pn, p, b, _rc, _be in retained_all]
-            return
-        run: list = []
-        cur = None
-        for pn, p, b, _rc, _be in retained_all:
-            if cur is not None and pn is not cur:
-                yield run
-                run = []
-            cur = pn
-            run.append((p, b))
-        if run:
-            yield run
-
-    seq = 0
-    for run in runs():
-        for group in groups_of(iter(run)):
-            unode = _price_unit(seq, group, by_part, runner, batch,
-                                peek, plans, shape, fused, depth,
-                                cost, tot, detail)
-            seq += 1
-            if detail and unode is not None:
-                pnode_of[group[0][0].uid]["units"].append(unode)
+    stream = ((p, b) for _pn, p, b, _rc, _be in retained_all)
+    for seq, group in enumerate(groups_of(stream)):
+        unode = _price_unit(seq, group, by_part, runner, batch, peek,
+                            plans, shape, depth, cost, tot, detail)
+        if detail and unode is not None:
+            pnode_of[group[0][0].uid]["units"].append(unode)
 
 
 def _price_unit(seq, group, by_part, runner, batch, peek, plans,
-                shape, fused, depth, cost, tot,
-                detail: bool) -> dict | None:
+                shape, depth, cost, tot, detail: bool) -> dict | None:
     from ..tpu import pipeline
 
     rows = sum(by_part[p.uid][0] for p, _b in group)
@@ -459,30 +429,25 @@ def _price_unit(seq, group, by_part, runner, batch, peek, plans,
     stats_rows = rows if shape in ("stats", "topk") else 0
 
     cold = 0
-    n_dispatch = 0
+    # one fused program a unit, as the gate sees it: a tree with a scan
+    # leaf, or a stats / topk shape
+    n_dispatch = 1 if batch and (plans or stats_rows) else 0
     if batch and plans:
         # staging keys are per DISPATCH TARGET: a packed unit stages
         # under the pack's uid (tpu/pipeline PackedPart), not its
         # members' — the cold-bytes estimate must probe the same keys
         uid = ("pack",) + tuple(p.uid for p, _b in group) \
             if len(group) > 1 else group[0][0].uid
-        # same rule as BatchRunner._gate_host_est: once per field,
-        # warm under either staging layout
+        # same rule as BatchRunner._gate_host_est: once per field
         for fld in {plan.field for plan in plans}:
-            if not (runner.cache.contains((uid, fld)) or
-                    runner.cache.contains((uid, "#fl", fld))):
+            if not runner.cache.contains((uid, "#fl", fld)):
                 cold += scan_bytes
-        n_dispatch = 1 if stats_rows or fused else \
-            sum(max(len(p.ops), 1) for p in plans)
-    elif batch and stats_rows:
-        n_dispatch = 1
 
     host = _prefers_host(peek, rows, scan_bytes, n_dispatch, cold,
                          stats_rows)
     kind = "host" if host else (
         "stats" if shape == "stats" else
-        "topk" if shape == "topk" else
-        "fused_filter" if fused else "leaf_filter")
+        "topk" if shape == "topk" else "fused_filter")
 
     # the unit detail node exists only for the explain endpoint; the
     # continuous pricing pass keeps the accounting without the dicts
@@ -622,53 +587,18 @@ def _graft_units(tree, tdict) -> None:
     both times), so matching is tree-wide."""
     submits: dict = {}
     harvests: dict = {}
-    dup = False
     for sp in tracing.iter_tree(tdict, "submit"):
         attrs = sp.get("attrs") or {}
         if "unit" in attrs:
-            dup = dup or attrs["unit"] in submits
             submits[attrs["unit"]] = (sp, attrs)
     for sp in tracing.iter_tree(tdict, "harvest"):
         attrs = sp.get("attrs") or {}
         if "unit" in attrs:
             harvests[attrs["unit"]] = (sp, attrs)
-    if dup:
-        # VL_CROSS_PARTITION=0 restarts the unit sequence at every
-        # partition boundary (submit/harvest spans nest under their
-        # partition span there), so colliding global seqs mean the
-        # compat walk ran: match per partition instead — a partition's
-        # i-th planned unit IS its i-th executed unit
-        _graft_units_compat(tree, tdict)
-        return
     units = [u for pnode in tree.get("partitions", ())
              for u in pnode.get("units", ())]
     for unode in units:
         _attach_actual(unode, submits, harvests, unode.get("seq"))
-
-
-def _graft_units_compat(tree, tdict) -> None:
-    """Per-partition matching for the VL_CROSS_PARTITION=0 walk: each
-    partition span subtree carries its own 0-based unit sequence, and
-    the plan listed that partition's units in the same order."""
-    by_day: dict = {}
-    for psp in tracing.iter_tree(tdict, "partition"):
-        by_day[(psp.get("attrs") or {}).get("day")] = psp
-    for pnode in tree.get("partitions", ()):
-        psp = by_day.get(pnode.get("day"))
-        if psp is None:
-            continue
-        submits: dict = {}
-        harvests: dict = {}
-        for sp in tracing.iter_tree(psp, "submit"):
-            attrs = sp.get("attrs") or {}
-            if "unit" in attrs:
-                submits[attrs["unit"]] = (sp, attrs)
-        for sp in tracing.iter_tree(psp, "harvest"):
-            attrs = sp.get("attrs") or {}
-            if "unit" in attrs:
-                harvests[attrs["unit"]] = (sp, attrs)
-        for i, unode in enumerate(pnode.get("units", ())):
-            _attach_actual(unode, submits, harvests, i)
 
 
 def _attach_actual(unode, submits, harvests, seq) -> None:

@@ -124,69 +124,19 @@ def shard_batch(mesh: Mesh, *arrays):
 
 # ---------------- the multi-chip product runner ----------------
 
-@partial(jax.jit, static_argnames=("num_buckets", "strides", "mesh"))
-def _stats_values_mesh(mesh, values, ids_tuple, strides, mask,
-                       num_buckets):
-    """Sharded stats partials: each device reduces its row shard with the
-    same chunked kernel body, then count/sums ride psum and min/max ride
-    pmin/pmax over ICI — the mesh analogue of the reference's mergeState
-    (pipe_stats.go:354-377)."""
-    @jax.named_scope("stats")
-    def shard_fn(v, ids, m):
-        b = K.combine_ids(ids, strides)
-        cnt, sums, lo, hi = K.stats_values_local(v, b, m, num_buckets,
-                                                 vary_axes=(BLOCK_AXIS,))
-        cnt = jax.lax.psum(cnt, BLOCK_AXIS)
-        sums = jax.lax.psum(sums, BLOCK_AXIS)
-        lo = jax.lax.pmin(lo, BLOCK_AXIS)
-        hi = jax.lax.pmax(hi, BLOCK_AXIS)
-        return K.pack_stats(cnt, sums, lo, hi)
-
-    spec = P(BLOCK_AXIS)
-    return jax.shard_map(
-        shard_fn, mesh=mesh,
-        in_specs=(spec, tuple(spec for _ in ids_tuple), spec),
-        out_specs=P())(values, ids_tuple, mask)
-
-
-@partial(jax.jit, static_argnames=("num_buckets", "strides", "mesh"))
-def _stats_count_mesh(mesh, ids_tuple, strides, mask, num_buckets):
-    @jax.named_scope("stats")
-    def shard_fn(ids, m):
-        b = K.combine_ids(ids, strides)
-        cnt = K.stats_count_local(b, m, num_buckets,
-                                  vary_axes=(BLOCK_AXIS,))
-        return jax.lax.psum(cnt, BLOCK_AXIS)
-
-    spec = P(BLOCK_AXIS)
-    return jax.shard_map(
-        shard_fn, mesh=mesh,
-        in_specs=(tuple(spec for _ in ids_tuple), spec),
-        out_specs=P())(ids_tuple, mask)
-
-
 class MeshBatchRunner(BatchRunner):
     """BatchRunner over a device mesh: the PRODUCT multi-chip query path.
 
-    Staged arrays (string matrices, numeric columns, bucket ids, masks)
-    are device_put with their row axis sharded over the mesh, so:
-    - filter scans (match_scan & friends) compile SPMD under jit — each
-      device scans its row stripe, no collectives needed (the bitmap
-      gathers on download);
-    - stats partials run under shard_map with psum/pmin/pmax over ICI and
-      only the (7, buckets) reduced result reaches the host.
+    Staged arrays (string planes, numeric columns, bucket ids, masks)
+    are device_put with their row axis sharded over the mesh, and the
+    fused programs run SPMD: shard_mapped over the row axis, so a fused
+    query is ONE collective dispatch across the whole mesh.  Each device
+    scans its row stripe; stats partials ride psum/pmin/pmax over ICI
+    and only the (7, buckets) reduced result reaches the host.
 
     Single-device behavior is identical to BatchRunner (the sharding
     degenerates); engine.searcher drives both through the same interface.
     """
-
-    # the fused single-dispatch path runs SPMD here: the program is
-    # shard_mapped over the row axis with psum'd partials (ICI), so a
-    # fused query is ONE collective dispatch across the whole mesh
-    fused_enabled = True
-    # always reduce on device: the point of the mesh runner is that
-    # partials ride psum over ICI, however small the shard's share
-    stats_host_threshold = 0
 
     def __init__(self, mesh: Mesh | None = None, **kw):
         mesh = mesh if mesh is not None else make_mesh()
@@ -269,12 +219,3 @@ class MeshBatchRunner(BatchRunner):
         self._trace_collective()
         return filter_mesh_program(name)(self.mesh, BLOCK_AXIS, prog,
                                          blk, cand_packed, args)
-
-    def _dispatch_stats_count(self, ids_tuple, strides, mask, nb):
-        return np.array(_stats_count_mesh(self.mesh, ids_tuple, strides,
-                                          mask, nb))
-
-    def _dispatch_stats_values(self, values, ids_tuple, strides, mask,
-                               nb):
-        return np.array(_stats_values_mesh(self.mesh, values, ids_tuple,
-                                           strides, mask, nb))

@@ -1,29 +1,30 @@
-"""Batched TPU execution: ONE device dispatch per filter leaf per part.
+"""Part-at-a-time device execution: leaf planning, staging, the host
+gate and the runner the dispatch window drives.
 
-Round-1's BlockRunner dispatched one kernel per block per leaf with a
-synchronous download each time, so an 8M-row query paid thousands of
-dispatch round trips on the device path.  This
-module is the production path instead: a part's string column is staged into
-HBM ONCE as a single fixed-width (rows, W) uint8 matrix covering every block
-(parts are immutable, so the staging is cached across queries), and each
-device-capable filter leaf becomes one `match_scan` dispatch over the whole
-matrix, downloaded as one bool vector and sliced per block on the host.
+A query's filter tree is planned per leaf (`device_plan`: the scan ops,
+bloom tokens and field of one leaf) and compiled whole by the fused
+planner (tpu/fused.py) into ONE device program a part or pack: filter,
+filter|stats or filter|sort-topk.  A part's columns are staged into HBM
+once in the stats layout's row coordinates and cached across queries
+(parts are immutable).  There is one device path:
 
-This mirrors the reference's batched scanning (64-block batches per worker —
+    host gate -> host executor
+    fused planner -> one fused / topk / filter program
+    planner declines -> host executor
+
+The host executor is `_host_eval_blocks`, the CPU path's own per-block
+filter evaluation, so a gated or declined part's answer is the
+reference's by construction; the parity tests in tests/test_batch_runner.py
+and tests/test_decline_to_host.py diff the device programs against it
+bit-exactly.  Bloom pruning stays on the kill-path before staging
+(filter_phrase.go:302 analogue) as one batched plane probe a (part,
+column) through the filter-index subsystem (storage/filterbank.py +
+tpu/bloom_device.py); rows longer than the staging width are truncated
+on device and settled on the host with the filter's full predicate.
+
+This mirrors the reference's batched scanning (64-block batches per worker:
 lib/logstorage/block_search.go:16, storage_search.go:1035-1121) reshaped for
-a dispatch-latency-bound accelerator: fewer, bigger kernels win.
-
-Filter-tree semantics are identical to the CPU path (the parity tests in
-tests/test_tpu_runner.py and tests/test_batch_runner.py diff them bit-exactly):
-- AND children evaluate left-to-right with block-level early exit;
-- bloom pruning stays on the kill-path BEFORE staging
-  (filter_phrase.go:302 analogue), but runs as one batched plane probe
-  per (part, column) via the filter-index subsystem
-  (storage/filterbank.py + tpu/bloom_device.py), not per block;
-- rows longer than the staging width are truncated on device and re-checked
-  on the host with the filter's full predicate;
-- regex runs its mandatory-literal substring prefilter on device and
-  re.search on the survivors only (filter_regexp.go:44-51 analogue).
+a dispatch-latency-bound accelerator: fewer, bigger programs win.
 """
 
 from __future__ import annotations
@@ -37,18 +38,14 @@ import weakref
 
 import numpy as np
 
-from ..engine.block_search import BlockSearch
 from .. import config
 from ..logsql import filters as F
-from ..obs import hist
 from ..storage.filterbank import bloom_keep_mask
 from ..storage.values_encoder import VT_DICT, VT_STRING
 from ..utils.hashing import cached_token_hashes
 from . import compile_stats
 from . import kernels as K
-from . import kernels32 as K32
-from .layout import StagingCache, row_width_bucket
-from .kernels import pad_bucket
+from .layout import StagingCache
 
 
 # ---------------- leaf planning ----------------
@@ -238,103 +235,7 @@ def _contains_plan(f, require_all: bool) -> LeafPlan | None:
                     "and" if require_all else "or", tokens)
 
 
-# ---------------- part-level staging ----------------
-
-@dataclass
-class StagedPart:
-    rows: object                   # jax uint32[W/4, Rb/128, 128] planes (kernels32)
-    lengths: object                # jax int32[Rb]
-    lengths_np: np.ndarray         # host copy (truncated at W-1)
-    nrows: int                     # real staged rows
-    width: int
-    block_rows: dict               # block_idx -> (start, nrows)
-    overflow: dict                 # block_idx -> np.ndarray of row idxs
-    nbytes: int
-
-    def device_bytes(self) -> int:
-        return self.nbytes
-
-
 _UNSTAGEABLE = object()  # cache marker: part+field can't be staged
-
-
-def _row_accessor(bs: BlockSearch, field: str):
-    """Per-row string access without materializing the whole column.
-
-    Host verification touches only surviving rows; decoding the full
-    block's value list (bs.values) wasted most of the device path's win
-    on verify-heavy regex queries."""
-    if field not in ("_time", "_stream", "_stream_id") and \
-            field not in bs.consts():
-        col = bs.column(field)
-        if col is not None and col.vtype == VT_STRING:
-            arena, offs, lens = col.arena, col.offsets, col.lengths
-
-            def at(i: int) -> str:
-                o = int(offs[i])
-                return arena[o:o + int(lens[i])].tobytes().decode(
-                    "utf-8", "replace")
-            return at
-    vals = bs.values(field)
-    return vals.__getitem__
-
-
-def stage_part_column(part, field: str,
-                      max_bytes: int = 4 << 30,
-                      put=None) -> StagedPart | None:
-    """Stage every string-typed block of `field` in one (Rb, W) matrix.
-
-    Blocks whose column is missing/const/dict/numeric are left out (the
-    evaluator runs those on the host).  Returns None when nothing is
-    stageable or the staged matrix would exceed max_bytes.
-    put: host->device transfer (default jnp.asarray); a mesh runner passes
-    a sharding device_put so the rows axis spreads over its devices."""
-    import jax.numpy as jnp
-    if put is None:
-        def put(a, row_axis=0):
-            return jnp.asarray(a)
-
-    cols = {}
-    total = 0
-    max_len = 0
-    for bi in range(part.num_blocks):
-        col = part.block_column(bi, field)
-        if col is None or col.vtype != VT_STRING:
-            continue
-        cols[bi] = col
-        total += part.block_rows(bi)
-        if col.lengths.size:
-            max_len = max(max_len, int(col.lengths.max()))
-    if not cols:
-        return None
-    w = row_width_bucket(max_len)
-    # whole (8, 128) tiles of rows a plane (layout.to_lanes32), which also
-    # stripe over a mesh of up to eight devices
-    rb = -(-pad_bucket(max(total, 1), minimum=1024) // 1024) * 1024
-    if rb * (w + 4) > max_bytes:
-        return None
-    mat = np.full((rb, w), 0xFF, dtype=np.uint8)
-    lens = np.zeros(rb, dtype=np.int32)
-    block_rows = {}
-    overflow = {}
-    start = 0
-    from .layout import to_fixed_width
-    for bi, col in cols.items():
-        r = int(col.offsets.shape[0])
-        sub, _w, ov = to_fixed_width(col.arena, col.offsets, col.lengths,
-                                     r, width=w)
-        mat[start:start + r] = sub
-        lens[start:start + r] = np.minimum(col.lengths, w - 1).astype(np.int32)
-        block_rows[bi] = (start, r)
-        if ov.size:
-            overflow[bi] = ov
-        start += r
-    from .layout import to_lanes32
-    return StagedPart(rows=put(to_lanes32(mat), row_axis=1),
-                      lengths=put(lens),
-                      lengths_np=lens, nrows=start, width=w,
-                      block_rows=block_rows, overflow=overflow,
-                      nbytes=rb * (w + 4))
 
 
 # ---------------- stats staging (device partials) ----------------
@@ -353,8 +254,8 @@ def _int_vtypes():
 
 @dataclass
 class StatsLayout:
-    """Canonical whole-part row layout for stats dispatches: every block in
-    index order (unlike string staging, which skips non-string blocks)."""
+    """Canonical whole-part row layout of every staged column: every
+    block in index order."""
     starts: dict                   # block_idx -> row start
     nrows: int                     # real rows
     nrows_padded: int              # STATS_CHUNK multiple
@@ -944,17 +845,13 @@ def live_prefetch_pools() -> int:
 
 
 class BatchRunner:
-    """Part-at-a-time filter evaluation with one dispatch per device leaf.
+    """Part-at-a-time evaluation: one fused device program a part (or
+    pack), or the host executor where the cost gate or the fused planner
+    says so.
 
-    Exposes run_part() (used by engine.searcher.run_query when present) and
-    a per-block apply_filter() shim for callers holding one BlockSearch."""
-
-    # single-dispatch filter->stats fusion (tpu/fused.py); MeshBatchRunner
-    # keeps its shard_map stats path instead
-    fused_enabled = True
-    # below this many matched rows the unfused stats path hands the rows
-    # to the host pipe instead of paying an upload + dispatch round trip
-    stats_host_threshold = 1024
+    The dispatch window (tpu/pipeline.py) drives the run_part*_submit
+    handles; run_part*() are the synchronous front of the same path
+    (the members of a pack that declined)."""
 
     def __init__(self, max_cache_bytes: int | None = None,
                  max_part_bytes: int | None = None, devices=None):
@@ -978,7 +875,6 @@ class BatchRunner:
         self.cache = StagingCache(max_cache_bytes)
         self.max_part_bytes = max_part_bytes
         self.cost = CostModel()
-        self._scan_sigs: set = set()   # jit signatures already compiled
         self.device_calls = 0          # every dispatch issued to the device
         self.plane_scan_leaves = 0     # scan and `A.*B` leaves those
         #                                dispatches ran through the plane
@@ -986,7 +882,8 @@ class BatchRunner:
         self.operand_blocks = 0        # fused/topk/filter dispatches that
         #                                shipped their host operands as
         #                                one block (tpu/fused.py:_launch)
-        self.cpu_fallbacks = 0
+        self.cpu_fallbacks = 0         # parts the fused planner declined:
+        #                                evaluated by the host executor
         self.gated_host_parts = 0
         self.stats_dispatches = 0
         self.fused_dispatches = 0
@@ -1155,8 +1052,7 @@ class BatchRunner:
 
     # ---- prefetch (stage part N+k while parts N..N+k-1 scan) ----
     def submit_prefetch(self, part, f, stats_spec=None,
-                        cand_bis=None, fused=False,
-                        sort_field=None) -> None:
+                        cand_bis=None, sort_field=None) -> None:
         """Queue background staging of what the query will need from
         `part`, so the host decode/upload of UPCOMING parts overlaps the
         device scans of the current ones (SURVEY §7 hard-part 3).  The
@@ -1170,10 +1066,8 @@ class BatchRunner:
         fraction takes the host path instead of staging).
         cand_bis: candidate block idxs (after tenant/stream/time
         pruning); None means every block is a candidate.
-        fused=True stages for the single-dispatch fused programs
-        (layout-coordinate columns + timestamp planes — what the
-        windowed pipeline dispatches, including packed super-parts)
-        instead of the per-leaf string staging.
+        Stages what the fused programs read: layout-coordinate columns
+        and timestamp planes, for packed super-parts too.
         sort_field: the sort-topk by-column — its uint32 value staging
         (the fused topk dispatch's score operand) uploads ahead like
         the stats value columns."""
@@ -1191,7 +1085,7 @@ class BatchRunner:
                 with tracing.use_span(caller_span), \
                         activity.use_activity(caller_act):
                     self._prefetch_work(part, f, stats_spec, cand_bis,
-                                        fused, sort_field)
+                                        sort_field)
             # vlint: allow-broad-except(prefetch is best-effort)
             except Exception:
                 pass  # prefetch is best-effort; the scan path re-stages
@@ -1201,7 +1095,7 @@ class BatchRunner:
             pass  # pool closed between return and submit; best-effort
 
     def _prefetch_work(self, part, f, stats_spec, cand_bis,
-                       fused, sort_field=None) -> None:
+                       sort_field) -> None:
         bis = list(cand_bis) if cand_bis is not None else \
             list(range(part.num_blocks))
         cand_rows = sum(part.block_rows(bi) for bi in bis)
@@ -1210,25 +1104,19 @@ class BatchRunner:
                 stats_rows=cand_rows if stats_spec or sort_field
                 else 0):
             return     # the evaluator will take the host path
-        layout = None
-        if fused:
-            from .stats_device import MAX_STAT_ROWS
-            layout = self._stats_layout(part)
-            if layout.nrows > MAX_STAT_ROWS:
-                layout = None
-            elif _tree_has_time(f):
-                self._stage_ts_planes(part, layout)
-        if sort_field is not None and layout is not None:
-            # the topk score operand (fused_topk_submit's staging key).
-            # A decline (non-numeric sort column for this part) means
-            # the evaluator will decline the fused topk too and fall
-            # back to per-leaf string scans — revert THIS part's
-            # prefetch to the classic string staging instead of
-            # uploading #fl matrices the dispatch will never read.
-            from .stats_device import MAX_ABS_TIMES_ROWS
-            if self._stage_numeric(part, sort_field, layout,
-                                   MAX_ABS_TIMES_ROWS) is None:
-                layout = None
+        from .stats_device import MAX_ABS_TIMES_ROWS, MAX_BUCKETS, \
+            MAX_STAT_ROWS
+        layout = self._stats_layout(part)
+        if layout.nrows > MAX_STAT_ROWS:
+            return     # every fused family declines: the host evaluates
+        if _tree_has_time(f):
+            self._stage_ts_planes(part, layout)
+        if sort_field is not None:
+            # the topk score operand (fused_topk_submit's staging key);
+            # where the column is not numeric here the topk program
+            # declines and the filter program reads the same columns
+            self._stage_numeric(part, sort_field, layout,
+                                MAX_ABS_TIMES_ROWS)
         for plan in device_plans(f):
             surv = bis
             if plan.bloom_tokens:
@@ -1244,25 +1132,11 @@ class BatchRunner:
             if not surv:
                 continue
             cand_rows = sum(part.block_rows(bi) for bi in surv)
-            if layout is not None:
-                # fused staging key (#fl) mirrors _scan_leaf's
-                # narrowness gate
-                if self.cache.contains(
-                        (part.uid, "#fl", plan.field)) or \
-                        cand_rows * 8 >= part.num_rows:
-                    self._stage_fused_field(part, plan.field,
-                                            layout)
-                continue
-            if not self.cache.contains((part.uid, plan.field)) \
-                    and cand_rows * 8 < part.num_rows:
-                continue  # evaluator will take the host path
-            self.stage_part(part, plan.field)
+            # mirrors _scan_leaf's narrowness gate
+            if self.cache.contains((part.uid, "#fl", plan.field)) or \
+                    cand_rows * 8 >= part.num_rows:
+                self._stage_fused_field(part, plan.field, layout)
         if stats_spec is not None:
-            from .stats_device import MAX_ABS_TIMES_ROWS, \
-                MAX_BUCKETS, MAX_STAT_ROWS
-            layout = self._stats_layout(part)
-            if layout.nrows > MAX_STAT_ROWS:
-                return
             for fld in stats_spec.value_fields:
                 self._stage_numeric(part, fld, layout,
                                     MAX_ABS_TIMES_ROWS)
@@ -1300,7 +1174,7 @@ class BatchRunner:
                 np.zeros(shape, dtype=dtype)))
         return got
 
-    # ---- stats dispatch hooks (MeshBatchRunner shard_maps + psum-reduces)
+    # ---- dispatch hooks (MeshBatchRunner shard_maps + psum-reduces)
     # `name`: the program's name (fused.program_name), under which the
     # one jitted callable of that kind is looked up
     def _dispatch_fused(self, name, prog, strides, nb, n_values, blk,
@@ -1320,62 +1194,6 @@ class BatchRunner:
     def _dispatch_filter(self, name, prog, blk, cand_packed, args):
         from .fused import filter_program
         return filter_program(name)(prog, blk, cand_packed, args)
-
-    def _dispatch_stats_count(self, ids_tuple, strides, mask, nb):
-        # vlint: allow-jax-host-sync(result readback at dispatch boundary)
-        return np.array(K.stats_bucket_count(ids_tuple, strides, mask,
-                                             nb))
-
-    def _dispatch_stats_values(self, values, ids_tuple, strides, mask,
-                               nb):
-        # vlint: allow-jax-host-sync(result readback at dispatch boundary)
-        return np.array(K.stats_bucket_values(values, ids_tuple, strides,
-                                              mask, nb))
-
-    # ---- staging (cached across queries; parts are immutable) ----
-    def stage_part(self, part, field: str) -> StagedPart | None:
-        key = (part.uid, field)
-        with self._key_lock(key):
-            got = self.cache.get(key)
-            if got is _UNSTAGEABLE:
-                return None
-            if got is not None:
-                return got
-            spc = stage_part_column(part, field, self.max_part_bytes,
-                                    put=self._put)
-            if spc is None:
-                self.cache.put_small(key, _UNSTAGEABLE)
-                return None
-            self.cache.put(key, spc)
-            return spc
-
-    def _stage_nonascii(self, part, field: str) -> dict:
-        """block_idx -> row idxs whose SOURCE value has a byte >= 0x80,
-        for string-typed blocks.  Computed lazily on first use by a
-        case-fold leaf (most queries never pay for it) and cached per
-        (part, field)."""
-        key = (part.uid, "#na", field)
-        with self._key_lock(key):
-            got = self.cache.get(key)
-            if got is None:
-                from .layout import rows_with_multibyte
-                na = {}
-                for bi in range(part.num_blocks):
-                    col = part.block_column(bi, field)
-                    if col is None or col.vtype != VT_STRING:
-                        continue
-                    idx = np.nonzero(rows_with_multibyte(
-                        col.arena, col.offsets, col.lengths))[0]
-                    if idx.size:
-                        na[bi] = idx
-                got = na
-                self.cache.put_small(key, got)
-            return got
-
-    # ---- per-block compatibility shim ----
-    def apply_filter(self, f, bs: BlockSearch) -> np.ndarray:
-        out = self.run_part(f, bs.part, {bs.block_idx: bs})
-        return out[bs.block_idx]
 
     # ---- cost gate (device must never lose to the CPU executor) ----
     def _gate_host(self, f, part, bss: dict, stats_rows: int = 0) -> bool:
@@ -1399,34 +1217,37 @@ class BatchRunner:
             return self.cost.prefer_host(0, cand_rows * 8, 1, 0,
                                          stats_rows=stats_rows)
         scan_bytes = cand_rows * 128        # W estimate; fidelity is low
-        # cold upload: once per FIELD not yet staged under either layout
-        # (the fused path stages under "#fl"; pricing per leaf against
-        # the per-leaf key alone charged a warm fused column as cold, and
-        # a column three leaves scan as three uploads — enough to send a
+        # cold upload: once per FIELD not yet staged (a column three
+        # leaves scan is one upload, not three — enough to send a
         # 72k-row pack to the host at the chip's 1 ms round trip)
         cold = 0
         for fld in {plan.field for plan in plans}:
-            if not (self.cache.contains((part.uid, fld)) or
-                    self.cache.contains((part.uid, "#fl", fld))):
+            if not self.cache.contains((part.uid, "#fl", fld)):
                 cold += scan_bytes
         n_dispatch = 1 if stats_rows else \
             sum(max(len(p.ops), 1) for p in plans)
         return self.cost.prefer_host(cand_rows, scan_bytes, n_dispatch,
                                      cold, stats_rows=stats_rows)
 
-    def _host_eval_part(self, f, bss: dict) -> dict:
+    @staticmethod
+    def _host_eval_blocks(f, bss: dict) -> dict:
         """The CPU executor's own per-block path (native scans inside the
-        filters); timed to keep the cost model's host rate honest."""
-        import time
-        t0 = time.perf_counter()
+        filters)."""
         out = {}
-        rows = 0
         for bi, bs in bss.items():
             bm = np.ones(bs.nrows, dtype=bool)
             f.apply_to_block(bs, bm)
             out[bi] = bm
-            rows += bs.nrows
-        self.cost.observe_host_scan(rows, time.perf_counter() - t0)
+        return out
+
+    def _host_eval_part(self, f, bss: dict) -> dict:
+        """A part the gate sent to the host executor; timed to keep the
+        cost model's host rate honest."""
+        import time
+        t0 = time.perf_counter()
+        out = self._host_eval_blocks(f, bss)
+        self.cost.observe_host_scan(sum(bs.nrows for bs in bss.values()),
+                                    time.perf_counter() - t0)
         return out
 
     # ---- part-level evaluation ----
@@ -1435,150 +1256,18 @@ class BatchRunner:
 
         bss: block_idx -> BlockSearch (with .ctx set for stream filters).
         Returns block_idx -> bool bitmap, bit-identical to the CPU path."""
-        if self._gate_host(f, part, bss):
-            self._bump("gated_host_parts")
-            return self._host_eval_part(f, bss)
-        return self._run_part_device(f, part, bss)
+        return self.run_part_submit(f, part, bss).harvest()
 
-    def _run_part_device(self, f, part, bss: dict) -> dict:
-        """run_part past the host gate (run_part_submit's fused-decline
-        fallback lands here directly — its gate already ran)."""
-        return self._eval(f, part, bss, list(bss))
-
-    def _eval(self, f, part, bss, alive) -> dict:
-        if isinstance(f, F.FilterAnd):
-            acc = {bi: np.ones(bss[bi].nrows, dtype=bool) for bi in alive}
-            cur = list(alive)
-            for sub in f.filters:
-                if not cur:
-                    break
-                sub_bms = self._eval(sub, part, bss, cur)
-                nxt = []
-                for bi in cur:
-                    acc[bi] &= sub_bms[bi]
-                    if acc[bi].any():
-                        nxt.append(bi)
-                cur = nxt
-            return acc
-        if isinstance(f, F.FilterOr):
-            acc = {bi: np.zeros(bss[bi].nrows, dtype=bool) for bi in alive}
-            cur = list(alive)
-            for sub in f.filters:
-                if not cur:
-                    break
-                sub_bms = self._eval(sub, part, bss, cur)
-                nxt = []
-                for bi in cur:
-                    acc[bi] |= sub_bms[bi]
-                    if not acc[bi].all():
-                        nxt.append(bi)
-                cur = nxt
-            return acc
-        if isinstance(f, F.FilterNot):
-            inner = self._eval(f.inner, part, bss, alive)
-            return {bi: ~inner[bi] for bi in alive}
-        plan = device_plan(f)
-        if plan is None:
-            self._bump("cpu_fallbacks")
-            out = {}
-            for bi in alive:
-                bm = np.ones(bss[bi].nrows, dtype=bool)
-                f.apply_to_block(bss[bi], bm)
-                out[bi] = bm
-            return out
-        return self._eval_leaf(plan, part, bss, alive)
-
-    def _eval_leaf(self, plan: LeafPlan, part, bss, alive) -> dict:
-        out = {}
-        # bloom kill-path FIRST (cheap, mmap'd words): when a rare token
-        # prunes every candidate block, the part is never staged.  The
-        # probe is one dense gather over the part's packed bloom plane
-        # (storage/filterbank.py + tpu/bloom_device.py), not a per-block
-        # Python loop; columns without a plane keep the per-block path.
-        survivors = list(alive)
-        if plan.bloom_tokens:
-            from ..storage.filterbank import filter_bank
-            hashes = cached_token_hashes(plan.filter, plan.bloom_tokens)
-            keep = bloom_keep_mask(part, plan.field, hashes, alive)
-            from ..storage.filterindex import part_index
-            if part_index(part) is not None:
-                # evidence the v2 MAPLET served the probe (exact keep
-                # set, no plane build at all)
-                self._bump("maplet_probes")
-            elif filter_bank(part).cached_plane(plan.field) is not None:
-                # evidence the PLANE path served the probe (a declined
-                # column rode the per-block fallback instead)
-                self._bump("bloom_plane_probes")
-            survivors = []
-            for bi, k in zip(alive, keep):
-                if k:
-                    survivors.append(bi)
-                else:
-                    out[bi] = np.zeros(bss[bi].nrows, dtype=bool)
-            if not survivors:
-                return out
-
-        # when the candidate blocks are a small fraction of the part (e.g.
-        # a narrow stream filter) and the part isn't staged yet, the host
-        # path over just those blocks beats staging + scanning everything
-        cand_rows = sum(bss[bi].nrows for bi in survivors)
-        already_staged = self.cache.contains((part.uid, plan.field))
-        if not already_staged and cand_rows * 8 < part.num_rows:
-            spc = None
-        else:
-            spc = self.stage_part(part, plan.field)
-        if spc is None:
-            dev_bis = []
-            host_bis = survivors
-        else:
-            dev_bis = [bi for bi in survivors if bi in spc.block_rows]
-            host_bis = [bi for bi in survivors if bi not in spc.block_rows]
-        for bi in host_bis:
-            bm = np.ones(bss[bi].nrows, dtype=bool)
-            plan.filter.apply_to_block(bss[bi], bm)
-            out[bi] = bm
-        if not dev_bis:
-            return out
-
-        verify_mask = None     # None => verify ALL survivors when plan.verify
-        need_verify = plan.verify
-        if plan.pair is not None:
-            combined, verify_mask = self._scan_pair(spc, plan.pair)
-            need_verify = True
-        else:
-            combined = self._run_ops(spc, plan)
-        na_map = self._stage_nonascii(part, plan.field) \
-            if any(op.fold for op in plan.ops) else {}
-        for bi in dev_bis:
-            start, n = spc.block_rows[bi]
-            bm = combined[start:start + n].copy() if combined is not None \
-                else np.ones(n, dtype=bool)
-            recheck = spc.overflow.get(bi)
-            # case-fold leaves: rows with non-ASCII bytes can diverge
-            # from the byte fold in EITHER direction (U+212A lowers to
-            # ASCII 'k') — the host predicate decides them outright
-            na = na_map.get(bi)
-            if na is not None:
-                recheck = na if recheck is None else \
-                    np.union1d(recheck, na)
-            value_at = None
-            if recheck is not None and recheck.size:
-                # truncated rows: ask the filter's full predicate
-                value_at = _row_accessor(bss[bi], plan.field)
-                for i in recheck:
-                    bm[i] = plan.filter._pred(value_at(i))
-            if need_verify and bm.any():
-                check = np.nonzero(
-                    bm & verify_mask[start:start + n]
-                    if verify_mask is not None else bm)[0]
-                if check.size:
-                    if value_at is None:
-                        value_at = _row_accessor(bss[bi], plan.field)
-                    for i in check:
-                        if not plan.filter._pred(value_at(i)):
-                            bm[i] = False
-            out[bi] = bm
-        return out
+    def _decline_to_host(self, f, bss: dict) -> dict:
+        """A part the fused planner declined (fused._NoFuse, axes
+        _assemble_axes refuses, a layout over MAX_STAT_ROWS): the host
+        executor evaluates it, counted once a part.  Not timed into the
+        host rate: the gate routes on the parts it sent to the host
+        itself, whatever an earlier query's shape was (a narrow decline,
+        most of whose blocks die in the bloom, reads two to three times
+        the rate and would pull parts the device serves to the host)."""
+        self._bump("cpu_fallbacks")
+        return self._host_eval_blocks(f, bss)
 
     # ---- device stats partials (filter bitmap -> per-bucket aggregates) ----
 
@@ -1962,12 +1651,13 @@ class BatchRunner:
 
     def run_part_topk(self, f, part, bss: dict, spec):
         """Filter + sort-topk threshold prefilter for one part in ONE
-        dispatch (tpu/fused.py try_fused_topk; spec from
+        dispatch (tpu/fused.py fused_topk_submit; spec from
         sort_device.device_sort_spec).  Returns block_idx -> bitmap
         holding exactly the filter-matching rows at-or-above the part's
         k-th best sort key (a superset of the part's contribution to the
         global top-k — the host sort processor resolves order and ties
-        exactly like the CPU path), or None when the shape declines."""
+        exactly like the CPU path), or None when the host gate or the
+        fused planner declines (run_part then serves the part)."""
         pending = self.run_part_topk_submit(f, part, bss, spec)
         return None if pending is None else pending.harvest()
 
@@ -1976,7 +1666,8 @@ class BatchRunner:
         single-part) is ISSUED now and materialized at harvest(), so
         the windowed pipeline keeps sort-topk units outstanding like
         every other query shape.  None when the host gate or the fused
-        planner declines (caller falls back to ordinary evaluation)."""
+        planner declines: the caller hands the part to run_part, whose
+        filter program reads the same staged columns."""
         cand_rows = sum(bs.nrows for bs in bss.values())
         if self._gate_host(f, part, bss, stats_rows=max(cand_rows, 1)):
             return None               # run_part re-gates and runs host
@@ -1986,15 +1677,13 @@ class BatchRunner:
     def run_part_stats(self, f, part, bss: dict, spec):
         """Filter + stats partials for one part.
 
-        Fast path (tpu/fused.py): when the whole filter tree is
-        device-expressible and every candidate block is stats-eligible,
-        filter AND stats run as ONE device dispatch — the row bitmap
-        never leaves HBM.  Otherwise: ordinary filter evaluation
-        (run_part), then per-bucket count/sum/min/max partials on
-        device with the row bitmap uploaded once and only
-        (buckets,)-sized results downloaded.  This is the fused
+        When the whole filter tree is device-expressible and every
+        candidate block is stats-eligible (tpu/fused.py), filter AND
+        stats run as ONE device dispatch: the row bitmap never leaves
+        HBM and only (buckets,)-sized partials come back, the fused
         analogue of the reference's per-worker stats shards merged at
-        flush (pipe_stats.go:354-377).
+        flush (pipe_stats.go:354-377).  Otherwise the host executor
+        evaluates the filter and the host pipe aggregates.
 
         Returns (bms, handled, partials):
         - bms: block_idx -> bitmap (covers at least the non-handled
@@ -2016,7 +1705,7 @@ class BatchRunner:
         """Async variant of run_part_stats: the fused dispatch (when the
         shape allows one) is ISSUED now and materialized at harvest(), so
         the windowed pipeline can keep several parts outstanding.  Host-
-        gated and unfused shapes compute synchronously and come back as
+        gated and declined parts compute synchronously and come back as
         ready handles — one protocol either way."""
         from .fused import _Ready, fused_stats_submit
         cand_rows = sum(bs.nrows for bs in bss.values())
@@ -2024,150 +1713,22 @@ class BatchRunner:
             self._bump("gated_host_parts")
             return _Ready((self._host_eval_part(f, bss), set(), []))
         asm = self._assemble_axes(part, spec)
-        if asm is not None and self.fused_enabled:
+        if asm is not None:
             pending = fused_stats_submit(self, f, part, bss, spec, asm)
             if pending is not None:
                 return pending
-        return _Ready(self._run_part_stats_unfused(f, part, bss, spec,
-                                                   asm))
+        return _Ready((self._decline_to_host(f, bss), set(), []))
 
     def run_part_submit(self, f, part, bss: dict):
         """Async variant of run_part for ROW queries: the whole filter
         tree compiles into ONE fused dispatch (fused.fused_filter_submit)
-        whose packed result is materialized at harvest(); shapes the
-        planner declines fall back to the per-leaf path synchronously."""
+        whose packed result is materialized at harvest(); a part the
+        planner declines comes back ready from the host executor."""
         from .fused import _Ready, fused_filter_submit
         if self._gate_host(f, part, bss):
             self._bump("gated_host_parts")
             return _Ready(self._host_eval_part(f, bss))
-        if self.fused_enabled:
-            pending = fused_filter_submit(self, f, part, bss)
-            if pending is not None:
-                return pending
-        return _Ready(self._run_part_device(f, part, bss))
-
-    def _run_part_stats_unfused(self, f, part, bss: dict, spec, asm):
-        """The two-dispatch fallback: ordinary filter evaluation, then
-        per-bucket partials over the uploaded row mask."""
-        bms = self.run_part(f, part, bss)
-        if asm is None:
-            return bms, set(), []
-        layout = asm.layout
-        handled = {bi for bi in bss
-                   if all(bi in el for el in asm.eligibility)}
-        if not handled:
-            return bms, set(), []
-        mask = np.zeros(layout.nrows_padded, dtype=bool)
-        matched = 0
-        for bi in handled:
-            bm = bms[bi]
-            if bm.any():
-                start = layout.starts[bi]
-                mask[start:start + bm.shape[0]] = bm
-                matched += int(bm.sum())
-        if not matched:
-            return bms, handled, []
-        if matched < self.stats_host_threshold:
-            # a handful of rows: the host pipe aggregates them faster
-            # than a mask upload (+~97ms) and a dispatch (+~65ms)
-            return bms, set(), []
-        mask_j = self._put(mask)
-
-        if spec.value_fields:
-            counts = None
-            stats_np = {}
-            for fld in spec.value_fields:
-                self._bump("device_calls")
-                self._bump("stats_dispatches")
-                self._kind("stats_values")
-                packed = self._dispatch_stats_values(
-                    asm.numerics[fld].values, asm.ids_tuple, asm.strides,
-                    mask_j, asm.nb)
-                counts = packed[0]
-                stats_np[fld] = packed
-            return bms, handled, self._partials_from_counts(
-                asm, counts, stats_np)
-
-        self._bump("device_calls")
-        self._bump("stats_dispatches")
-        self._kind("stats_count")
-        counts = self._dispatch_stats_count(asm.ids_tuple, asm.strides,
-                                            mask_j, asm.nb)
-        return bms, handled, self._partials_from_counts(asm, counts, {})
-
-    def _scan_pair(self, spc: StagedPart, pair: tuple):
-        """Device `A.*B` evaluation; returns (survivors, host_verify_mask)."""
-        a, b = pair
-        if max(len(a), len(b)) >= spc.width:
-            return np.zeros(spc.nrows, dtype=bool), None
-        self._bump("device_calls")
-        self._bump("plane_scan_leaves")
-        self._kind("scan_pair")
-        # vlint: allow-jax-host-sync(bit-packed survivor download)
-        packed = np.array(K32.match_ordered_pair_t_packed(
-            spc.rows, spc.lengths,
-            self._put_replicated(np.frombuffer(a, dtype=np.uint8)), len(a),
-            self._put_replicated(np.frombuffer(b, dtype=np.uint8)), len(b)))
-        definite = np.unpackbits(packed[0])[:spc.nrows].astype(bool)
-        needs_verify = np.unpackbits(packed[1])[:spc.nrows].astype(bool)
-        return definite | needs_verify, needs_verify
-
-    def _run_ops(self, spc: StagedPart, plan: LeafPlan) -> np.ndarray | None:
-        """AND/OR the leaf's scan ops over the whole staged part.
-
-        Returns bool[spc.nrows], or None for an op-less leaf (regex with no
-        safe literals => everything survives to verification)."""
-        combined = None
-        for op in plan.ops:
-            m = self._scan(spc, op)
-            if combined is None:
-                combined = m
-            elif plan.combine == "and":
-                combined &= m
-            else:
-                combined |= m
-            if plan.combine == "and" and combined is not None and \
-                    not combined.any():
-                break
-        return combined
-
-    def _scan(self, spc: StagedPart, op: ScanOp) -> np.ndarray:
-        if op.match_nonempty:
-            return spc.lengths_np[:spc.nrows] > 0
-        if op.match_empty:
-            return spc.lengths_np[:spc.nrows] == 0
-        if len(op.pattern) >= spc.width:
-            # no staged (truncated) value can contain it; overflow rows are
-            # re-checked from the full values by the caller
-            return np.zeros(spc.nrows, dtype=bool)
-        self._bump("device_calls")
-        self._bump("plane_scan_leaves")
-        self._kind(f"scan:m{op.mode}" + (":fold" if op.fold else ""))
-        import time
-        # calls of a not-yet-compiled jit signature pay (or block on a
-        # concurrent worker's) XLA compilation — seconds; feeding such a
-        # timing to the EWMA would poison dev_bytes_per_s into the MB/s
-        # range and route everything to host (ADVICE r4).  Only timings
-        # whose signature was compiled BEFORE the dispatch started count.
-        sig = (spc.rows.shape, len(op.pattern), op.mode,
-               op.starts_tok, op.ends_tok, op.fold)
-        with self._counter_mu:
-            pre_compiled = sig in self._scan_sigs
-        t0 = time.perf_counter()
-        pat = self._put_replicated(np.frombuffer(op.pattern,
-                                                 dtype=np.uint8))
-        res = K32.match_scan_t_packed(spc.rows, spc.lengths, pat,
-                                      len(op.pattern), op.mode,
-                                      op.starts_tok, op.ends_tok, op.fold)
-        # bit-packed download (~20x less transfer); unpack is a writable copy
-        # vlint: allow-jax-host-sync(bit-packed survivor download)
-        out = np.unpackbits(np.array(res))[:spc.nrows].astype(bool)
-        elapsed = time.perf_counter() - t0
-        with self._counter_mu:
-            self._scan_sigs.add(sig)
-        if pre_compiled:
-            self.cost.observe_device_scan(spc.nbytes, elapsed)
-            # per-leaf dispatches are full round trips too; compile-time
-            # samples are excluded for the same poisoning reason
-            hist.DISPATCH_RTT.observe(elapsed)
-        return out
+        pending = fused_filter_submit(self, f, part, bss)
+        if pending is not None:
+            return pending
+        return _Ready(self._decline_to_host(f, bss))

@@ -1,6 +1,6 @@
 """Fully-fused `filter | stats` device path: ONE dispatch per part.
 
-Why this exists: the unfused pipeline (scan dispatch -> bitmap download
+Why this exists: a pipeline of steps (scan dispatch -> bitmap download
 -> host slice -> mask re-upload -> stats dispatch) pays a dispatch round
 trip and an R-byte transfer per step.  This module evaluates the WHOLE
 filter tree and the stats partials inside a single jit: the bitmap never
@@ -9,8 +9,8 @@ plus (when needed) a bit-packed "needs-host-verify" vector (R/8 bytes).
 
 Key design points:
 - Staging is in STATS-LAYOUT coordinates (every block of the part, in
-  index order — tpu/batch.py part_stats_layout), not the string-only
-  packing of stage_part_column.  Dict/const/missing blocks are
+  index order — tpu/batch.py part_stats_layout).  Dict/const/missing
+  blocks are
   MATERIALIZED into the fixed-width matrix (a const block is one
   template row broadcast), so every filter leaf is a pure scan and the
   jitted program needs no per-block composition tables — which keeps
@@ -89,8 +89,8 @@ def stage_layout_column(part, field: str, layout: StatsLayout,
     are gathered per code; const/missing blocks broadcast a template
     row ('' for missing — the host's value semantics for absent
     fields).  Returns None when any block is numeric/ipv4/ts-typed
-    (the caller falls back to the unfused path) or the matrix would
-    exceed max_bytes."""
+    (the planner declines and the host evaluates the part) or the
+    matrix would exceed max_bytes."""
     virtual = field in ("_stream", "_stream_id")
     plans = []        # (start, n, kind, payload)
     max_len = 0
@@ -482,7 +482,7 @@ class _Planner:
         # candidate block's bloom, the leaf is constant false — no scan.
         # And when bloom + candidate pruning leave only a small row
         # fraction, the host path over those few blocks beats staging +
-        # whole-part scanning (same narrowness gate as _eval_leaf).
+        # whole-part scanning.
         # The probe is the packed-plane batch probe (filterbank); when
         # only SOME blocks die, the same plane is staged to HBM and the
         # kill bitmap ANDs into the tree inside the dispatch
@@ -495,13 +495,13 @@ class _Planner:
             keep = bloom_keep_mask(self.part, plan.field, hashes, bis)
             from ..storage.filterindex import part_index
             if part_index(self.part) is not None:
-                # same evidence counters _eval_leaf keeps: the v2
-                # maplet (exact) served this probe
+                # evidence the v2 MAPLET served the probe (exact keep
+                # set, no plane build at all)
                 self.runner._bump("maplet_probes")
             elif filter_bank(self.part).cached_plane(plan.field) \
                     is not None:
-                # same evidence counter _eval_leaf keeps on the per-leaf
-                # path: the PLANE served this probe
+                # evidence the PLANE path served the probe (a declined
+                # column rode the per-block fallback instead)
                 self.runner._bump("bloom_plane_probes")
             for i, bi in enumerate(bis):
                 if keep[i]:
@@ -1481,7 +1481,7 @@ def fused_topk_submit(runner, f, part, bss, spec):
     block_idx -> bitmap, the _FilterPending protocol — maybe rows above
     threshold settle through the filter's own host predicate), a _Ready
     result for constant-false trees, or None when the shape declines
-    (caller falls back to ordinary filter evaluation).
+    (the caller hands the part to BatchRunner.run_part).
 
     part may be a PackedPart (tpu/pipeline.py): its per-row segment ids
     stage like the stats seg axis and the dispatch k-selects per
@@ -1541,18 +1541,6 @@ def fused_topk_submit(runner, f, part, bss, spec):
                           planner.has_maybe)
 
 
-def try_fused_topk(runner, f, part, bss, spec):
-    """Synchronous shim over fused_topk_submit (single-part callers):
-    block_idx -> bitmap covering EVERY candidate block (exactly the
-    filter-matching rows at-or-above the part's k-th best key — a
-    superset of the part's contribution to the global top-k), or None
-    when the shape declines."""
-    pending = fused_topk_submit(runner, f, part, bss, spec)
-    if pending is None:
-        return None
-    return pending.harvest()
-
-
 # ---------------- fused filter-only dispatch (row queries) ----------------
 
 def _filter_local(prog, axis, blk, cand_packed, args, rl):
@@ -1582,13 +1570,11 @@ def _filter_dispatch(prog, blk, cand_packed, args):
     """One device call: the WHOLE filter tree -> bit-packed (definite,
     maybe) row vectors — the row-query analogue of _fused_dispatch.
 
-    Round 3 evaluated row-query trees leaf-by-leaf (one dispatch per
-    device leaf, host AND/OR combination); this compiles the same
-    three-valued program the stats/topk paths already trust into a
+    The same three-valued program the stats/topk paths run, as a
     single dispatch per part whose only downloads are two R/8-byte
     packed vectors, which is what makes the dispatch window's
     submit/harvest split (tpu/pipeline.py) worthwhile: one async
-    handle per part instead of a host sync per leaf."""
+    handle per part."""
     return _filter_local(prog, None, blk, cand_packed, args, prog[1])
 
 
@@ -1627,7 +1613,7 @@ class _FilterPending:
     """An in-flight fused filter dispatch for a row query; harvest()
     returns block_idx -> bool bitmap, bit-identical to the CPU path
     (maybe rows are settled by the filter tree's own apply_to_block,
-    the same residue discipline as try_fused/try_fused_topk)."""
+    the same residue discipline as the fused stats path)."""
 
     __slots__ = ("runner", "f", "part", "bss", "layout", "dm", "mm",
                  "has_maybe")
@@ -1665,24 +1651,13 @@ class _FilterPending:
         return bms
 
 
-def fused_filter_enabled() -> bool:
-    """The VL_FUSED_FILTER kill-switch, shared by the dispatch gate and
-    the pipeline's prefetch-mode decision so the two can never diverge
-    (prefetching #fl layout staging for a path that will dispatch
-    per-leaf would waste the upload AND leave the real staging cold)."""
-    return config.env_flag("VL_FUSED_FILTER")
-
-
 def fused_filter_submit(runner, f, part, bss):
     """Single-dispatch evaluation of a row query's whole filter tree.
 
     Returns a pending handle (harvest() -> block_idx -> bitmap), a
     _Ready result for constant trees, or None when the shape declines
-    (caller falls back to the per-leaf run_part path).  Kill-switch:
-    VL_FUSED_FILTER=0 restores the round-3 per-leaf behavior."""
+    (the runner hands the part to the host executor)."""
     from .stats_device import MAX_STAT_ROWS
-    if not fused_filter_enabled():
-        return None
     with tracing.current_span().span("args"):
         layout = runner._stats_layout(part)
         if layout.nrows > MAX_STAT_ROWS:

@@ -324,29 +324,6 @@ def combine_ids(ids_tuple, strides):
     return c
 
 
-@partial(jax.jit, static_argnames=("num_buckets", "strides"))
-def stats_bucket_count(ids_tuple, strides, mask: jnp.ndarray,
-                       num_buckets: int) -> jnp.ndarray:
-    """Masked row count per combined bucket.
-
-    ids_tuple: per-axis int32[R] arrays; strides: static per-axis
-    multipliers; mask: bool[R]; R must be a STATS_CHUNK multiple (pad
-    rows masked off).  Returns uint32[B]."""
-    return stats_count_local(combine_ids(ids_tuple, strides), mask,
-                             num_buckets)
-
-
-@partial(jax.jit, static_argnames=("num_buckets", "strides"))
-def stats_bucket_values(values: jnp.ndarray, ids_tuple, strides,
-                        mask: jnp.ndarray, num_buckets: int):
-    """count/sum/min/max partials per combined bucket for one uint32
-    value column (offsets from the part minimum — see stage_numeric);
-    returns uint32[7, B] packed as [count, plane_sums[0..3], vmin, vmax].
-    Buckets with count 0 carry vmin=UINT32_MAX, vmax=0."""
-    return pack_stats(*stats_values_local(
-        values, combine_ids(ids_tuple, strides), mask, num_buckets))
-
-
 def pad_bucket(n: int, minimum: int = 8192) -> int:
     """Pad sizes to coarse buckets so jit caches stay small."""
     b = minimum

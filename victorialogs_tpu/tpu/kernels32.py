@@ -35,9 +35,8 @@ only.
 
 Two launchers, one body (_launch).  On the TPU a Pallas call tiles the
 rows through VMEM blocks and runs the body a register tile at a time; on
-every other backend, and for a stripe under a mesh axis or a column
-XLA partitions over devices, the body runs directly as jax.numpy over
-the whole column.  The choice follows what the code can observe, never
+every other backend, and for a stripe under a mesh axis, the body runs
+directly as jax.numpy over the whole column.  The choice follows what the code can observe, never
 an environment switch.
 
 Measured on a v5e, R = 2M, W = 128 (tools/bench_kernels32.py; my chip
@@ -310,13 +309,13 @@ def _on_tpu_alone(lanes_t) -> bool:
             and lanes_t.shape[1] % _SUBLANES == 0)
 
 
-def _launch(body, nout: int, lanes_t, lengths, pc, spmd: bool = False):
+def _launch(body, nout: int, lanes_t, lengths, pc):
     """body(load, lens, pc) -> nout bool arrays over a tile of rows;
     returns them over every row, bool[R] each.  On the TPU the tiles
-    are register-sized slices of VMEM blocks (Pallas); elsewhere, and
-    for a column striped over devices (spmd), the tile is the column."""
+    are register-sized slices of VMEM blocks (Pallas); elsewhere the
+    tile is the column."""
     lens = lengths.reshape(lanes_t.shape[1:])
-    if spmd or not _on_tpu_alone(lanes_t):
+    if not _on_tpu_alone(lanes_t):
         out = body(lambda q: jax.lax.dynamic_index_in_dim(
             lanes_t, q, 0, keepdims=False), lens, pc)
     else:
@@ -364,12 +363,12 @@ def _launch_pallas(body, lanes_t, lens, pc, interpret: bool = False):
 # ---------------- the scan ----------------
 
 @partial(jax.jit, static_argnames=("pat_len", "mode", "starts_tok",
-                                   "ends_tok", "fold", "spmd"))
+                                   "ends_tok", "fold"))
 @jax.named_scope("match_scan")
 def match_scan_t(lanes_t: jnp.ndarray, lengths: jnp.ndarray,
                  pattern: jnp.ndarray, pat_len: int, mode: int,
                  starts_tok: bool, ends_tok: bool,
-                 fold: bool = False, spmd: bool = False) -> jnp.ndarray:
+                 fold: bool = False) -> jnp.ndarray:
     """Per-row match bitmap over a staged string column.
 
     lanes_t: uint32[W/4, R/128, 128] planes (layout.to_lanes32);
@@ -390,40 +389,16 @@ def match_scan_t(lanes_t: jnp.ndarray, lengths: jnp.ndarray,
         return [_scan_rows(load, nl, lens, pc, masks, pat_len, mode,
                            need_start, need_end, fold)]
 
-    return _launch(body, 1, lanes_t, lengths, pc, spmd)[0]
-
-
-def _striped(lanes_t) -> bool:
-    """Whether a staged column (a placed array, not a tracer) is spread
-    over several devices: its jitted scan is then partitioned by XLA."""
-    return len(lanes_t.sharding.device_set) > 1
-
-
-def match_scan_t_packed(lanes_t, lengths, pattern, pat_len, mode,
-                        starts_tok, ends_tok, fold=False):
-    """match_scan_t with the bitmap bit-packed on device before download
-    (8x fewer bytes over the host link)."""
-    return _scan_packed(lanes_t, lengths, pattern, pat_len, mode,
-                        starts_tok, ends_tok, fold, _striped(lanes_t))
-
-
-@partial(jax.jit, static_argnames=("pat_len", "mode", "starts_tok",
-                                   "ends_tok", "fold", "spmd"))
-def _scan_packed(lanes_t, lengths, pattern, pat_len, mode, starts_tok,
-                 ends_tok, fold, spmd):
-    return jnp.packbits(match_scan_t(lanes_t, lengths, pattern, pat_len,
-                                     mode, starts_tok, ends_tok, fold,
-                                     spmd).astype(jnp.uint8))
+    return _launch(body, 1, lanes_t, lengths, pc)[0]
 
 
 # ---------------- the ordered pair ----------------
 
-@partial(jax.jit, static_argnames=("len_a", "len_b", "spmd"))
+@partial(jax.jit, static_argnames=("len_a", "len_b"))
 @jax.named_scope("match_pair")
 def match_ordered_pair_t(lanes_t: jnp.ndarray, lengths: jnp.ndarray,
                          pat_a: jnp.ndarray, len_a: int,
-                         pat_b: jnp.ndarray, len_b: int,
-                         spmd: bool = False):
+                         pat_b: jnp.ndarray, len_b: int):
     """`A.*B` decomposition over the planes: matches iff the FIRST
     occurrence of A ends at or before the LAST occurrence of B.
     Rows containing a newline go to the needs-verify channel ('.' does
@@ -441,19 +416,4 @@ def match_ordered_pair_t(lanes_t: jnp.ndarray, lengths: jnp.ndarray,
                           len_b)
 
     return tuple(_launch(body, 2, lanes_t, lengths,
-                         jnp.concatenate([pc_a, pc_b]), spmd))
-
-
-def match_ordered_pair_t_packed(lanes_t, lengths, pat_a, len_a,
-                                pat_b, len_b):
-    """Both result vectors packed into ONE uint8[2, R/8] download."""
-    return _pair_packed(lanes_t, lengths, pat_a, len_a, pat_b, len_b,
-                        _striped(lanes_t))
-
-
-@partial(jax.jit, static_argnames=("len_a", "len_b", "spmd"))
-def _pair_packed(lanes_t, lengths, pat_a, len_a, pat_b, len_b, spmd):
-    definite, needsv = match_ordered_pair_t(lanes_t, lengths, pat_a,
-                                            len_a, pat_b, len_b, spmd)
-    return jnp.stack([jnp.packbits(definite.astype(jnp.uint8)),
-                      jnp.packbits(needsv.astype(jnp.uint8))], axis=0)
+                         jnp.concatenate([pc_a, pc_b])))

@@ -7,7 +7,7 @@ but the part walk itself stayed serial: every part's dispatch blocked on
 the previous part's host materialization, so a query over P parts paid P
 serial round trips even though the dispatches are independent.  This
 module is the per-part execution driver that removes that serialization
-(engine/searcher._scan_parts delegates here for batch runners):
+(engine/searcher feeds it every selected partition's parts):
 
 1. **In-flight dispatch window** — fused dispatches return asynchronous
    jax arrays; nothing forces them to the host at submit time.  Up to
@@ -38,9 +38,8 @@ downstream: in-flight handles are simply dropped (jax buffers are
 released when the device finishes; staging entries are complete,
 keyed, budget-accounted values, so the StagingCache stays balanced).
 
-Kill-switches: VL_INFLIGHT=1 reduces to the serial submit-then-harvest
-walk; VL_PACK_PARTS=1 disables packing; VL_FUSED_FILTER=0 restores the
-per-leaf row-query path inside each unit (tpu/fused.py).
+Sizes: VL_INFLIGHT=1 reduces to the serial submit-then-harvest walk;
+VL_PACK_PARTS=1 disables packing.
 """
 
 from __future__ import annotations
@@ -127,12 +126,6 @@ def pack_topk_k() -> int:
     inflates every member's padded slots, so past this cap the
     per-part dispatches win."""
     return max(0, config.env_int("VL_PACK_TOPK_K"))
-
-
-def cross_partition_enabled() -> bool:
-    """VL_CROSS_PARTITION=0 restores the per-partition dispatch window
-    (the pre-PR-15 shape: the window drains at every day boundary)."""
-    return config.env_flag("VL_CROSS_PARTITION")
 
 
 def pack_policy(runner, sort_spec, probe: bool = True):
@@ -585,13 +578,13 @@ def _submit(runner, f, unit: _Unit, stats_spec, sort_spec, spec_seg):
             return _submit_pack_topk(runner, f, unit, sort_spec)
         pending = runner.run_part_topk_submit(f, unit.part, unit.bss,
                                               sort_spec)
-        if pending is not None:
-            # async: the dispatch stays outstanding in the window like
-            # every other shape (harvest -> block_idx -> bitmap)
-            return _SingleRows(unit, pending)
-        part, blocks = unit.members[0]
-        bms = runner.run_part(f, part, unit.bss)
-        return _UnitReady([_Member(part, blocks, bms, set(), [])])
+        if pending is None:
+            # the gate or the topk program declined: a row unit, the
+            # host pipe sorts
+            pending = runner.run_part_submit(f, unit.part, unit.bss)
+        # async: the dispatch stays outstanding in the window like
+        # every other shape (harvest -> block_idx -> bitmap)
+        return _SingleRows(unit, pending)
     if unit.pack:
         return _submit_pack_rows(runner, f, unit)
     return _SingleRows(unit, runner.run_part_submit(f, unit.part,
@@ -622,15 +615,13 @@ def _submit_pack_rows(runner, f, unit: _Unit):
     if runner._gate_host(f, unit.part, unit.bss):
         runner._bump("gated_host_parts", len(unit.members))
         return _UnitReady(_host_members(runner, f, unit))
-    pending = None
-    if runner.fused_enabled:
-        from .fused import fused_filter_submit
-        pending = fused_filter_submit(runner, f, unit.part, unit.bss)
+    from .fused import fused_filter_submit
+    pending = fused_filter_submit(runner, f, unit.part, unit.bss)
     if pending is not None:
         _count_pack(runner, unit, pending)
         return _PackRows(unit, pending)
-    # the planner declined the pack: fall back to the serial per-member
-    # path (results identical to the unpacked walk)
+    # the planner declined the pack, maybe for a reason a member does
+    # not have: each member goes through the single-part submit
     out = []
     for p, blocks in unit.members:
         bms = runner.run_part_submit(f, p, dict(blocks)).harvest()
@@ -649,19 +640,15 @@ def _submit_pack_topk(runner, f, unit: _Unit, sort_spec):
                          stats_rows=max(cand_rows, 1)):
         runner._bump("gated_host_parts", len(unit.members))
         return _UnitReady(_host_members(runner, f, unit))
-    pending = None
-    if runner.fused_enabled:
-        from .fused import fused_topk_submit
-        pending = fused_topk_submit(runner, f, unit.part, unit.bss,
-                                    sort_spec)
+    from .fused import _Ready, fused_topk_submit
+    pending = fused_topk_submit(runner, f, unit.part, unit.bss, sort_spec)
     if pending is not None:
         _count_pack(runner, unit, pending)
-        from .fused import _Ready
         if not isinstance(pending, _Ready):
             runner._bump("packed_topk_dispatches")
         return _PackRows(unit, pending)
-    # decline (non-numeric sort column, unfusable leaf): serial
-    # per-member path — results identical to the unpacked walk
+    # decline (non-numeric sort column, unfusable leaf): each member
+    # goes through the single-part submit
     out = []
     for p, blocks in unit.members:
         mbss = dict(blocks)
@@ -678,18 +665,17 @@ def _submit_pack_stats(runner, f, unit: _Unit, stats_spec, spec_seg):
                          stats_rows=max(cand_rows, 1)):
         runner._bump("gated_host_parts", len(unit.members))
         return _UnitReady(_host_members(runner, f, unit))
+    from .fused import fused_stats_submit
     pending = None
-    if runner.fused_enabled:
-        from .fused import fused_stats_submit
-        asm = runner._assemble_axes(unit.part, spec_seg)
-        if asm is not None:
-            pending = fused_stats_submit(runner, f, unit.part, unit.bss,
-                                         spec_seg, asm)
+    asm = runner._assemble_axes(unit.part, spec_seg)
+    if asm is not None:
+        pending = fused_stats_submit(runner, f, unit.part, unit.bss,
+                                     spec_seg, asm)
     if pending is not None:
         _count_pack(runner, unit, pending)
         return _PackStats(unit, pending)
-    # decline (ineligible column, bucket blowup, unfusable leaf): serial
-    # per-member fallback with the ORIGINAL spec
+    # decline (ineligible column, bucket blowup, unfusable leaf): each
+    # member goes through the single-part submit with the ORIGINAL spec
     out = []
     for p, blocks in unit.members:
         bms, handled, partials = runner.run_part_stats(f, p, dict(blocks),
@@ -722,29 +708,14 @@ def _make_sync(runner):
     return sync
 
 
-def scan_parts_device(parts, q, head, runner, cand_fn, ctx, needed,
-                      deadline, stats_spec, sort_spec,
-                      token_leaves, qcache=None) -> None:
-    """Drive ONE partition's parts through the async dispatch window
-    (the VL_CROSS_PARTITION=0 compatibility shape: the window drains at
-    the partition boundary).  The default path is scan_device_stream,
-    which engine/searcher feeds with parts from EVERY selected
-    partition so the window never drains between days."""
-    act = activity.current_activity()
-    act.add("parts_total", len(parts))
-    scan_device_stream(((p, cand_fn, ctx) for p in parts), q, head,
-                       runner, needed, deadline, stats_spec, sort_spec,
-                       token_leaves, qcache=qcache)
-
-
 def scan_device_stream(items, q, head, runner, needed, deadline,
                        stats_spec, sort_spec, token_leaves,
                        qcache=None) -> None:
     """Drive a cross-partition part stream through the async dispatch
     window.
 
-    Replaces the serial device walk of engine/searcher._scan_parts:
-    candidate pruning and part-aggregate kills are unchanged; submission
+    Candidate pruning and part-aggregate kills are the host walk's
+    (engine/searcher._scan_parts); submission
     keeps up to VL_INFLIGHT units' dispatches outstanding; harvest is in
     submission order, so downstream block order and stats absorb
     granularity are identical to the serial path.  `items` yields
@@ -810,18 +781,7 @@ def scan_device_stream(items, q, head, runner, needed, deadline,
     lookahead: deque = deque()
     exhausted = False
     prefetched: set = set()
-    # prefetch staging mode must match what the units will dispatch:
-    # fused layout staging for stats, for sort-topk (now a fused
-    # async dispatch — packed or single) and (unless the
-    # VL_FUSED_FILTER kill-switch reverts to the per-leaf path) row
-    # queries
-    from .fused import fused_filter_enabled
-    fused_pf = stats_spec is not None or (
-        sort_spec is not None and runner.fused_enabled) or (
-        sort_spec is None and fused_filter_enabled()
-        and runner.fused_enabled)
-    sort_field = sort_spec.field if sort_spec is not None and \
-        runner.fused_enabled else None
+    sort_field = sort_spec.field if sort_spec is not None else None
     psp = tracing.current_span()
     seq = 0
 
@@ -872,7 +832,7 @@ def scan_device_stream(items, q, head, runner, needed, deadline,
             # so only leased entries return one.
             if leased:
                 slots.release()
-            # _UnitReady units never dispatched (host gate / serial
+            # _UnitReady units never dispatched (host gate / per-member
             # fallback): their submit-to-harvest time is pure window
             # queue wait and must not pollute the device-RTT histogram
             dispatched = not isinstance(pending, _UnitReady)
@@ -937,7 +897,6 @@ def scan_device_stream(items, q, head, runner, needed, deadline,
                                 runner.submit_prefetch(
                                     uj.part, f, stats_spec,
                                     cand_bis=list(uj.bss),
-                                    fused=fused_pf,
                                     sort_field=sort_field)
                     # our own window's depth backpressure is NOT
                     # scheduler wait: drain it untimed first, so the
