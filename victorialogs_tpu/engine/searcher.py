@@ -16,7 +16,6 @@ from __future__ import annotations
 
 import contextlib
 import time
-from dataclasses import dataclass
 
 import numpy as np
 
@@ -29,12 +28,7 @@ from ..logsql.pipes import Processor, SinkProcessor
 from ..storage.log_rows import TenantID
 from .block_result import BlockResult
 from .block_search import BlockSearch, new_bitmap
-
-
-@dataclass
-class SearchContext:
-    partition: object
-    tenants: tuple
+from .planwalk import PartitionWalk, observe
 
 
 class QueryCancelled(Exception):
@@ -227,13 +221,6 @@ def _run_query_guarded(storage, tenants, q, write_block, timestamp,
         if hasattr(p, "init_with_storage"):
             p.init_with_storage(storage, tenants, runner)
 
-    if price:
-        # the same header walk _scan_parts repeats in a moment, priced
-        # against the live cost-model EWMAs: predicted_* land next to
-        # the actuals in the query_done journal event, and
-        # sched/admission can weigh predicted_duration_s against a
-        # request deadline in a follow-up
-        explain.price_into_activity(storage, tenants, q, runner, act0)
     min_ts, max_ts = q.get_time_range()
 
     # rate()/rate_sum() divide by the time-filter range (reference
@@ -299,7 +286,6 @@ def _run_query_guarded(storage, tenants, q, write_block, timestamp,
     from ..logsql.filters import iter_and_path_token_leaves
     token_leaves = list(iter_and_path_token_leaves(q.filter))
 
-    tenant_set = set(tenants)
     # CPU-path block workers (reference spawns GetConcurrency() workers
     # over a 64-block channel — storage_search.go:1035-1067; numpy/zstd
     # release the GIL, so threads overlap real work).  One pool is SHARED
@@ -313,16 +299,11 @@ def _run_query_guarded(storage, tenants, q, write_block, timestamp,
     def scan_partition(pt, sink_head):
         with tracing.current_span().span(
                 "partition", day=getattr(pt, "day", None)) as psp:
-            ctx = SearchContext(partition=pt, tenants=tenants)
-            allowed_sids = None
-            if sfs:
-                allowed_sids = set.intersection(
-                    *(f.resolve(pt, tenants) for f in sfs))
-                if not allowed_sids:
-                    psp.set("pruned_by_stream_filter", True)
-                    return
-            _scan_parts(pt, q, sink_head, tenant_set, allowed_sids,
-                        min_ts, max_ts, ctx, needed, deadline, pool,
+            pw = PartitionWalk(pt, tenants, min_ts, max_ts, sfs)
+            if pw.pruned_by_stream_filter:
+                psp.set("pruned_by_stream_filter", True)
+                return
+            _scan_parts(pw, q, sink_head, needed, deadline, pool,
                         token_leaves, qcache)
 
     try:
@@ -335,10 +316,31 @@ def _run_query_guarded(storage, tenants, q, write_block, timestamp,
             # it.  The window IS the parallelism here (dispatches from
             # several partitions overlap on the one device), so the
             # thread-per-partition fan-out below is the host executor's.
-            _scan_partitions_device(
-                pts, q, head, runner, tenants, tenant_set, sfs, min_ts,
-                max_ts, needed, deadline, stats_spec, sort_spec,
-                token_leaves, qcache)
+            from ..tpu.pipeline import scan_device_stream
+            qsp = tracing.current_span()
+            walk_args = (pts, tenants, min_ts, max_ts, sfs, token_leaves,
+                         runner)
+            if price:
+                # ONE header walk a query: eager, priced against the
+                # live cost-model EWMAs (predicted_* ride the query_done
+                # event beside the actuals), then the window's part
+                # stream, so the priced plan IS the executed plan; a
+                # cold aggregate fold waits until the window reaches the
+                # part.  Unpriced, the window pulls the walk lazily and
+                # a `limit` stops it early.
+                act.set_phase("prune")
+                with qsp.span("prune") as prsp:
+                    items = list(_device_walk(prsp, *walk_args,
+                                              build=False))
+                    prsp.set("parts_retained", len(items))
+                explain.price_into_activity(items, q, runner, act0,
+                                            stats_spec, sort_spec, qcache)
+                runner._bump("shared_plan_walks")
+            else:
+                items = _device_walk(qsp, *walk_args, build=True)
+            scan_device_stream(items, q, head, runner, needed, deadline,
+                               stats_spec, sort_spec, token_leaves,
+                               qcache=qcache)
         else:
             # per-day partitions search CONCURRENTLY under a worker cap
             # (reference storage_search.go:1095-1126): a 30-day query
@@ -420,74 +422,32 @@ def _scan_partitions_parallel(pts, scan_partition, head, npw) -> None:
         raise errors[0]
 
 
-def _make_cand_fn(tenant_set, allowed_sids, min_ts, max_ts):
-    """Header-only candidate selection closure (shared by the serial
-    walk, the cross-partition device stream and the prefetcher);
-    candidate_blocks skips whole header groups outside the query's
-    time range without decoding them (v2 metaindex)."""
-    def cand_block_idxs(part) -> list:
-        out = []
-        for bi in part.candidate_blocks(min_ts, max_ts):
-            sid = part.block_stream_id(bi)
-            if sid.tenant not in tenant_set:
-                continue
-            if allowed_sids is not None and sid not in allowed_sids:
-                continue
-            out.append(bi)
-        return out
-    return cand_block_idxs
-
-
-def _scan_partitions_device(pts, q, head, runner, tenants, tenant_set,
-                            sfs, min_ts, max_ts, needed, deadline,
-                            stats_spec, sort_spec,
-                            token_leaves, qcache=None) -> None:
-    """The cross-partition device path: feed every selected partition's
-    parts through ONE async dispatch window (tpu/pipeline.py).
-
-    Partition setup stays lazy AND attributed: each partition resolves
-    its stream filters and snapshots its parts only when the window's
-    planning pull reaches it, under a short-lived per-partition span
-    (day, part count, stream-filter prunes — the same attribution the
-    per-partition walk recorded); an early exit (limit, deadline,
-    cancel) therefore stops the partition walk exactly where the old
-    loop would have."""
-    from ..tpu.pipeline import scan_device_stream
-    qsp = tracing.current_span()
+def _device_walk(qsp, pts, tenants, min_ts, max_ts, sfs, token_leaves,
+                 runner, build: bool):
+    """The device path's header walk: a lazy stream of (PartStep, ctx),
+    one a surviving part of every selected partition, the execution's
+    accounting landed as it advances.  Partition setup stays lazy AND
+    attributed: a partition resolves and snapshots only when the pull
+    reaches it, under a short-lived span off `qsp` (day, part count,
+    stream-filter prunes), so a lazy consumer's early exit (limit,
+    deadline, cancel) stops the walk where the serial loop would."""
     act = activity.current_activity()
-
-    def part_stream():
-        for pt in pts:
-            parts = []
-            cand_fn = None
-            ctx = None
-            # the span covers partition SETUP only (it must not stay
-            # open across planning pulls — spans are ambient via a
-            # contextvar, and a generator holding one open would leak
-            # it into the window driver's own spans between pulls)
-            with qsp.span("partition", day=getattr(pt, "day",
-                                                   None)) as psp:
-                ctx = SearchContext(partition=pt, tenants=tenants)
-                allowed_sids = None
-                if sfs:
-                    allowed_sids = set.intersection(
-                        *(f.resolve(pt, tenants) for f in sfs))
-                    if not allowed_sids:
-                        psp.set("pruned_by_stream_filter", True)
-                if allowed_sids is None or allowed_sids:
-                    parts = [p for p in pt.ddb.snapshot_parts()
-                             if p.num_rows and p.min_ts <= max_ts
-                             and p.max_ts >= min_ts]
-                    psp.set("parts", len(parts))
-                    act.add("parts_total", len(parts))
-                    cand_fn = _make_cand_fn(tenant_set, allowed_sids,
-                                            min_ts, max_ts)
-            for part in parts:
-                yield part, cand_fn, ctx
-
-    scan_device_stream(part_stream(), q, head, runner, needed, deadline,
-                       stats_spec, sort_spec, token_leaves,
-                       qcache=qcache)
+    for pt in pts:
+        # the span covers partition SETUP only (it must not stay open
+        # across planning pulls — spans are ambient via a contextvar,
+        # and a generator holding one open would leak it into the
+        # window driver's own spans between pulls)
+        with qsp.span("partition", day=getattr(pt, "day", None)) as psp:
+            pw = PartitionWalk(pt, tenants, min_ts, max_ts, sfs)
+            if pw.pruned_by_stream_filter:
+                psp.set("pruned_by_stream_filter", True)
+            else:
+                psp.set("parts", pw.in_range)
+                act.add("parts_total", pw.in_range)
+        for step in pw.steps(token_leaves, build):
+            observe(step, runner)
+            if step.bis:
+                yield step, pw.ctx
 
 
 def _eval_block_cpu(q, bs):
@@ -512,49 +472,25 @@ def _absorb_stats_partials(head, q, spec, partials) -> None:
         head.absorb_partials(key, states)
 
 
-def _scan_parts(pt, q, head, tenant_set, allowed_sids, min_ts, max_ts,
-                ctx, needed, deadline, pool, token_leaves,
+def _scan_parts(pw, q, head, needed, deadline, pool, token_leaves,
                 qcache) -> None:
     """The host executor's walk over one partition's parts."""
-    from ..storage.filterbank import (maplet_prune_candidates,
-                                      part_aggregate_prunes)
-    parts = [p for p in pt.ddb.snapshot_parts()
-             if p.num_rows and p.min_ts <= max_ts and p.max_ts >= min_ts]
-    cand_block_idxs = _make_cand_fn(tenant_set, allowed_sids, min_ts,
-                                    max_ts)
-
+    ctx = pw.ctx
     sp = tracing.current_span()
-    sp.set("parts", len(parts))
+    sp.set("parts", pw.in_range)
     act = activity.current_activity()
-    act.add("parts_total", len(parts))
+    act.add("parts_total", pw.in_range)
     act.set_phase("scan")
-    for part in parts:
+    for step in pw.steps(token_leaves):
         if deadline is not None and time.monotonic() > deadline:
             raise QueryTimeoutError(
                 "query exceeded -search.maxQueryDuration")
-        part_bis = cand_block_idxs(part)
-        sp.add("blocks_candidate", len(part_bis))
-        if token_leaves and part_bis:
-            # part-level aggregate kill (filter-index subsystem): an
-            # AND-path leaf's required token absent from EVERY block
-            # skips the whole part — identical results, the per-block
-            # kill-path would have zeroed each block anyway.  A COLD
-            # aggregate build reads all the part's blooms, so it only
-            # pays when the candidate set covers a sizable fraction;
-            # narrow queries probe an already-built aggregate for free.
-            if part_aggregate_prunes(
-                    part, token_leaves,
-                    build=len(part_bis) * 4 >= part.num_blocks):
-                continue
-            # sealed v2 parts: the token→block maplet turns AND-path
-            # leaf pruning into one exact lookup — surviving blocks
-            # are exactly the per-block kill-path's survivors, found
-            # before any block header or bloom word is touched
-            part_bis = maplet_prune_candidates(part, token_leaves,
-                                               part_bis)
-            if not part_bis:
-                continue
-        activity.note_part_scanned(act, part, part_bis)
+        part, part_bis = step.part, step.bis
+        sp.add("blocks_candidate", step.n_cand)
+        observe(step)
+        if not part_bis:
+            continue
+        activity.note_part_scanned(act, part, part_bis, step.rows)
         if qcache is not None and qcache.kind == "bms":
             # sealed-part replay: the cached bitmaps feed the chain in
             # the exact block order the walk below would produce

@@ -111,7 +111,7 @@ def filter_plan_tree(f) -> dict:
     """Compact JSON-ready view of a filter tree for the EXPLAIN plan
     (obs/explain.py): operator kind, target field, and — on
     bloom-prunable leaves — the required word tokens the part-aggregate
-    kill path (storage/filterbank.part_aggregate_prunes) can cite when
+    kill path (storage/filterbank.aggregate_kill_leaf) can cite when
     it kills a part.  Purely descriptive: no evaluation, no token
     hashing."""
     kind = type(f).__name__.removeprefix("Filter").lower() or "filter"

@@ -702,14 +702,14 @@ def part_bytes_per_row(part) -> float:
     return 64.0
 
 
-def note_part_scanned(act, part, bis) -> None:
-    """One part's candidate blocks entered the scan: the
-    parts/rows/bytes progress adds in ONE place, shared by the serial
-    walk (engine/searcher._scan_parts) and the device planner
+def note_part_scanned(act, part, bis, rows: int) -> None:
+    """One part's candidate blocks (`rows` rows, as the header walk
+    counted them) entered the scan: the parts/rows/bytes progress adds
+    in ONE place, shared by the serial walk
+    (engine/searcher._scan_parts) and the device planner
     (tpu/pipeline._unit_stream) so the estimator can't diverge."""
     if not act.enabled or not bis:
         return
-    rows = sum(part.block_rows(bi) for bi in bis)
     act.add("parts_scanned")
     act.add("rows_scanned", rows)
     act.add("bytes_scanned", int(rows * part_bytes_per_row(part)))

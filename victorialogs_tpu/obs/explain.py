@@ -1,7 +1,9 @@
 """Query EXPLAIN: priced physical plans and predicted-vs-actual cost
 accountability.
 
-Two consumers share one header-only plan walk:
+Three consumers share one header-only plan walk (engine/planwalk.py:
+partitions → parts → candidate blocks → aggregate / maplet prunes), the
+two below and the execution itself:
 
 - **`?explain=1`** (server/vlselect.handle_explain): the physical plan
   tree WITHOUT executing — partitions → parts (retained vs killed, with
@@ -19,10 +21,12 @@ Two consumers share one header-only plan walk:
   `storage_node` nodes exactly like `?trace=1`
   (server/cluster.NetSelectStorage.net_explain).
 
-- **continuous pricing** (engine/searcher hooks `predict_query` at plan
-  time for every device-path query): the same walk at part granularity
-  writes `predicted_duration_s` / `predicted_bytes` /
-  `predicted_dispatches` onto the activity record, so `query_done`
+- **continuous pricing** (engine/searcher hooks `price_into_activity`
+  at plan time for every device-path query): the engine runs the walk
+  ONCE, this module prices the retained parts and the dispatch window
+  then consumes the same list, so the priced plan is the executed plan.
+  `predicted_duration_s` / `predicted_bytes` / `predicted_dispatches`
+  land on the activity record, so `query_done`
   journal events carry predicted-vs-actual pairs, /metrics grows
   `vl_cost_model_rel_error_*` histograms (obs/activity computes the
   errors at deregister), and `top_queries?by=cost_error` surfaces the
@@ -31,8 +35,9 @@ Two consumers share one header-only plan walk:
   follow-up (a per-QUERY run estimate instead of the per-endpoint
   EWMA).  `VL_QUERY_PRICING=0` kills the continuous pass.
 
-The plan walk deliberately REUSES the execution planner's own pieces —
-`candidate_blocks` header selection, `filterbank.aggregate_kill_leaf`,
+The plan is built from the execution planner's own pieces — the one
+walk (`planwalk.PartitionWalk`: `candidate_blocks` header selection,
+`filterbank.aggregate_kill_leaf`, the maplets),
 `pipeline.iter_pack_groups` pack membership, `CostModel` rates — so the
 displayed plan cannot diverge from what a real run would dispatch.
 """
@@ -70,10 +75,10 @@ def build_plan(storage, tenants, q, runner=None) -> dict:
 
 
 def predict_query(storage, tenants, q, runner=None) -> dict:
-    """The cheap continuous pricing pass: predicted summary only (no
-    per-part nodes, no cold aggregate builds — only aggregates a prior
-    query already folded are probed, the execution walk that follows
-    pays for new ones itself)."""
+    """The cheap pricing pass over a walk of its own: predicted summary
+    only (no per-part nodes, no cold aggregate builds — only aggregates
+    a prior query already folded are probed).  What a priced query
+    writes on its record (price_into_activity), on the same snapshot."""
     return _walk(storage, tenants, q, runner, detail=False)["predicted"]
 
 
@@ -82,48 +87,35 @@ def _walk(storage, tenants, q, runner, detail: bool) -> dict:
                                   iter_and_path_token_leaves)
     from ..logsql.parser import MAX_TS, MIN_TS
     from ..storage.log_rows import TenantID
+    from ..engine.planwalk import PartitionWalk
     from ..engine.searcher import _collect_stream_filters
 
     if isinstance(tenants, TenantID):
         tenants = [tenants]
     tenants = tuple(tenants)
-    tenant_set = set(tenants)
     min_ts, max_ts = q.get_time_range()
 
-    batch = runner is not None
-    peek = runner.cost.peek() if batch else dict(_HOST_ONLY_PEEK)
     stats_spec = sort_spec = None
-    plans = []
-    if batch:
-        from ..tpu.batch import device_plans
+    if runner is not None:
         from ..tpu.stats_device import device_stats_spec
-        plans = device_plans(q.filter)
         stats_spec = device_stats_spec(q)
         if stats_spec is None:
             from ..tpu.sort_device import device_sort_spec
             sort_spec = device_sort_spec(q)
-    shape = "stats" if stats_spec is not None else \
-        "topk" if sort_spec is not None else "rows"
+    pricing = _Pricing(q, runner, stats_spec, sort_spec, detail)
 
     sfs: list = []
     _collect_stream_filters(q.filter, sfs)
     token_leaves = list(iter_and_path_token_leaves(q.filter))
-    if batch:
-        # the SAME depth derivation the window dispatches with, minus
-        # the lazy RTT probe (explain must stay zero-dispatch)
-        from ..tpu.pipeline import inflight_depth
-        depth = inflight_depth(runner, probe=False)
-    else:
-        depth = 1
 
     tree: dict = {
         "name": "explain",
         "mode": "plan",
         "query": q.to_string(),
-        "shape": shape,
-        "executor": "device" if batch else "host",
-        "fused_filter": batch,
-        "inflight_depth": depth,
+        "shape": pricing.shape,
+        "executor": "device" if pricing.batch else "host",
+        "fused_filter": pricing.batch,
+        "inflight_depth": pricing.depth,
         "time_range": {
             "min_ts": None if min_ts == MIN_TS else min_ts,
             "max_ts": None if max_ts == MAX_TS else max_ts,
@@ -132,12 +124,6 @@ def _walk(storage, tenants, q, runner, detail: bool) -> dict:
     }
     if detail:
         tree["filter"] = filter_plan_tree(q.filter)
-
-    tot = {"parts_total": 0, "parts_retained": 0, "parts_killed": 0,
-           "parts_cached": 0, "blocks_candidate": 0, "rows_scanned": 0,
-           "bytes_scanned": 0, "dispatches": 0, "bytes_staged": 0}
-    cost = {"rtt_s": 0.0, "device_scan_s": 0.0, "upload_s": 0.0,
-            "emit_s": 0.0, "host_s": 0.0}
 
     # result-cache peek (engine/standing/resultcache.py): parts whose
     # answer would replay from the cache are priced ~0 — the admission
@@ -149,239 +135,135 @@ def _walk(storage, tenants, q, runner, detail: bool) -> dict:
                                   min_ts, max_ts)
 
     active_pts = 0
-    retained_all: list = []   # (pnode, part, bis, rows_cand, bytes_est)
     for pt in storage.select_partitions(min_ts, max_ts):
-        pnode, retained = _walk_partition(
-            pt, tenants, tenant_set, min_ts, max_ts, sfs,
-            token_leaves, detail, tot, qcache)
-        if retained:
+        pw = PartitionWalk(pt, tenants, min_ts, max_ts, sfs)
+        pnode: dict = {"name": "partition",
+                       "day": getattr(pt, "day", None),
+                       "parts": [], "units": []}
+        if pw.pruned_by_stream_filter:
+            pnode["pruned_by_stream_filter"] = True
+        n0 = len(pricing.retained)
+        # detailed plans apply the execution walk's own aggregate build
+        # gate; the cheap pass probes CACHED aggregates only — a cold
+        # part the execution would build+kill shows up as prediction
+        # error instead of a second cold fold per query
+        for step in pw.steps(token_leaves, build=detail):
+            pricing.add(step, qcache, pnode if detail else None)
+        if len(pricing.retained) > n0:
             active_pts += 1
-        retained_all.extend((pnode, p, b, rc, be)
-                            for p, b, rc, be in retained)
         if detail:
             tree["partitions"].append(pnode)
 
-    # planned dispatch units: THE pack-membership rules the window
-    # dispatches with (pipeline.pack_policy + iter_pack_groups), run
-    # over the CROSS-PARTITION retained stream exactly like the
-    # execution planner — packs may span a day boundary, and the unit
-    # seq is global (it matches the window's submit/harvest span
-    # numbering, which _graft keys on).  A unit node hangs off the
-    # partition of its FIRST member.
-    _price_units(retained_all, runner, batch, peek, plans, shape,
-                 sort_spec, depth, detail, tot, cost)
-
     if not detail:
         tree.pop("partitions")
-
-    # host-path per-day partitions scan concurrently under the worker
-    # cap (engine/searcher._scan_partitions_parallel), so wall time
-    # divides by the effective partition parallelism.  The device
-    # path's cross-partition window overlaps round trips ACROSS
-    # partitions already (depth folded above): no extra parallelism.
-    npw = 1 if batch else max(1, min(active_pts, q.get_concurrency()))
-    duration = sum(cost.values()) / npw
-    tree["predicted"] = dict(tot)
-    tree["predicted"].update({k: round(v, 6) for k, v in cost.items()})
-    tree["predicted"]["duration_s"] = round(duration, 6)
-    tree["predicted"]["calibrated"] = peek["calibrated"]
+    tree["predicted"] = pricing.predicted(active_pts)
     return tree
 
 
-def _maplet_exact(part, token_leaves, bis):
-    """(exact_bis, killing_leaf, have_maplet): the sealed part's exact
-    AND-path candidate blocks from its token→block maplets.  Pure
-    probe — no trace/registry side effects, so both the explain
-    endpoint and the continuous pricing pass may call it; the AND
-    semantics live in ONE place (filterbank.maplet_leaf_keep, shared
-    with the execution pruning).  Classic parts return
-    (bis, None, False): their candidates stay the probabilistic
-    per-block estimate."""
-    from ..storage.filterbank import maplet_leaf_keep
-    from ..storage.filterindex import part_index
-    fi = part_index(part)
-    if fi is None:
-        return bis, None, False
-    keep, kill_leaf = maplet_leaf_keep(fi, token_leaves, bis)
-    if kill_leaf is not None:
-        return [], kill_leaf, True
-    if keep is None:
-        return bis, None, True
-    return [bi for bi, k in zip(bis, keep) if k], None, True
+def _kill_citation(killed_by) -> dict:
+    field, tokens, f, artifact = killed_by
+    return {"field": field, "tokens": list(tokens),
+            "filter": f.to_string(), "artifact": artifact}
 
 
-def _part_header_table(part) -> dict:
-    """Per-part header summary cached on the (immutable) part object —
-    the pricing walk runs on EVERY query, so the per-block header
-    object churn (stream ids, row counts) is paid once per part
-    lifetime instead of once per query.  Same attach idiom as
-    storage/filterbank.filter_bank."""
-    t = getattr(part, "_explain_htab", None)
-    if t is None:
-        nb = part.num_blocks
-        sids = [part.block_stream_id(bi) for bi in range(nb)]
-        rows = [part.block_rows(bi) for bi in range(nb)]
-        tset = {s.tenant for s in sids}
-        t = {
-            "sids": sids, "rows": rows, "rows_total": sum(rows),
-            "uniform_tenant": next(iter(tset)) if len(tset) == 1
-            else None,
-        }
-        part._explain_htab = t
-    return t
+class _Pricing:
+    """One query's tally of walk steps and their price: what `_walk`
+    shows as a plan and what the engine writes on the activity record,
+    from the same steps."""
 
+    def __init__(self, q, runner, stats_spec, sort_spec, detail=False):
+        self.q, self.runner, self.detail = q, runner, detail
+        self.batch = runner is not None
+        self.sort_spec = sort_spec
+        self.peek = runner.cost.peek() if self.batch \
+            else dict(_HOST_ONLY_PEEK)
+        self.plans = []
+        self.depth = 1
+        if self.batch:
+            from ..tpu.batch import device_plans
+            from ..tpu.pipeline import inflight_depth
+            self.plans = device_plans(q.filter)
+            # the SAME depth derivation the window dispatches with,
+            # minus the lazy RTT probe (explain must stay zero-dispatch)
+            self.depth = inflight_depth(runner, probe=False)
+        self.shape = "stats" if stats_spec is not None else \
+            "topk" if sort_spec is not None else "rows"
+        self.tot = {"parts_total": 0, "parts_retained": 0,
+                    "parts_killed": 0, "parts_cached": 0,
+                    "blocks_candidate": 0, "rows_scanned": 0,
+                    "bytes_scanned": 0, "dispatches": 0,
+                    "bytes_staged": 0}
+        self.retained: list = []  # (pnode, part, bis, rows, bytes_est)
 
-def _walk_partition(pt, tenants, tenant_set, min_ts, max_ts, sfs,
-                    token_leaves, detail, tot, qcache=None):
-    from ..storage.filterbank import aggregate_kill_leaf
-
-    pnode: dict = {"name": "partition",
-                   "day": getattr(pt, "day", None),
-                   "parts": [], "units": []}
-    allowed_sids = None
-    if sfs:
-        allowed_sids = set.intersection(
-            *(f.resolve(pt, tenants) for f in sfs))
-        if not allowed_sids:
-            pnode["pruned_by_stream_filter"] = True
-            return pnode, []
-
-    retained: list = []      # (part, bis, rows_cand, bytes_est)
-    for part in pt.ddb.snapshot_parts():
-        if not part.num_rows:
-            continue
+    def add(self, step, qcache, pnode=None) -> None:
+        """Tally one walk step; `pnode` (the explain endpoint only)
+        receives the per-part detail node — the continuous pass must
+        not allocate throwaway dicts per part."""
+        tot = self.tot
+        part, bis = step.part, step.bis
         tot["parts_total"] += 1
-        # per-part detail nodes only exist on the explain endpoint; the
-        # continuous pricing pass (detail=False, every query) must not
-        # allocate throwaway dicts per part
-        node: dict = {"part": str(part.uid), "rows": part.num_rows,
-                      "blocks": part.num_blocks} if detail else {}
-        if part.min_ts > max_ts or part.max_ts < min_ts:
+        node = None
+        if pnode is not None:
+            node = {"part": str(part.uid), "rows": part.num_rows,
+                    "blocks": part.num_blocks}
+            pnode["parts"].append(node)
+        if step.reason is not None:
             tot["parts_killed"] += 1
-            if detail:
-                node.update(status="killed", reason="time_range")
-                pnode["parts"].append(node)
-            continue
-        bis: list = []
-        rows_cand = 0
-        n_time = n_tenant = 0
-        if part.min_ts >= min_ts and part.max_ts <= max_ts:
-            # part fully inside the range: every block is a time
-            # candidate — the cached header table answers the tenant/
-            # stream filtering without touching header groups
-            htab = _part_header_table(part)
-            sids, rows = htab["sids"], htab["rows"]
-            n_time = len(sids)
-            if htab["uniform_tenant"] is not None and \
-                    htab["uniform_tenant"] not in tenant_set:
-                pass                       # n_tenant stays 0: killed
-            elif htab["uniform_tenant"] is not None and \
-                    allowed_sids is None:
-                n_tenant = n_time
-                bis = list(range(n_time))
-                rows_cand = htab["rows_total"]
-            else:
-                for bi, sid in enumerate(sids):
-                    if sid.tenant not in tenant_set:
-                        continue
-                    n_tenant += 1
-                    if allowed_sids is not None and \
-                            sid not in allowed_sids:
-                        continue
-                    bis.append(bi)
-                    rows_cand += rows[bi]
-        else:
-            block_sid = part.block_stream_id
-            block_rows = part.block_rows
-            for bi in part.candidate_blocks(min_ts, max_ts):
-                n_time += 1
-                sid = block_sid(bi)
-                if sid.tenant not in tenant_set:
-                    continue
-                n_tenant += 1
-                if allowed_sids is not None and sid not in allowed_sids:
-                    continue
-                bis.append(bi)
-                rows_cand += block_rows(bi)
-        if not bis:
-            tot["parts_killed"] += 1
-            if detail:
-                node.update(status="killed",
-                            reason="time_range" if n_time == 0 else
-                            "tenant" if n_tenant == 0 else
-                            "stream_filter")
-                pnode["parts"].append(node)
-            continue
-        if token_leaves:
-            # detailed plans apply the execution walk's own build gate;
-            # the cheap continuous pass probes CACHED aggregates only
-            # (build=False) — with the result memo those repeats are
-            # dict lookups, and a cold part the execution would build+
-            # kill shows up as prediction error instead of a second
-            # cold fold per query.  Sealed v2 parts (filter-index
-            # sidecar) answer either way from the loaded xor aggregate.
-            killed = aggregate_kill_leaf(
-                part, token_leaves,
-                build=detail and len(bis) * 4 >= part.num_blocks)
-            if killed is not None:
-                field, tokens, f, artifact = killed
-                tot["parts_killed"] += 1
-                if detail:
-                    node.update(status="killed",
-                                reason="xor_aggregate"
-                                if artifact == "xor_aggregate"
-                                else "aggregate_bloom",
-                                killed_by={"field": field,
-                                           "tokens": list(tokens),
-                                           "filter": f.to_string(),
-                                           "artifact": artifact})
-                    pnode["parts"].append(node)
-                continue
+            if pnode is not None:
+                node.update(status="killed", reason=step.reason)
+                if step.killed_by is not None:
+                    node["killed_by"] = _kill_citation(step.killed_by)
+            return
+        if pnode is not None and len(bis) != step.n_cand:
             # sealed v2 parts: the token→block maplet yields the EXACT
-            # candidate block list for the AND-path leaves — priced
-            # units reflect what the execution walk will dispatch, and
-            # an emptied list kills the part with the maplet cited
-            exact_bis, kill_leaf, have_maplet = _maplet_exact(
-                part, token_leaves, bis)
-            if kill_leaf is not None:
-                field, tokens, f = kill_leaf
-                tot["parts_killed"] += 1
-                if detail:
-                    node.update(status="killed", reason="maplet",
-                                killed_by={"field": field,
-                                           "tokens": list(tokens),
-                                           "filter": f.to_string(),
-                                           "artifact": "maplet"})
-                    pnode["parts"].append(node)
-                continue
-            if have_maplet and len(exact_bis) != len(bis):
-                bis = exact_bis
-                rows_cand = sum(part.block_rows(bi) for bi in bis)
-                if detail:
-                    node["maplet_exact"] = True
+            # candidate block list for the AND-path leaves
+            node["maplet_exact"] = True
+        tot["parts_retained"] += 1
         if qcache is not None and qcache.peek(part, bis):
             # the part's answer replays from the result cache: it is
             # retained but priced ~0 (no dispatch, no bytes scanned) —
             # the dashboard-refresh query pays only its unsealed head
-            tot["parts_retained"] += 1
             tot["parts_cached"] += 1
-            if detail:
+            if pnode is not None:
                 node.update(status="retained", cached=True,
                             blocks_candidate=len(bis))
-                pnode["parts"].append(node)
-            continue
-        bytes_est = int(rows_cand * activity.part_bytes_per_row(part))
-        tot["parts_retained"] += 1
+            return
+        bytes_est = int(step.rows * activity.part_bytes_per_row(part))
         tot["blocks_candidate"] += len(bis)
-        tot["rows_scanned"] += rows_cand
+        tot["rows_scanned"] += step.rows
         tot["bytes_scanned"] += bytes_est
-        if detail:
+        if pnode is not None:
             node.update(status="retained", blocks_candidate=len(bis),
-                        rows_candidate=rows_cand, bytes_est=bytes_est)
-            pnode["parts"].append(node)
-        retained.append((part, bis, rows_cand, bytes_est))
+                        rows_candidate=step.rows, bytes_est=bytes_est)
+        self.retained.append((pnode, part, bis, step.rows, bytes_est))
 
-    return pnode, retained
+    def predicted(self, active_pts: int) -> dict:
+        """Price the retained parts as planned dispatch units: THE
+        pack-membership rules the window dispatches with
+        (pipeline.pack_policy + iter_pack_groups), run over the
+        CROSS-PARTITION retained stream exactly like the execution
+        planner — packs may span a day boundary, and the unit seq is
+        global (it matches the window's submit/harvest span numbering,
+        which _graft keys on).  A unit node hangs off the partition of
+        its FIRST member."""
+        cost = {"rtt_s": 0.0, "device_scan_s": 0.0, "upload_s": 0.0,
+                "emit_s": 0.0, "host_s": 0.0}
+        _price_units(self.retained, self.runner, self.batch, self.peek,
+                     self.plans, self.shape, self.sort_spec, self.depth,
+                     self.detail, self.tot, cost)
+        # host-path per-day partitions scan concurrently under the
+        # worker cap (engine/searcher._scan_partitions_parallel), so
+        # wall time divides by the effective partition parallelism.
+        # The device path's cross-partition window overlaps round trips
+        # ACROSS partitions already (depth folded above): no extra
+        # parallelism.
+        npw = 1 if self.batch else \
+            max(1, min(active_pts, self.q.get_concurrency()))
+        pred = dict(self.tot)
+        pred.update({k: round(v, 6) for k, v in cost.items()})
+        pred["duration_s"] = round(sum(cost.values()) / npw, 6)
+        pred["calibrated"] = self.peek["calibrated"]
+        return pred
 
 
 def _price_units(retained_all, runner, batch, peek, plans, shape,
@@ -515,14 +397,20 @@ def _prefers_host(peek, cand_rows, scan_bytes, n_dispatch, cold_bytes,
 
 # ---------------- continuous pricing (engine hook) ----------------
 
-def price_into_activity(storage, tenants, q, runner, act) -> None:
-    """Plan-time pricing for ONE query: predicted summary onto the
-    activity record (counters named predicted_* so they ride the
-    query_done journal event next to the actuals; obs/activity folds
-    the pair into vl_cost_model_rel_error_* at deregister).  Advisory:
-    never fails the query."""
+def price_into_activity(items, q, runner, act, stats_spec, sort_spec,
+                        qcache) -> None:
+    """Plan-time pricing for ONE query from the header walk its
+    execution is about to consume (`items`: the engine's retained
+    (PartStep, ctx) list): predicted summary onto the activity record
+    (counters named predicted_* so they ride the query_done journal
+    event next to the actuals; obs/activity folds the pair into
+    vl_cost_model_rel_error_* at deregister).  Advisory: never fails
+    the query."""
     try:
-        pred = predict_query(storage, tenants, q, runner)
+        pricing = _Pricing(q, runner, stats_spec, sort_spec)
+        for step, _ctx in items:
+            pricing.add(step, qcache)
+        pred = pricing.predicted(0)
     # vlint: allow-broad-except(pricing is advisory, the query must run)
     except Exception:
         return

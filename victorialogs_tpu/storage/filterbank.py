@@ -483,10 +483,17 @@ def _observe_keep(keep: np.ndarray, observe: bool = True) -> np.ndarray:
 def aggregate_kill_leaf(part, leaves, build: bool = True):
     """The (field, tokens, owner_filter, artifact) leaf whose required
     tokens are provably absent from every block of the part, or None —
-    the EXPLAIN plan's kill citation (obs/explain.py) and the predicate
-    behind part_aggregate_prunes.  No trace/registry side effects: pure
-    probe, so the pricing pass can call it without polluting the
-    counters the execution walk will land.
+    the header walk's O(1) part-level kill (engine/planwalk.py) and the
+    EXPLAIN plan's kill citation.  No trace/registry side effects: pure
+    probe (planwalk.observe lands the execution's counters).
+
+    leaves: [(field, tokens, owner_filter)] from
+    logsql.filters.iter_and_path_token_leaves — owner_filter carries the
+    per-filter token-hash cache so tokens hash once per query.
+    build=False probes only aggregates that already exist (a cold build
+    reads every block's bloom, which a time-narrow query touching few
+    candidate blocks should not pay — the caller gates on candidate
+    coverage).
 
     Sealed v2 parts probe the xor-filter aggregate first (artifact
     `xor_aggregate`: ~0.62x the bits/key and a fixed ~2^-8 fp rate, so
@@ -515,37 +522,16 @@ def aggregate_kill_leaf(part, leaves, build: bool = True):
     return None
 
 
-def part_aggregate_prunes(part, leaves, build: bool = True) -> bool:
-    """O(1) part-level kill: True when some AND-path filter leaf's
-    required tokens are provably absent from every block of the part.
-
-    leaves: [(field, tokens, owner_filter)] from
-    logsql.filters.iter_and_path_token_leaves — owner_filter carries the
-    per-filter token-hash cache so tokens hash once per query.
-    build=False probes only aggregates that already exist (a cold build
-    reads every block's bloom, which a time-narrow query touching few
-    candidate blocks should not pay — the caller gates on candidate
-    coverage)."""
-    killed = aggregate_kill_leaf(part, leaves, build=build)
-    if killed is not None:
-        field, _tokens, _f, artifact = killed
-        sp = tracing.current_span()
-        if sp.enabled:
-            sp.add("parts_pruned_aggregate")
-            sp.set("last_aggregate_prune_field", field)
-            sp.set("last_aggregate_prune_artifact", artifact)
-        activity.current_activity().add("parts_pruned")
-        return True
-    return False
-
-
 def maplet_leaf_keep(fi, leaves, bis):
-    """THE shared AND-path maplet core — both the execution pruning
-    below and the EXPLAIN walk (obs/explain._maplet_exact) ride it, so
-    the priced candidate set can never diverge from what execution
-    dispatches.  Returns (keep bool[len(bis)] | None, killing_leaf |
-    None): keep is None when no leaf had maplet coverage; killing_leaf
-    is the first leaf whose candidates emptied."""
+    """THE AND-path maplet core of the header walk (engine/planwalk.py):
+    ONE lookup per leaf over the sealed part's token→block maplets.
+    The blocks it drops are exactly those the per-leaf kill-path would
+    have zeroed (the maplet is exact on token membership), so results
+    are identical — the kill only moves before any header, bloom or
+    dispatch work, where the EXPLAIN planner can count it.  Returns
+    (keep bool[len(bis)] | None, killing_leaf | None): keep is None
+    when no leaf had maplet coverage; killing_leaf is the first leaf
+    whose candidates emptied."""
     keep = None
     for field, tokens, f in leaves:
         if not fi.has(field):
@@ -555,38 +541,3 @@ def maplet_leaf_keep(fi, leaves, bis):
         if not keep.any():
             return keep, (field, tokens, f)
     return keep, None
-
-
-def maplet_prune_candidates(part, leaves, bis, observe: bool = True):
-    """Exact AND-path block pruning from the sealed part's token→block
-    maplets: ONE lookup per leaf yields the candidate block list, so
-    blocks that cannot satisfy every AND-path token leaf drop out
-    BEFORE any header/bloom/dispatch work.  Returns the pruned block-id
-    list (possibly `bis` unchanged); classic parts (no v2 sidecar)
-    return `bis` untouched — their pruning happens per-leaf in
-    bloom_keep_mask.
-
-    The dropped blocks are exactly those the per-leaf kill-path would
-    have zeroed (the maplet is exact on token membership), so results
-    are identical — this only moves the kill earlier and makes its
-    size knowable to the EXPLAIN planner."""
-    from .filterindex import part_index
-    fi = part_index(part)
-    if fi is None or not leaves or not bis:
-        return bis
-    keep, _kill_leaf = maplet_leaf_keep(fi, leaves, bis)
-    if keep is None:
-        return bis
-    n = len(bis)
-    killed = n - int(keep.sum())
-    if observe:
-        sp = tracing.current_span()
-        if sp.enabled:
-            sp.add("blocks_probed_maplet", n)
-            sp.add("blocks_killed_maplet", killed)
-        if killed:
-            activity.current_activity().add("blocks_killed_maplet",
-                                            killed)
-    if not killed:
-        return bis
-    return [bi for bi, k in zip(bis, keep) if k]

@@ -896,6 +896,9 @@ class BatchRunner:
         # async pipeline observability (tpu/pipeline.py)
         self.pipeline_units = 0        # units driven through the window
         self.scanned_parts = 0         # member parts those units carried
+        self.shared_plan_walks = 0     # queries whose window consumed
+        #                                the header walk their pricing
+        #                                ran (engine/searcher)
         self.replicated_row_puts = 0   # mesh: row arrays that could not
         #                                stripe and were replicated
         self.packed_dispatches = 0     # super-dispatches over packed parts
@@ -980,6 +983,7 @@ class BatchRunner:
                 "maplet_pruned_blocks": self.maplet_pruned_blocks,
                 "pipeline_units": self.pipeline_units,
                 "scanned_parts": self.scanned_parts,
+                "shared_plan_walks": self.shared_plan_walks,
                 "replicated_row_puts": self.replicated_row_puts,
                 "packed_dispatches": self.packed_dispatches,
                 "packed_parts": self.packed_parts,

@@ -458,10 +458,12 @@ def iter_pack_groups(items, packable: bool, pack_max: int,
 def _unit_stream(runner, items, head, stats_spec, sort_spec,
                  token_leaves, check_deadline, qcache=None):
     """Lazily fold the pruned part stream into dispatch units, in part
-    order.  `items` yields (part, cand_fn, ctx) — the cross-partition
-    window feeds parts from EVERY selected partition through one
-    stream (each carrying its partition's SearchContext), so packs may
-    span a day boundary when the members share a pad bucket.
+    order.  `items` yields (PartStep, ctx), the header walk's surviving
+    parts with their candidate blocks (engine/planwalk.py) — the
+    cross-partition window feeds parts from EVERY selected partition
+    through one stream (each carrying its partition's SearchContext),
+    so packs may span a day boundary when the members share a pad
+    bucket.
 
     Consecutive parts pack when packing is on, the query shape supports
     a pack dispatch (pack_policy — sort-topk packs under the
@@ -472,10 +474,9 @@ def _unit_stream(runner, items, head, stats_spec, sort_spec,
     early exit (head.is_done) or a deadline must stop the header walk
     exactly like the serial loop did — the consumer only pulls the
     window's lookahead ahead of execution."""
+    from ..engine import planwalk
     from ..engine.block_search import BlockSearch
     from ..engine.searcher import QueryCancelled
-    from ..storage.filterbank import (maplet_prune_candidates,
-                                      part_aggregate_prunes)
     packable, pack_max, rows_cap = pack_policy(runner, sort_spec)
 
     def make_unit(group):
@@ -523,33 +524,20 @@ def _unit_stream(runner, items, head, stats_spec, sort_spec,
     act = activity.current_activity()
 
     def pruned():
-        for part, cand_fn, ctx in items:
+        for step, ctx in items:
             check_deadline()
             if head.is_done():
                 raise QueryCancelled()
-            bis = cand_fn(part)
-            if not bis:
+            if step.cold and planwalk.kill_cold(step, token_leaves):
+                # the priced walk probed cached aggregates only: the
+                # cold fold, and its kill, is paid as the window
+                # reaches the part
+                planwalk.observe(step, runner)
                 continue
-            if token_leaves and part_aggregate_prunes(
-                    part, token_leaves,
-                    build=len(bis) * 4 >= part.num_blocks):
-                runner._bump("agg_pruned_parts")
-                continue
-            if token_leaves:
-                # sealed v2 parts: exact maplet block pruning before
-                # staging/packing — the dropped blocks are the ones
-                # the in-dispatch kill would have zeroed anyway
-                pruned_bis = maplet_prune_candidates(part, token_leaves,
-                                                     bis)
-                if len(pruned_bis) != len(bis):
-                    runner._bump("maplet_pruned_blocks",
-                                 len(bis) - len(pruned_bis))
-                    bis = pruned_bis
-                if not bis:
-                    continue
+            part, bis = step.part, step.bis
             # registry progress at part granularity (the planning pull
             # IS the prune stage, so these land as the walk advances)
-            activity.note_part_scanned(act, part, bis)
+            activity.note_part_scanned(act, part, bis, step.rows)
             if qcache is not None:
                 e = qcache.probe(part, bis)
                 if e is not None:
@@ -714,14 +702,15 @@ def scan_device_stream(items, q, head, runner, needed, deadline,
     """Drive a cross-partition part stream through the async dispatch
     window.
 
-    Candidate pruning and part-aggregate kills are the host walk's
-    (engine/searcher._scan_parts); submission
+    Candidate pruning and part-aggregate kills are the one header
+    walk's (engine/planwalk.py); submission
     keeps up to VL_INFLIGHT units' dispatches outstanding; harvest is in
     submission order, so downstream block order and stats absorb
     granularity are identical to the serial path.  `items` yields
-    (part, cand_fn, ctx) lazily — partitions resolve their stream
+    (PartStep, ctx): the list a priced query's walk already produced,
+    or the walk itself, lazy — partitions then resolve their stream
     filters and snapshot their parts only as the planning pull reaches
-    them, so parts from partition N+1 submit while partition N
+    them.  Either way parts from partition N+1 submit while partition N
     harvests, prefetch depth survives the day boundary, and packs may
     span it (iter_pack_groups' pad-bucket + time-span rules)."""
     from ..engine.block_result import BlockResult
@@ -793,9 +782,10 @@ def scan_device_stream(items, q, head, runner, needed, deadline,
         if exhausted or len(lookahead) >= depth + 1:
             return
         act.set_phase("prune")
-        # the planning pull IS the prune stage: candidate selection +
-        # part-aggregate kills run inside _unit_stream, so filterbank's
-        # prune counters land on this span
+        # the planning pull IS the prune stage: a lazy header walk
+        # advances inside _unit_stream, so its prune counters land on
+        # this span (a priced query's walk ran under a `prune` span of
+        # its own, engine/searcher)
         with psp.span("prune") as prsp:
             planned = 0
             while not exhausted and len(lookahead) < depth + 1:
