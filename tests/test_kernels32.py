@@ -371,52 +371,193 @@ def test_to_lanes32_layout_contract():
 
 
 # ---------------- the Pallas launcher, interpreted ----------------
+#
+# The launcher sweeps each tile of rows only to its longest row
+# (kernels32.sweep_steps); the direct launcher sweeps the column's whole
+# width.  Both must agree bit for bit, and with the u8 oracle, on
+# columns whose sweep tiles differ in their longest row.
 
-@pytest.mark.parametrize("width,rows", [(16, 1024), (128, 2048),
-                                        (256, 4096)])
-def test_pallas_launcher_matches_the_body(width, rows):
-    """The TPU launcher (blocks of rows in VMEM, a register tile at a
-    time) runs the SAME body; interpreted on jax-CPU it must agree with
-    the direct launcher bit for bit (as tests/test_pallas.py does for
-    the other kernels)."""
+PHRASE = b"deadline exceeded"                # 17 B, the benchmark's
+LEAVES = [(PHRASE, K.MODE_PHRASE, True, True, False),
+          (b"line exc", K.MODE_SUBSTRING, False, False, False),
+          (b"dead", K.MODE_PREFIX, True, False, False),
+          (PHRASE, K.MODE_PHRASE, True, True, True),
+          (PHRASE, K.MODE_EXACT, False, False, False)]
+PAIR = (b"dead", b"exceeded")
+
+
+def _busy(longest: int) -> list[bytes]:
+    """Rows of a tile whose longest is `longest` bytes: the phrase, the
+    pair and near misses at every alignment, cut to fit."""
+    vals = [b"-" * k + PHRASE + b" tail" for k in range(8)]
+    vals += [b"dead " + b"x" * k + b" exceeded" for k in range(4)]
+    vals += [b"exceeded dead", b"Deadline EXCEEDED now", b"dead\nexceeded",
+             b"a" * longest]
+    return [v[:longest] for v in vals]
+
+
+def _at_the_end(longest: int) -> list[bytes]:
+    """Rows of `longest` bytes that end in each leaf's pattern (the
+    phrase after a space and after a word char, the substring, the pair
+    with its B last), and a shorter row."""
+    return [b" " * (longest - 17) + PHRASE, b"x" * (longest - 17) + PHRASE,
+            b"." * (longest - 8) + b"line exc",
+            b"dead" + b"=" * (longest - 12) + b"exceeded",
+            (b"dead " + PHRASE)[:longest - 1]]
+
+
+def _at_the_end_folded(longest: int) -> list[bytes]:
+    return [b" " * (longest - 17) + b"DEADLINE EXCEEDED",
+            b"-" * (longest - 17) + b"Deadline Exceeded",
+            b"x" * (longest - 17) + b"DeadLine exceeded", b"dead exceeded"]
+
+
+def _newline_last(longest: int) -> list[bytes]:
+    """`A.*B` rows whose last live byte is a newline, beside rows
+    without one and with one first."""
+    return [b"dead" + b" " * (longest - 13) + b"exceeded\n",
+            b"dead exceeded", b"\ndead exceeded", b"exceeded dead\n"]
+
+
+# case -> (width, the values of each sweep tile, the tiles' longest rows)
+TILE_CASES = {
+    "empty_tile": (128, [_busy(60), [b""] * 50, _busy(30), _busy(100)],
+                   [60, 0, 30, 100]),
+    "tile_shorter_than_the_pattern": (
+        128, [_busy(40), [PHRASE[:k] for k in range(17)] + [b"Dead"],
+              _busy(127), _busy(16)], [40, 16, 127, 16]),
+    "match_ends_on_the_last_live_byte": (
+        128, [_at_the_end(37 + t) for t in range(4)], [37, 38, 39, 40]),
+    "end_token_reads_the_first_padding_byte": (
+        128, [_at_the_end(17 + t) + [b"  " + PHRASE + b"s"]
+              for t in (3, 4, 5, 6)], [20, 21, 22, 23]),
+    "folded": (128, [_at_the_end_folded(17 + t) for t in (0, 5, 10, 23)],
+               [17, 22, 27, 40]),
+    "pair_newline_in_the_last_live_byte": (
+        128, [_newline_last(21 + t) for t in range(4)], [21, 22, 23, 24]),
+    "one_row_at_w_minus_1": (
+        512, [_busy(20), _busy(20) + [b" " * 494 + PHRASE], _busy(30),
+              [b"dead" + b"=" * 499 + b"exceeded"] + _busy(9)],
+        [20, 511, 30, 511]),
+}
+
+
+def _tiled(width: int, tiles: list[list[bytes]]):
+    """One sweep tile of the Pallas launcher a list of values."""
+    nl = width // 4
+    tile = K32.sweep_blocks(nl, len(tiles) * 16)[1] * 128
+    rows = len(tiles) * tile
+    assert K32.sweep_blocks(nl, rows // 128)[1] * 128 == tile
+    mat, lens = _matrix([], width, rows=rows)
+    for t, vals in enumerate(tiles):
+        sub, sl = _matrix(vals, width, rows=tile)
+        mat[t * tile:(t + 1) * tile], lens[t * tile:(t + 1) * tile] = sub, sl
+    return mat, lens, tile
+
+
+def _old_column(width: int, rows: int):
     pat = b"dead line"
     vals = (_edge_values(pat, width) + _edge_values(b"EXC", width)) * 3
     mat, lens = _matrix(vals, width, rows=rows)
     mat[rows - 1, :len(pat)] = np.frombuffer(pat, dtype=np.uint8)
     lens[rows - 1] = len(pat)
+    leaves = [(pat, K.MODE_PHRASE, True, True, False),
+              (b"exc", K.MODE_PREFIX, True, False, True),
+              (b"ad li", K.MODE_SUBSTRING, False, False, False),
+              (pat, K.MODE_EXACT, False, False, False)]
+    return mat, lens, leaves, (b"dead", b"ne")
+
+
+@pytest.mark.parametrize("width,rows,case", [
+    (16, 1024, None), (128, 2048, None), (256, 4096, None),
+    *((None, None, c) for c in TILE_CASES)],
+    ids=["16-1024", "128-2048", "256-4096", *TILE_CASES])
+def test_pallas_launcher_matches_the_body(width, rows, case):
+    """The TPU launcher (blocks of rows in VMEM, a register tile at a
+    time, each swept to its longest row) runs the SAME body; interpreted
+    on jax-CPU it must agree bit for bit with the direct launcher, which
+    sweeps the whole width, and with the u8 oracle (as
+    tests/test_pallas.py does for the other kernels)."""
+    if case is None:
+        mat, lens, leaves, (pa, pb) = _old_column(width, rows)
+    else:
+        width, tiles, longest = TILE_CASES[case]
+        mat, lens, tile = _tiled(width, tiles)
+        assert list(lens.reshape(-1, tile).max(axis=1)) == longest
+        leaves, (pa, pb) = LEAVES, PAIR
     lanes = jnp.asarray(to_lanes32(mat))
     lens2 = jnp.asarray(lens).reshape(-1, 128)
     nl = width // 4
-    for p, mode, st, et, fold in [(pat, K.MODE_PHRASE, True, True, False),
-                                  (b"exc", K.MODE_PREFIX, True, False, True),
-                                  (b"ad li", K.MODE_SUBSTRING, False, False,
-                                   False),
-                                  (pat, K.MODE_EXACT, False, False, False)]:
+    hits = 0
+    for p, mode, st, et, fold in leaves:
+        if fold:
+            p = p.lower()
         pc, masks = K32._pattern_chunks(_u8(p), len(p))
         ns = st and mode in (K.MODE_PHRASE, K.MODE_PREFIX)
         ne = et and mode == K.MODE_PHRASE
 
-        def body(load, tile_lens, pcs):
+        def body(load, tile_lens, pcs, tile_max):
             return [K32._scan_rows(load, nl, tile_lens, pcs, masks, len(p),
-                                   mode, ns, ne, fold)]
+                                   mode, ns, ne, fold, tile_max)]
         code = np.asarray(K32._launch_pallas(body, lanes, lens2, pc,
                                              interpret=True))
-        want = np.asarray(K32.match_scan_t(lanes, jnp.asarray(lens), _u8(p),
-                                           len(p), mode, st, et, fold))
+        want = _assert_scan_parity(mat, lens, p, mode, st, et, fold)
         assert np.array_equal(code.reshape(-1) != 0, want), (p, mode)
-        assert want.any()
-    pa, pb = b"dead", b"ne"
+        if case is None:
+            assert want.any()
+        hits += int(want.sum())
+    assert hits
     ca, ma = K32._pattern_chunks(_u8(pa), len(pa))
     cb, mb = K32._pattern_chunks(_u8(pb), len(pb))
 
-    def pair(load, tile_lens, pcs):
+    def pair(load, tile_lens, pcs, tile_max):
         return K32._pair_rows(load, nl, tile_lens, pcs, ma, mb, len(pa),
-                              len(pb))
+                              len(pb), tile_max)
     code = np.asarray(K32._launch_pallas(
         pair, lanes, lens2, jnp.concatenate([ca, cb]),
         interpret=True)).reshape(-1)
     wd, wv = K32.match_ordered_pair_t(lanes, jnp.asarray(lens), _u8(pa),
                                       len(pa), _u8(pb), len(pb))
+    od, ov = K.match_ordered_pair(jnp.asarray(mat), jnp.asarray(lens),
+                                  _u8(pa), len(pa), _u8(pb), len(pb))
+    assert np.array_equal(np.asarray(wd), np.asarray(od))
+    assert np.array_equal(np.asarray(wv), np.asarray(ov))
     assert np.array_equal(code & 1 != 0, np.asarray(wd))
     assert np.array_equal(code & 2 != 0, np.asarray(wv))
     assert np.asarray(wd).any()
+    if case == "pair_newline_in_the_last_live_byte":
+        assert np.asarray(wv).any()
+
+
+SWEEP_CASES = [  # (tile_max, pat_len or None for `A.*B`, nl)
+    (0, 1, 8), (0, None, 8), (3, 4, 8), (3, 5, 8), (4, 4, 8), (5, 4, 8),
+    (16, 17, 32), (17, 17, 32), (20, 17, 32), (21, 17, 32), (73, 17, 32),
+    (127, 17, 32), (127, 1, 32), (127, 4, 32), (127, 128, 32),
+    (73, None, 32), (72, None, 32), (1, None, 32), (127, None, 32),
+    (2047, 17, 512), (2047, None, 512), (31, 31, 8), (31, 32, 8)]
+
+
+def test_the_host_count_of_sweep_steps_is_the_kernels():
+    """The host's count of steps swept and skipped (FusedField) takes
+    the kernel's own arithmetic (kernels32.sweep_steps, traced in the
+    kernel): equal on every case, equal to the windows that fit, and
+    swept + skipped is the whole width's count."""
+    from victorialogs_tpu.tpu.fused import FusedField
+    for tile_max, pat_len, nl in SWEEP_CASES:
+        traced = int(jax.jit(lambda t: K32.sweep_steps(t, nl, pat_len))(
+            jnp.int32(tile_max)))
+        whole = K32.sweep_steps(None, nl, pat_len)
+        if pat_len is None:      # a byte of a row in plane q
+            fit = sum(4 * q < tile_max for q in range(nl))
+        else:                    # a window that starts in plane q fits
+            fit = sum(4 * q + pat_len <= min(tile_max, 4 * nl)
+                      for q in range(whole))
+        ff = FusedField(rows=None, lengths=None, width=4 * nl,
+                        ovf_packed=None, ovf_np=None, has_ovf=False,
+                        nbytes=0, tile_max=(np.array([tile_max, 0]),
+                                            np.array([3, 2])))
+        swept, skipped = ff.sweep_steps(pat_len)
+        case = (tile_max, pat_len, nl)
+        assert traced == fit, case
+        assert swept == 3 * traced, case
+        assert swept + skipped == 5 * whole, case

@@ -60,9 +60,11 @@ SCANS = [(17, K.MODE_PHRASE, True, True, False),
 
 
 # the benchmark's `_msg` (W=128, parts of millions of rows), the
-# narrowest and the widest column, and the smallest row bucket
+# narrowest and the widest column, the smallest row bucket, and the
+# largest layout the device takes (stats_device.MAX_STAT_ROWS)
 @pytest.mark.parametrize("width,rows", [(128, 1 << 21), (32, 1 << 21),
-                                        (2048, 1 << 16), (64, 1024)])
+                                        (2048, 1 << 16), (64, 1024),
+                                        (128, 16 << 20)])
 def test_every_leaf_kind_compiles_through_mosaic(topo, as_on_the_chip,
                                                  width, rows):
     one = SingleDeviceSharding(topo.devices[0])
@@ -82,6 +84,44 @@ def test_every_leaf_kind_compiles_through_mosaic(topo, as_on_the_chip,
     assert text.count("tpu_custom_call") == 1
     for op in ("reverse(", " sort("):
         assert op not in text, op
+
+
+def _pallas_calls(jaxpr):
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            yield e
+        for v in e.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield from _pallas_calls(inner)
+
+
+@pytest.mark.parametrize("rows", [1 << 21, 16 << 20])
+def test_the_tile_table_is_an_smem_block_a_grid_step(as_on_the_chip, rows):
+    """Each sweep tile's longest row reaches the kernel as ONE SMEM
+    block a grid step, of ts // sub words whatever R is (the table of a
+    16M-row layout is never prefetched whole); the pattern's chunks stay
+    the one scalar-prefetched operand."""
+    from victorialogs_tpu.tpu.stats_device import MAX_STAT_ROWS
+    assert rows <= MAX_STAT_ROWS
+    lanes, lens = _shapes(128, rows, None)
+    pat = jax.ShapeDtypeStruct((17,), jnp.uint8)
+    ts, sub = K32.sweep_blocks(32, rows // 128)
+    jaxpr = jax.make_jaxpr(lambda l, n, p: K32.match_ordered_pair_t(
+        l, n, p[:4], 4, p[4:], 13)[0] | K32.match_scan_t(
+        l, n, p, 17, K.MODE_PHRASE, True, True))(lanes, lens, pat).jaxpr
+    calls = list(_pallas_calls(jaxpr))
+    assert calls
+    for e in calls:
+        gm = e.params["grid_mapping"]
+        assert gm.num_index_operands == 1
+        assert gm.grid == (rows // 128 // ts,)
+        smem = [bm for bm in gm.block_mappings
+                if str(bm.block_aval.memory_space) == "smem"]
+        assert len(smem) == 1
+        bm = smem[0]
+        assert bm.array_aval.shape == (rows // 128 // ts, 1, ts // sub)
+        assert bm.block_aval.shape == (1, ts // sub) == (1, 8)
 
 
 def test_a_stripe_under_a_mesh_axis_runs_the_body_directly(

@@ -411,6 +411,44 @@ def test_plane_scan_leaves_counted_where_the_dispatch_is_issued(
     assert re.search(rb"^vl_tpu_plane_scan_leaves \d+$", text, re.M)
 
 
+@pytest.mark.parametrize("query,sweeps", [
+    ("error | stats count() c", True),
+    ('_msg:~"dead.*beef" NOT ok | stats count() c', True),
+    ("* | stats count_uniq(app) u", False),
+])
+def test_plane_sweep_steps_counted_beside_the_leaves(
+        served, monkeypatch, query, sweeps):
+    """vl_tpu_scan_plane_steps_swept / _skipped, in sweep-tile steps:
+    the direct launcher (jax-CPU) sweeps every step and skips none;
+    where the sweep stops at each tile's longest row, the same
+    dispatches split the same steps into swept and skipped (the
+    fixture's rows are 17-27 B of a 32 B staging, and most of a part's
+    tiles are padding)."""
+    srv, storage, runner = served
+
+    def steps(bounded):
+        monkeypatch.setattr(runner, "sweeps_bounded", lambda: bounded)
+        before = runner.stats()
+        run_query_collect(storage, [TEN], query, runner=runner)
+        after = runner.stats()
+        return [after[k] - before[k] for k in (
+            "device_calls", "scan_plane_steps_swept",
+            "scan_plane_steps_skipped")]
+    calls, whole, none = steps(False)
+    calls2, swept, skipped = steps(True)
+    assert calls == calls2 > 0
+    assert none == 0
+    assert swept + skipped == whole
+    assert (skipped > 0 and swept > 0) == sweeps
+    if not sweeps:
+        assert whole == 0
+    status, text = _req(srv, "/metrics")
+    assert status == 200
+    for name in (rb"swept", rb"skipped"):
+        assert re.search(rb"^vl_tpu_scan_plane_steps_" + name + rb" \d+$",
+                         text, re.M)
+
+
 @pytest.fixture
 def stall_lines():
     lines = []

@@ -157,6 +157,11 @@ class MeshBatchRunner(BatchRunner):
         # diffed on one chip only; under shard_map the XLA twins serve
         return False
 
+    def sweeps_bounded(self) -> bool:
+        # a stripe under shard_map runs the plane kernel's body directly,
+        # over the column's whole width
+        return False
+
     def _put(self, arr, row_axis: int = 0):
         # shard the row axis when it divides evenly (stats layouts always
         # do; string-staging row buckets do for power-of-two mesh sizes),

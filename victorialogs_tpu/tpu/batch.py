@@ -879,6 +879,9 @@ class BatchRunner:
         self.plane_scan_leaves = 0     # scan and `A.*B` leaves those
         #                                dispatches ran through the plane
         #                                kernel (tpu/kernels32.py)
+        self.scan_plane_steps_swept = 0    # their sweep steps a tile, and
+        self.scan_plane_steps_skipped = 0  # those cut off by the tiles'
+        #                                longest rows (fused.FusedField)
         self.operand_blocks = 0        # fused/topk/filter dispatches that
         #                                shipped their host operands as
         #                                one block (tpu/fused.py:_launch)
@@ -947,6 +950,12 @@ class BatchRunner:
         seg-major count) where a dispatch has one."""
         return config.env("VL_PALLAS") == "1"
 
+    def sweeps_bounded(self) -> bool:
+        """Whether the plane kernel's sweep stops at each tile's longest
+        row here: the Pallas launcher, on the TPU backend."""
+        from .kernels32 import on_tpu
+        return on_tpu()
+
     def _bump(self, attr: str, n=1) -> None:
         with self._counter_mu:
             setattr(self, attr, getattr(self, attr) + n)
@@ -970,6 +979,8 @@ class BatchRunner:
             out = {
                 "device_calls": self.device_calls,
                 "plane_scan_leaves": self.plane_scan_leaves,
+                "scan_plane_steps_swept": self.scan_plane_steps_swept,
+                "scan_plane_steps_skipped": self.scan_plane_steps_skipped,
                 "operand_blocks": self.operand_blocks,
                 "cpu_fallbacks": self.cpu_fallbacks,
                 "gated_host_parts": self.gated_host_parts,

@@ -43,7 +43,7 @@ from __future__ import annotations
 import math
 import struct
 import threading
-from dataclasses import dataclass
+from dataclasses import dataclass, field as dc_field
 from functools import lru_cache
 
 import jax
@@ -76,9 +76,29 @@ class FusedField:
     ovf_np: np.ndarray             # host bool[RLp] (residue bookkeeping)
     has_ovf: bool
     nbytes: int
+    # the Pallas launcher's sweep tiles by their longest length: the
+    # distinct lengths and how many tiles have each
+    tile_max: tuple[np.ndarray, np.ndarray]
+    # pattern length (None: `A.*B`) -> (steps swept, steps skipped)
+    _steps: dict = dc_field(default_factory=dict, repr=False)
 
     def device_bytes(self) -> int:
         return self.nbytes
+
+    def sweep_steps(self, pat_len: int | None) -> tuple[int, int]:
+        """The plane steps a scan of pat_len bytes (None: an `A.*B`
+        pair) sweeps over this column's tiles, each to its longest row,
+        and the steps the column's whole width would have added
+        (kernels32.sweep_steps); computed once a pattern length."""
+        got = self._steps.get(pat_len)
+        if got is None:
+            lengths, tiles = self.tile_max
+            nl = self.width // 4
+            swept = int((K32.sweep_steps(lengths, nl, pat_len)
+                         * tiles).sum())
+            whole = K32.sweep_steps(None, nl, pat_len) * int(tiles.sum())
+            got = self._steps[pat_len] = (swept, whole - swept)
+        return got
 
 
 def stage_layout_column(part, field: str, layout: StatsLayout,
@@ -164,10 +184,13 @@ def stage_layout_column(part, field: str, layout: StatsLayout,
                 ovf[start:start + n] = True
     has_ovf = bool(ovf.any())
     ovp = put(np.packbits(ovf)) if has_ovf else None
+    _ts, sub = K32.sweep_blocks(w // 4, rlp // 128)
+    tile_max = np.unique(lens.reshape(-1, sub * 128).max(axis=1),
+                         return_counts=True)
     return FusedField(rows=put(to_lanes32(mat), row_axis=1),
                       lengths=put(lens), width=w,
                       ovf_packed=ovp, ovf_np=ovf, has_ovf=has_ovf,
-                      nbytes=rlp * (w + 5))
+                      nbytes=rlp * (w + 5), tile_max=tile_max)
 
 
 @dataclass
@@ -300,6 +323,7 @@ class _Planner:
         self._block = bytearray(struct.pack("<i", layout.nrows))
         self.field_slots: dict[str, int] = {}
         self.fields: list[FusedField] = []
+        self.planes: dict[int, FusedField] = {}   # planes arg -> column
         self._slot_args: list = []
         self.ts_slot: tuple | None = None
         self.has_maybe = False
@@ -349,6 +373,7 @@ class _Planner:
         if ff is None:
             raise _NoFuse(field)
         ri = self.arg(ff.rows, row=2)
+        self.planes[ri] = ff
         li = self.arg(ff.lengths, row=True)
         oi = self.arg(ff.ovf_packed, row=True) if ff.has_ovf else -1
         slot = len(self.fields)
@@ -790,6 +815,47 @@ def plane_scan_leaves(tree) -> int:
     _tree_leaves(tree, leaves)
     return sum(k == "regex" or k in _SCAN_MODE_NAMES.values()
                for k in leaves)
+
+
+def _swept_leaves(node, out: list) -> list:
+    """(planes arg, pattern length or None for `A.*B`) of each leaf
+    whose sweep stops at its tiles' longest row: the scans but the
+    window-0 modes, and the pairs."""
+    if node[0] == "scan":
+        if node[7] not in (K.MODE_EXACT, K.MODE_EXACT_PREFIX):
+            out.append((node[1], node[6]))
+    elif node[0] == "pair":
+        out.append((node[1], None))
+    elif node[0] == "not":
+        _swept_leaves(node[1], out)
+    elif node[0] in ("and", "or"):
+        for k in node[1]:
+            _swept_leaves(k, out)
+    return out
+
+
+@lru_cache(maxsize=1024)
+def swept_leaves(tree) -> tuple:
+    """_swept_leaves of a planned tree, once a tree."""
+    return tuple(_swept_leaves(tree, []))
+
+
+def _bump_plane_scans(runner, planner, tree) -> None:
+    """plane_scan_leaves, and the plane steps those leaves sweep and skip
+    (scan_plane_steps_swept / _skipped; FusedField.sweep_steps, a dict
+    lookup a leaf once a column has seen the pattern length).  Where the
+    direct launcher runs the body (off the TPU, under a mesh axis) every
+    step is swept."""
+    runner._bump("plane_scan_leaves", plane_scan_leaves(tree))
+    swept = skipped = 0
+    for ri, pat_len in swept_leaves(tree):
+        sw, sk = planner.planes[ri].sweep_steps(pat_len)
+        swept += sw
+        skipped += sk
+    if not runner.sweeps_bounded():
+        swept, skipped = swept + skipped, 0
+    runner._bump("scan_plane_steps_swept", swept)
+    runner._bump("scan_plane_steps_skipped", skipped)
 
 
 def stats_reduction(spec, n_values: int) -> str:
@@ -1375,7 +1441,7 @@ def fused_stats_submit(runner, f, part, bss, spec, asm):
                             stats_reduction(spec, len(values_tuple)))
         blk = planner.block()
     runner._bump("device_calls")
-    runner._bump("plane_scan_leaves", plane_scan_leaves(tree))
+    _bump_plane_scans(runner, planner, tree)
     runner._bump("stats_dispatches")
     runner._bump("fused_dispatches")
     runner._bump_max("stats_onehot_width",
@@ -1527,7 +1593,7 @@ def fused_topk_submit(runner, f, part, bss, spec):
         name = program_name("topk_seg" if nseg else "topk", tree)
         blk = planner.block()
     runner._bump("device_calls")
-    runner._bump("plane_scan_leaves", plane_scan_leaves(tree))
+    _bump_plane_scans(runner, planner, tree)
     runner._bump("topk_dispatches")
     runner._kind("topk_seg" if nseg else "topk")
     dm, mm = _launch(
@@ -1680,7 +1746,7 @@ def fused_filter_submit(runner, f, part, bss):
         name = program_name("filter", tree)
         blk = planner.block()
     runner._bump("device_calls")
-    runner._bump("plane_scan_leaves", plane_scan_leaves(tree))
+    _bump_plane_scans(runner, planner, tree)
     runner._bump("filter_dispatches")
     runner._kind("fused_filter")
     dm, mm = _launch(runner, runner._dispatch_filter, name, prog, blk,

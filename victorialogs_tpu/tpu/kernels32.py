@@ -33,19 +33,29 @@ running minimum and the last B as a running maximum plus the newline OR
 but the per-row result.  MODE_EXACT / MODE_EXACT_PREFIX look at window 0
 only.
 
+A sweep stops at its tile's longest row (sweep_steps).  Every byte of a
+row past its length is padding, which neither matches nor holds a
+newline, so a tile whose rows are all shorter than the staged width
+reads no plane that only padding fills: a window of a pat_len-byte scan
+at byte 4q+a needs 4q+a+pat_len <= the tile's longest length, and the
+pair's windows and newlines lie below it.  The Pallas launcher takes
+each tile's longest length from the lengths inside the program and hands
+the kernel one SMEM table block a grid step; the direct launcher sweeps
+the column's whole width (it is the oracle of the bounded sweep).
+
 Two launchers, one body (_launch).  On the TPU a Pallas call tiles the
 rows through VMEM blocks and runs the body a register tile at a time; on
 every other backend, and for a stripe under a mesh axis, the body runs
-directly as jax.numpy over the whole column.  The choice follows what the code can observe, never
-an environment switch.
+directly as jax.numpy over the whole column.  The choice follows what
+the code can observe, never an environment switch.
 
-Measured on a v5e, R = 2M, W = 128 (tools/bench_kernels32.py; my chip
-run, PR 27), ns a row and share of 819 GB/s: phrase (17 B, both token
-boundaries) 0.81 / 20%, prefix 0.44 / 37%, substring 0.38 / 43%, folded
-phrase 0.98 / 17%, `A.*B` pair 0.40 / 40%; the sublane formulation this
-replaced read 7.75, 2.37, 3.37, 8.14 and 9.47 ns a row.  Every kind is
-bound by its lane-ops, not by memory: a plain read of the column takes
-0.21 ns a row.
+Measured on a TPU v5e, R = 2M, W = 128, rows of at most 100 B
+(tools/bench_kernels32.py), ns a row and share of 819 GB/s: phrase
+(17 B, both token boundaries) 0.63 / 26%, prefix 0.37 / 44%, substring
+0.31 / 52%, folded phrase 0.76 / 21%, `A.*B` pair 0.33 / 49%; swept over
+the whole width the same kinds read 0.80, 0.44, 0.37, 0.97 and 0.40.
+Every kind is bound by its lane-ops, not by memory: a plain read of the
+column takes 0.21 ns a row.
 """
 
 from __future__ import annotations
@@ -172,9 +182,31 @@ def _window_eq(al, a: int, pc, c0: int, masks):
     return acc
 
 
-def _sweep(load, nl: int, look: int, steps: int, fold: bool, words: bool,
+def sweep_steps(tile_max, nl: int, pat_len: int | None = None):
+    """The steps of a sweep over a tile of rows whose longest row is
+    `tile_max` bytes; with tile_max None, the column's whole width.
+
+    A scan of pat_len bytes: a window at byte 4q+a can match only if
+    4q+a+pat_len <= tile_max, so q runs to (tile_max - pat_len) // 4, and
+    never past nl - ceil(pat_len/4), the last plane a window starts in
+    and lies inside the column.  The `A.*B` pair (pat_len None): no byte
+    of a row, so no window start and no newline, lies at or past
+    tile_max, so q runs to ceil(tile_max / 4) - 1, and never past nl - 1.
+    tile_max is the kernel's traced int32 or the host's numpy array (its
+    count of the steps swept and skipped): one arithmetic for both."""
+    full = nl if pat_len is None else nl - (pat_len + 3) // 4 + 1
+    if tile_max is None:
+        return full
+    xp = jnp if isinstance(tile_max, jax.Array) else np
+    if pat_len is None:
+        return xp.minimum((tile_max + 3) >> 2, full)
+    return xp.minimum(xp.maximum(tile_max - pat_len + 4, 0) >> 2, full)
+
+
+def _sweep(load, nl: int, look: int, steps, fold: bool, words: bool,
            visit, state):
-    """state = visit(q, al, wm, state) for q = 0 .. steps-1, in order.
+    """state = visit(q, al, wm, state) for q = 0 .. steps-1, in order;
+    steps a static int or a traced int32 (sweep_steps).
 
     al[c] = _aligned4 of plane q+c for c < look (the planes a window of
     `look` chunks spans); wm[i] = the word mask of plane q-1+i for
@@ -215,9 +247,10 @@ def _sweep(load, nl: int, look: int, steps: int, fold: bool, words: bool,
 # ---------------- the two bodies: one tile of rows ----------------
 
 def _scan_rows(load, nl: int, lens, pc, masks, pat_len: int, mode: int,
-               need_start: bool, need_end: bool, fold: bool):
+               need_start: bool, need_end: bool, fold: bool, tile_max):
     """match_scan_t over one tile of rows: load(q) is word q of each,
-    lens their lengths, pc the pattern's chunk words (indexable)."""
+    lens their lengths, pc the pattern's chunk words (indexable),
+    tile_max the longest of lens (None: sweep the whole width)."""
     nc = len(masks)
     if mode in (MODE_EXACT, MODE_EXACT_PREFIX):
         # window 0 only: the first chunks of each row
@@ -251,19 +284,17 @@ def _scan_rows(load, nl: int, lens, pc, masks, pat_len: int, mode: int,
         # a word a row: Mosaic loops carry no booleans
         return hit | any_a.astype(jnp.int32)
 
-    # the last plane a window can start in, and still lie inside the
-    # column, is nl - nc: past it every window runs into the padding
-    hit = _sweep(load, nl, nc, nl - nc + 1, fold,
+    hit = _sweep(load, nl, nc, sweep_steps(tile_max, nl, pat_len), fold,
                  need_start or need_end, visit, lens & 0)
     return (hit != 0) & (lens >= pat_len)
 
 
 def _pair_rows(load, nl: int, lens, pc, masks_a, masks_b, len_a: int,
-               len_b: int):
+               len_b: int, tile_max):
     """match_ordered_pair_t over one tile of rows: the first window
     that equals A as a running minimum, the last that equals B as a
     running maximum (byte offsets), the newline bytes OR-ed.  pc holds
-    A's chunk words, then B's."""
+    A's chunk words, then B's; tile_max as for _scan_rows."""
     nowhere = np.int32(4 * nl)         # past every window start
     nca, ncb = len(masks_a), len(masks_b)
 
@@ -279,11 +310,13 @@ def _pair_rows(load, nl: int, lens, pc, masks_a, masks_b, len_a: int,
                                last_b)
         return first_a, last_b, newline
 
-    # sweeps every plane (a newline may sit in the last); windows that
-    # run past the column compare against the padding
+    # sweeps every plane a row's byte may sit in (a newline may sit in
+    # the last); windows that run past the column compare against the
+    # padding
     zero = lens & 0
     first_a, last_b, newline = _sweep(
-        load, nl, max(nca, ncb), nl, False, False, visit,
+        load, nl, max(nca, ncb), sweep_steps(tile_max, nl), False, False,
+        visit,
         (zero + nowhere, zero - 1, zero.astype(_U32)))
     ordered = (first_a < nowhere) & (last_b >= 0) \
         & (lens >= max(len_a, len_b)) & (first_a + len_a <= last_b)
@@ -300,24 +333,41 @@ _SWEEP_ROWS = 2 * _SUBLANES
 _BLOCK_BYTES = 2 << 20      # of a column, in VMEM at a time
 
 
+def sweep_blocks(nl: int, s: int) -> tuple[int, int]:
+    """(ts, sub): the Pallas launcher's block of ts rows of 128 (a grid
+    step), swept sub rows of 128 at a time (a sweep tile), for a column
+    of nl planes and s rows of 128."""
+    ts = next(t for t in (256, 128, 64, 32, 16, _SUBLANES)
+              if s % t == 0 and (t == _SUBLANES
+                                 or nl * t * 128 * 4 <= _BLOCK_BYTES))
+    return ts, min(ts, _SWEEP_ROWS)
+
+
+def on_tpu() -> bool:
+    """Whether this process's columns run the Pallas launcher (and so
+    the bounded sweep) when not striped under a mesh axis."""
+    return jax.default_backend() == "tpu"
+
+
 def _on_tpu_alone(lanes_t) -> bool:
     """Whether the Pallas launcher serves this column: the TPU backend,
     whole register tiles of rows, and not a stripe under a mesh axis
     (there XLA's own partitioning runs the body)."""
-    return (jax.default_backend() == "tpu"
+    return (on_tpu()
             and not jax.typeof(lanes_t).vma
             and lanes_t.shape[1] % _SUBLANES == 0)
 
 
 def _launch(body, nout: int, lanes_t, lengths, pc):
-    """body(load, lens, pc) -> nout bool arrays over a tile of rows;
-    returns them over every row, bool[R] each.  On the TPU the tiles
-    are register-sized slices of VMEM blocks (Pallas); elsewhere the
-    tile is the column."""
+    """body(load, lens, pc, tile_max) -> nout bool arrays over a tile of
+    rows; returns them over every row, bool[R] each.  On the TPU the
+    tiles are register-sized slices of VMEM blocks (Pallas), each swept
+    to its longest row; elsewhere the tile is the column, swept over its
+    whole width (tile_max None)."""
     lens = lengths.reshape(lanes_t.shape[1:])
     if not _on_tpu_alone(lanes_t):
         out = body(lambda q: jax.lax.dynamic_index_in_dim(
-            lanes_t, q, 0, keepdims=False), lens, pc)
+            lanes_t, q, 0, keepdims=False), lens, pc, None)
     else:
         code = _launch_pallas(body, lanes_t, lens, pc)
         out = [(code >> i) & 1 != 0 for i in range(nout)]
@@ -327,20 +377,21 @@ def _launch(body, nout: int, lanes_t, lengths, pc):
 def _launch_pallas(body, lanes_t, lens, pc, interpret: bool = False):
     """The body over (W/4, TS, 128) blocks of rows, _SWEEP_ROWS x 128
     rows at a time; int32[R/128, 128], bit i = the body's i-th
-    result."""
+    result.  Each sweep tile's longest length is reduced here from
+    lens, in the program, and reaches the kernel as an int32 SMEM table
+    blocked by grid step: ts // sub words a step, whatever R is."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     nl, s, lanes = lanes_t.shape
-    ts = next(t for t in (256, 128, 64, 32, 16, _SUBLANES)
-              if s % t == 0 and (t == _SUBLANES
-                                 or nl * t * lanes * 4 <= _BLOCK_BYTES))
-    sub = min(ts, _SWEEP_ROWS)
+    ts, sub = sweep_blocks(nl, s)
+    tile_max = lens.reshape(s // sub, sub * lanes).max(axis=1) \
+        .reshape(s // ts, 1, ts // sub)
 
-    def kernel(pc_ref, lanes_ref, lens_ref, out_ref):
+    def kernel(pc_ref, lanes_ref, lens_ref, tmax_ref, out_ref):
         def tile(t, carry):
             rows = pl.ds(pl.multiple_of(t * sub, sub), sub)
             out = body(lambda q: lanes_ref[q, rows, :],
-                       lens_ref[rows, :], pc_ref)
+                       lens_ref[rows, :], pc_ref, tmax_ref[0, t])
             out_ref[rows, :] = sum(o.astype(jnp.int32) << i
                                    for i, o in enumerate(out))
             return carry
@@ -353,11 +404,14 @@ def _launch_pallas(body, lanes_t, lens, pc, interpret: bool = False):
             num_scalar_prefetch=1, grid=(s // ts,),
             in_specs=[pl.BlockSpec((nl, ts, lanes),
                                    lambda i, pc: (0, i, 0)),
-                      pl.BlockSpec((ts, lanes), lambda i, pc: (i, 0))],
+                      pl.BlockSpec((ts, lanes), lambda i, pc: (i, 0)),
+                      pl.BlockSpec((None, 1, ts // sub),
+                                   lambda i, pc: (i, 0, 0),
+                                   memory_space=pltpu.SMEM)],
             out_specs=pl.BlockSpec((ts, lanes), lambda i, pc: (i, 0))),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-        interpret=interpret)(pc, lanes_t, lens)
+        interpret=interpret)(pc, lanes_t, lens, tile_max)
 
 
 # ---------------- the scan ----------------
@@ -385,9 +439,9 @@ def match_scan_t(lanes_t: jnp.ndarray, lengths: jnp.ndarray,
     need_start = starts_tok and mode in (MODE_PHRASE, MODE_PREFIX)
     need_end = ends_tok and mode == MODE_PHRASE
 
-    def body(load, lens, pc):
+    def body(load, lens, pc, tile_max):
         return [_scan_rows(load, nl, lens, pc, masks, pat_len, mode,
-                           need_start, need_end, fold)]
+                           need_start, need_end, fold, tile_max)]
 
     return _launch(body, 1, lanes_t, lengths, pc)[0]
 
@@ -411,9 +465,9 @@ def match_ordered_pair_t(lanes_t: jnp.ndarray, lengths: jnp.ndarray,
     pc_a, masks_a = _pattern_chunks(pat_a, len_a)
     pc_b, masks_b = _pattern_chunks(pat_b, len_b)
 
-    def body(load, lens, pc):
+    def body(load, lens, pc, tile_max):
         return _pair_rows(load, nl, lens, pc, masks_a, masks_b, len_a,
-                          len_b)
+                          len_b, tile_max)
 
     return tuple(_launch(body, 2, lanes_t, lengths,
                          jnp.concatenate([pc_a, pc_b])))
