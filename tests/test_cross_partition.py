@@ -230,6 +230,11 @@ def test_deadline_mid_stream_no_partial_writes(storage, monkeypatch):
     from victorialogs_tpu.engine.searcher import QueryTimeoutError
     monkeypatch.setenv("VL_INFLIGHT", "4")
     monkeypatch.setenv("VL_PACK_PARTS", "1")
+    # compile the query's program first (another runner): in a process
+    # that has not compiled it, the first submit alone outlasts the
+    # deadline and no second dispatch is ever in flight
+    run_query_collect(storage, [TEN], "* | stats count() c",
+                      timestamp=T0, runner=BatchRunner())
     runner = BatchRunner()
     orig = BatchRunner.run_part_stats_submit
     calls = {"n": 0}

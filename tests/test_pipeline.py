@@ -221,6 +221,11 @@ def test_deadline_expiry_drains_window(storage, monkeypatch):
     and the runner survives."""
     monkeypatch.setenv("VL_INFLIGHT", "4")
     monkeypatch.setenv("VL_PACK_PARTS", "1")
+    # compile the query's program first (another runner): in a process
+    # that has not compiled it, the first submit alone outlasts the
+    # deadline and no second dispatch is ever in flight
+    run_query_collect(storage, [TEN], "* | stats count() c",
+                      timestamp=T0, runner=BatchRunner())
     runner = BatchRunner()
     orig = BatchRunner.run_part_stats_submit
     calls = {"n": 0}
@@ -282,3 +287,156 @@ def test_pipeline_mesh_runner(storage, monkeypatch):
         assert _norm(cpu) == _norm(dev), qs
     assert runner.packed_dispatches > 0
     assert runner.inflight_hwm >= 1
+
+
+# ---- prefetch only what is not resident (BatchRunner.submit_prefetch) ----
+
+# the adhoc_scan shapes (phrase count over a time window, its chart, an
+# `A.*B` regex, count_uniq over every row) and a sort-topk
+PREFETCH_SHAPES = {
+    "count": '_time:[2025-07-28T00:00:10Z, 2025-07-28T00:06:30Z) "GET" '
+             '| stats count() c',
+    "chart": '_time:[2025-07-28T00:00:10Z, 2025-07-28T00:06:30Z) "GET" '
+             '| stats by (_time:5m) count() c',
+    "pair": '_msg:~"GET.*tail" | stats count() c',
+    "count_uniq": '* | stats count() c, count_uniq(_stream_id) u',
+    "sort": 'error | sort by (dur desc) limit 7 | fields dur, app',
+}
+PREFETCH_QUERIES = list(PREFETCH_SHAPES.values()) + [
+    '* | stats by (app) count() c, sum(dur) s',
+    '"GET" ok | fields _time',
+]
+
+
+def _drain_prefetch(runner) -> None:
+    """Wait for every queued prefetch: the pool has one worker, FIFO."""
+    runner._prefetcher().submit(lambda: None).result()
+
+
+def _spy_prefetch(runner, monkeypatch) -> tuple[list, list]:
+    """(parts handed to submit_prefetch, parts _prefetch_work ran for)."""
+    submitted, worked = [], []
+    submit, work = runner.submit_prefetch, runner._prefetch_work
+
+    def spy_submit(part, *a, **kw):
+        submitted.append(part.uid)
+        return submit(part, *a, **kw)
+
+    def spy_work(part, *a, **kw):
+        worked.append(part.uid)
+        return work(part, *a, **kw)
+
+    monkeypatch.setattr(runner, "submit_prefetch", spy_submit)
+    monkeypatch.setattr(runner, "_prefetch_work", spy_work)
+    return submitted, worked
+
+
+def _round(storage, runner, queries) -> None:
+    for qs in queries:
+        cpu = run_query_collect(storage, [TEN], qs, timestamp=T0)
+        dev = run_query_collect(storage, [TEN], qs, timestamp=T0,
+                                runner=runner)
+        assert _norm(cpu) == _norm(dev), qs
+    _drain_prefetch(runner)
+
+
+def test_cold_runner_prefetches(storage, monkeypatch):
+    """A fresh runner queues every lookahead unit's prefetch and stages
+    through it: nothing is resident, so the counter stays 0."""
+    monkeypatch.setenv("VL_INFLIGHT", "4")
+    monkeypatch.setenv("VL_PACK_PARTS", "1")
+    runner = BatchRunner()
+    submitted, worked = _spy_prefetch(runner, monkeypatch)
+    _round(storage, runner, PREFETCH_QUERIES[:1])
+    assert submitted and sorted(worked) == sorted(submitted)
+    assert runner.prefetch_resident_units == 0
+    assert runner.stats()["prefetch_resident_units"] == 0
+    assert runner.cache.stats()["entries"] > 0
+
+
+@pytest.mark.parametrize("pack", ["1", "8"])
+def test_warm_runner_queues_no_prefetch(storage, monkeypatch, pack):
+    """A second round of the same shapes on a warm runner finds every
+    key a unit's prefetch could stage in the cache: _prefetch_work never
+    runs, each skipped unit bumps prefetch_resident_units, and the
+    answers stay the host executor's."""
+    monkeypatch.setenv("VL_INFLIGHT", "4")
+    monkeypatch.setenv("VL_PACK_PARTS", pack)
+    runner = BatchRunner()
+    _round(storage, runner, PREFETCH_QUERIES)
+    submitted, worked = _spy_prefetch(runner, monkeypatch)
+    resident0 = runner.prefetch_resident_units
+    _round(storage, runner, PREFETCH_QUERIES)
+    assert submitted
+    assert worked == []
+    assert runner.prefetch_resident_units - resident0 == len(submitted)
+
+
+def test_dropped_key_prefetches_again(storage, monkeypatch):
+    """One `#fl` entry gone from a warm cache (evicted): that part's
+    unit, and only it, queues its prefetch again, which stages the key
+    back."""
+    monkeypatch.setenv("VL_INFLIGHT", "4")
+    monkeypatch.setenv("VL_PACK_PARTS", "1")
+    runner = BatchRunner()
+    qs = PREFETCH_SHAPES["pair"]
+    _round(storage, runner, [qs])
+    submitted, worked = _spy_prefetch(runner, monkeypatch)
+    _round(storage, runner, [qs])
+    assert submitted and worked == []
+    uid = submitted[-1]
+    key = (uid, "#fl", "_msg")
+    cache = runner.cache
+    with cache._mu:                       # an eviction, as the LRU does it
+        cache._bytes -= cache._cost(cache._lru.pop(key))
+    resident0 = runner.prefetch_resident_units
+    submitted.clear()
+    _round(storage, runner, [qs])
+    assert worked == [uid]
+    assert runner.prefetch_resident_units - resident0 == \
+        len(submitted) - 1
+    assert cache.contains(key)
+
+
+# the staging-key kinds each shape's unit needs: layout, timestamp
+# planes, scan column, time buckets, sort score column
+PREFETCH_KINDS = {
+    "count": {"#layout", "#ts2", "#fl"},
+    "chart": {"#layout", "#ts2", "#fl", "#tb"},
+    "pair": {"#layout", "#fl"},
+    "count_uniq": {"#layout"},
+    "sort": {"#layout", "#num", "#fl"},
+}
+
+
+@pytest.mark.parametrize("shape", list(PREFETCH_SHAPES))
+def test_prefetch_keys_are_what_prefetch_stages(storage, monkeypatch,
+                                                shape):
+    """The key list the residency check reads is exactly the set of keys
+    _prefetch_work puts into the cache of a cold part, of the kinds the
+    shape needs, and each one is a key the device dispatch reads."""
+    from victorialogs_tpu.logsql.parser import parse_query
+    from victorialogs_tpu.tpu.sort_device import device_sort_spec
+    from victorialogs_tpu.tpu.stats_device import device_stats_spec
+
+    q = parse_query(PREFETCH_SHAPES[shape], timestamp=T0)
+    spec = device_stats_spec(q)
+    sort = device_sort_spec(q)
+    sort_field = sort.field if sort is not None else None
+    assert (spec is None) == (shape == "sort")
+    part = next(p for pt in storage.partitions.values()
+                for p in pt.ddb.snapshot_parts() if p.num_rows)
+    runner = BatchRunner()
+    runner._prefetch_work(part, q.filter, spec, None, sort_field)
+    keys = [k for k, _plan in
+            runner._prefetch_keys(part, q.filter, spec, sort_field)]
+    assert set(runner.cache._lru) == set(keys)
+    assert {k[1] for k in keys} == PREFETCH_KINDS[shape]
+
+    # a runner that never prefetches: what the dispatches stage alone
+    monkeypatch.setenv("VL_PACK_PARTS", "1")
+    cold = BatchRunner()
+    monkeypatch.setattr(cold, "submit_prefetch", lambda *a, **kw: None)
+    run_query_collect(storage, [TEN], PREFETCH_SHAPES[shape],
+                      timestamp=T0, runner=cold)
+    assert set(keys) <= set(cold.cache._lru)

@@ -911,6 +911,9 @@ class BatchRunner:
         self.result_cache_units = 0    # units satisfied from the
         #                                per-part result cache (no
         #                                dispatch, no slot lease)
+        self.prefetch_resident_units = 0  # units whose prefetch queued
+        #                                nothing: every key it could
+        #                                stage was in the staging cache
         # widest bucket one-hot any stats dispatch paid (the seg-major
         # kernel keeps this at the BASE bucket product — it must not
         # scale with VL_PACK_PARTS; bench-asserted)
@@ -1001,6 +1004,7 @@ class BatchRunner:
                 "packed_topk_dispatches": self.packed_topk_dispatches,
                 "cross_partition_packs": self.cross_partition_packs,
                 "result_cache_units": self.result_cache_units,
+                "prefetch_resident_units": self.prefetch_resident_units,
                 "stats_onehot_width": self.stats_onehot_width,
                 "inflight_hwm": self.inflight_hwm,
                 "host_sync_wait_s": self.host_sync_wait_s,
@@ -1085,7 +1089,15 @@ class BatchRunner:
         and timestamp planes, for packed super-parts too.
         sort_field: the sort-topk by-column — its uint32 value staging
         (the fused topk dispatch's score operand) uploads ahead like
-        the stats value columns."""
+        the stats value columns.
+
+        A unit whose every key is already in the staging cache queues
+        nothing (`prefetch_resident_units`): the worker would only look
+        the keys up again, in Python, while this thread launches."""
+        if all(self.cache.contains(key) for key, _plan in
+               self._prefetch_keys(part, f, stats_spec, sort_field)):
+            self._bump("prefetch_resident_units")
+            return
         from ..obs import activity, tracing
         # staging runs on the vl-prefetch worker: re-enter the caller's
         # span AND activity record there so staged_entries/staged_bytes
@@ -1109,6 +1121,29 @@ class BatchRunner:
         except RuntimeError:
             pass  # pool closed between return and submit; best-effort
 
+    def _prefetch_keys(self, part, f, stats_spec, sort_field) -> list:
+        """The staging keys a unit's prefetch may stage, in the order it
+        stages them, as (key, plan) pairs: `_prefetch_work` stages the
+        missing ones, `submit_prefetch` skips a unit that has them all.
+        A `#fl` key carries its leaf plan (the bloom probe and the
+        narrowness gate decide whether it stages), every other None.
+        The layout comes first: the rest is staged against it."""
+        uid = part.uid
+        keys = [((uid, "#layout"), None)]
+        if _tree_has_time(f):
+            keys.append(((uid, "#ts2"), None))
+        if sort_field is not None:
+            keys.append(((uid, "#num", sort_field), None))
+        keys += [((uid, "#fl", plan.field), plan)
+                 for plan in device_plans(f)]
+        if stats_spec is not None:
+            keys += [((uid, "#num", fld), None)
+                     for fld in stats_spec.value_fields]
+            keys += [((uid, "#tb", bk.step, bk.offset) if bk.kind == "time"
+                      else (uid, "#dict", bk.name), None)
+                     for bk in stats_spec.by]
+        return keys
+
     def _prefetch_work(self, part, f, stats_spec, cand_bis,
                        sort_field) -> None:
         bis = list(cand_bis) if cand_bis is not None else \
@@ -1124,43 +1159,41 @@ class BatchRunner:
         layout = self._stats_layout(part)
         if layout.nrows > MAX_STAT_ROWS:
             return     # every fused family declines: the host evaluates
-        if _tree_has_time(f):
-            self._stage_ts_planes(part, layout)
-        if sort_field is not None:
-            # the topk score operand (fused_topk_submit's staging key);
-            # where the column is not numeric here the topk program
-            # declines and the filter program reads the same columns
-            self._stage_numeric(part, sort_field, layout,
-                                MAX_ABS_TIMES_ROWS)
-        for plan in device_plans(f):
-            surv = bis
-            if plan.bloom_tokens:
-                hashes = cached_token_hashes(plan.filter,
-                                             plan.bloom_tokens)
-                # observe=False: the evaluator/planner re-probes this
-                # exact (part, field, bis) at dispatch — counting the
-                # prefetch warm-up too would double every histogram
-                # sample and trace counter
-                keep = bloom_keep_mask(part, plan.field, hashes,
-                                       bis, observe=False)
-                surv = [bi for bi, k in zip(bis, keep) if k]
-            if not surv:
-                continue
-            cand_rows = sum(part.block_rows(bi) for bi in surv)
-            # mirrors _scan_leaf's narrowness gate
-            if self.cache.contains((part.uid, "#fl", plan.field)) or \
-                    cand_rows * 8 >= part.num_rows:
-                self._stage_fused_field(part, plan.field, layout)
-        if stats_spec is not None:
-            for fld in stats_spec.value_fields:
-                self._stage_numeric(part, fld, layout,
+        for key, plan in self._prefetch_keys(part, f, stats_spec,
+                                             sort_field)[1:]:
+            kind = key[1]
+            if kind == "#ts2":
+                self._stage_ts_planes(part, layout)
+            elif kind == "#num":
+                # a stats value column or the topk score operand
+                # (fused_topk_submit's staging key); where the column is
+                # not numeric here the topk program declines and the
+                # filter program reads the same columns
+                self._stage_numeric(part, key[2], layout,
                                     MAX_ABS_TIMES_ROWS)
-            for bk in stats_spec.by:
-                if bk.kind == "time":
-                    self._stage_buckets(part, layout, bk.step,
-                                        bk.offset, MAX_BUCKETS)
-                else:
-                    self._stage_dict(part, bk.name, layout)
+            elif kind == "#fl":
+                surv = bis
+                if plan.bloom_tokens:
+                    hashes = cached_token_hashes(plan.filter,
+                                                 plan.bloom_tokens)
+                    # observe=False: the evaluator/planner re-probes
+                    # this exact (part, field, bis) at dispatch —
+                    # counting the prefetch warm-up too would double
+                    # every histogram sample and trace counter
+                    keep = bloom_keep_mask(part, plan.field, hashes,
+                                           bis, observe=False)
+                    surv = [bi for bi, k in zip(bis, keep) if k]
+                if not surv:
+                    continue
+                rows = sum(part.block_rows(bi) for bi in surv)
+                # mirrors _scan_leaf's narrowness gate
+                if self.cache.contains(key) or rows * 8 >= part.num_rows:
+                    self._stage_fused_field(part, plan.field, layout)
+            elif kind == "#tb":
+                self._stage_buckets(part, layout, key[2], key[3],
+                                    MAX_BUCKETS)
+            else:
+                self._stage_dict(part, key[2], layout)
 
     # ---- device placement hook (MeshBatchRunner shards the row axis) ----
     def _put(self, arr, row_axis: int = 0):
