@@ -22,7 +22,7 @@ BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
 _PATHS = [BENCH, os.path.join(BENCH, "tests")]
 _TEST_MODULES = ("test_yardstick", "test_trace_readers", "test_schema_seam",
-                 "test_new_schema_as_files")
+                 "test_new_schema_as_files", "test_cpu_readers")
 
 
 def _harness_modules() -> dict:

@@ -628,6 +628,15 @@ declare_metric("vl_gc_collections_total", "counter",
                "generation-2 garbage collections")
 declare_metric("vl_gc_pause_seconds_total", "counter",
                "seconds spent in generation-2 garbage collections")
+declare_metric("vl_gc_young_collections_total", "counter",
+               "generation-0 and generation-1 garbage collections")
+declare_metric("vl_gc_young_pause_seconds_total", "counter",
+               "seconds spent in generation-0 and generation-1 garbage "
+               "collections")
+declare_metric("vl_background_thread_cpu_seconds_total", "counter",
+               "CPU seconds of the threads that serve no request, by "
+               "thread name with its number dropped, read at scrape "
+               "(obs/stallwatch.py)")
 declare_metric("vl_top_queries_evicted_total", "counter",
                "completed-query ring evictions", single_roll=True)
 declare_metric("vl_journal_dropped_total", "counter",

@@ -25,11 +25,12 @@ root is `request`, with the wall clock beside its start
 `parse` and the handler's `query` span (`tracing.make_child`,
 server/vlselect.py) hang beneath it, down to each dispatch's `submit` ->
 `args` + `launch` (tpu/fused.py), so one tree on one clock runs from the
-socket to the device and lays over a device profile.
+socket to the device and lays over a device profile.  Every real span
+carries `gc_ms`, the collector's pauses inside it.
 
 What no request can ask for is always on: the stall watch
-(stallwatch.py) is one heartbeat thread and a generation-2 `gc` hook,
-started with the server, that write ONE line per stall through the slow
-log's sink and the event bus (`vl_process_stalls_total`,
+(stallwatch.py) is one heartbeat thread and a `gc` hook that times every
+generation, started with the server, that write ONE line per stall
+through the slow log's sink and the event bus (`vl_process_stalls_total`,
 `vl_gc_pause_seconds_total`); it does no per-request work.
 """

@@ -5,9 +5,12 @@ something was already watching: spans exist only under ``?trace=1``.
 This module is that something, started with the server and costing the
 request path nothing (no per-request work, no new lock on it):
 
-- a ``gc.callbacks`` hook that times generation-2 collections only
-  (younger generations return at once): ``vl_gc_pause_seconds_total``,
-  ``vl_gc_collections_total``;
+- a ``gc.callbacks`` hook that times every collection: generation 2 in
+  ``vl_gc_pause_seconds_total`` / ``vl_gc_collections_total``,
+  generations 0-1 in ``vl_gc_young_pause_seconds_total`` /
+  ``vl_gc_young_collections_total``, and all of them into the tracing
+  core's process-wide pause total, which a real span reads at open and
+  at close (its ``gc_ms``, obs/tracing.py);
 - ONE heartbeat thread at 10 Hz that measures how late it wakes (the
   interpreter lock or the whole process was held) and how old the
   oldest live query is (obs/activity.py).  A beat later than LATE_S, or
@@ -23,6 +26,14 @@ request path nothing (no per-request work, no new lock on it):
   age.  A no-progress stall writes its line when it begins and counts
   its seconds while it lasts.
 
+``vl_background_thread_cpu_seconds_total{thread=...}`` is the CPU that
+the threads the program starts beside its requests have used (flush,
+merge, journal, prefetch, this heartbeat, the accept loop), read from
+each thread's CPU clock when /metrics is scraped: never on a request's
+path.  Left out are the request handler threads and the main thread,
+which a server parks in ``signal.pause()`` and an embedding program
+(the benchmark's child) uses for its own control work.
+
 Thresholds are fixed: there is no knob.  ``start()``/``close()`` are
 reference-counted, so several servers of one process share one thread
 and one hook.
@@ -32,12 +43,13 @@ from __future__ import annotations
 
 import gc
 import json
+import re
 import sys
 import threading
 import time
 
 from .. import sched
-from . import activity, events, slowlog
+from . import activity, events, slowlog, tracing
 
 BEAT_S = 0.1                 # 10 Hz
 LATE_S = 0.25                # a beat this late is a stall
@@ -50,22 +62,38 @@ _LIFETIME_ENDPOINTS = frozenset(("/select/logsql/tail",
 _mu = threading.Lock()       # start/close and the counters; never taken
 #                              on a request's path
 _counts = {"stalls": 0, "stall_seconds": 0.0}
-_gc = {"collections": 0, "pause_seconds": 0.0, "t0": None}
+_gc = {"collections": 0, "pause_seconds": 0.0,
+       "young_collections": 0, "young_pause_seconds": 0.0, "t0": None}
 _refs = 0
 _watch = None
 
+# the ThreadingHTTPServer's handler threads ("Thread-7
+# (process_request_thread)") serve requests: their CPU is the requests'
+# (the main thread is left out too: the module docstring)
+_REQUEST_THREAD = "(process_request_thread)"
+# a thread's last CPU reading and label, by thread; the sums of those
+# that ended, by label (so the counter never falls)
+_cpu_seen: dict = {}
+_cpu_ended: dict = {}
+
 
 def _on_gc(phase: str, info: dict) -> None:
-    if info["generation"] != 2:
-        return
     # collections never nest and run with the interpreter lock held: the
     # start/stop pair needs no lock of its own
     if phase == "start":
-        _gc["t0"] = time.perf_counter()
-    elif _gc["t0"] is not None:
-        _gc["pause_seconds"] += time.perf_counter() - _gc["t0"]
+        _gc["t0"] = time.perf_counter_ns()
+        return
+    if _gc["t0"] is None:
+        return
+    ns = time.perf_counter_ns() - _gc["t0"]
+    _gc["t0"] = None
+    tracing.add_gc_pause(ns)
+    if info["generation"] == 2:
+        _gc["pause_seconds"] += ns / 1e9
         _gc["collections"] += 1
-        _gc["t0"] = None
+    else:
+        _gc["young_pause_seconds"] += ns / 1e9
+        _gc["young_collections"] += 1
 
 
 def _compiles() -> int:
@@ -173,6 +201,7 @@ def start() -> None:
         _refs += 1
         if _refs == 1:
             gc.callbacks.append(_on_gc)
+            tracing.set_gc_hooked(True)
             _watch = StallWatch()
             _watch.start()
 
@@ -185,14 +214,53 @@ def close() -> None:
         if _refs == 0:
             watch, _watch = _watch, None
             gc.callbacks.remove(_on_gc)
+            tracing.set_gc_hooked(False)
     if watch is not None:
         watch.close()
+
+
+def thread_label(name: str) -> str:
+    """A thread's name with its number dropped: "vl-prefetch_0" ->
+    "vl-prefetch", "Thread-3 (serve_forever)" -> "Thread (serve_forever)"."""
+    return re.sub(r"(?:[-_]\d+)+(?=\W|$)", "", name)
+
+
+def _background_cpu() -> dict:
+    """{label: CPU seconds} of every thread that serves no request, the
+    ended ones at their last reading.  Called under _mu."""
+    live = {}
+    main = threading.main_thread()
+    for t in threading.enumerate():
+        if t is main or t.ident is None or \
+                t.name.endswith(_REQUEST_THREAD):
+            continue
+        try:
+            secs = time.clock_gettime(time.pthread_getcpuclockid(t.ident))
+        except OSError:         # it ended since enumerate()
+            continue
+        live[t] = (thread_label(t.name), secs)
+    for t, (label, secs) in _cpu_seen.items():
+        if t not in live:
+            _cpu_ended[label] = _cpu_ended.get(label, 0.0) + secs
+    _cpu_seen.clear()
+    _cpu_seen.update(live)
+    out = dict(_cpu_ended)
+    for label, secs in live.values():
+        out[label] = out.get(label, 0.0) + secs
+    return out
 
 
 def metrics_samples() -> list[tuple[str, dict, float]]:
     with _mu:
         stalls, stall_s = _counts["stalls"], _counts["stall_seconds"]
+        cpu = _background_cpu()
     return [("vl_process_stalls_total", {}, stalls),
             ("vl_process_stall_seconds_total", {}, stall_s),
             ("vl_gc_collections_total", {}, _gc["collections"]),
-            ("vl_gc_pause_seconds_total", {}, _gc["pause_seconds"])]
+            ("vl_gc_pause_seconds_total", {}, _gc["pause_seconds"]),
+            ("vl_gc_young_collections_total", {},
+             _gc["young_collections"]),
+            ("vl_gc_young_pause_seconds_total", {},
+             _gc["young_pause_seconds"])] + [
+        ("vl_background_thread_cpu_seconds_total", {"thread": label}, secs)
+        for label, secs in sorted(cpu.items())]

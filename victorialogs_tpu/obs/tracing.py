@@ -20,6 +20,14 @@ gate) and the handler hangs its `query` span under it with
 perf_counter start: any span's absolute time is `start_unix_ns +
 start_ms`, the clock a device profile's gaps are converted to.
 
+Every real span also carries `gc_ms`: the collector's pauses, any
+generation, run by any thread, inside the span.  It is the difference of
+one process-wide total of pause nanoseconds (`gc_pause_ns`) read before
+the span's start and after its end, so the span's own reads fall outside
+its duration.  The stall watch's gc hook (obs/stallwatch.py) pushes each
+pause into that total; while no hook is installed (no server started)
+the total reads None and spans carry no `gc_ms`.
+
 Direct ``Span(...)`` construction and un-with'd ``.span(...)`` calls are
 forbidden outside this module by the vlint `span-discipline` checker:
 the with-block is what guarantees every span closes on every exit path
@@ -69,22 +77,45 @@ _attrs_mu = threading.Lock()
 # counter is the documented tradeoff (the tree is already ~MBs there).
 MAX_CHILDREN = 4096
 
+# the collector's pause total (module docstring): written only by the
+# stall watch's gc hook, which runs with the interpreter lock held
+_gc = {"pause_ns": 0, "hooked": False}
+
 
 def spans_created() -> int:
     return _created
+
+
+def add_gc_pause(ns: int) -> None:
+    """The gc hook's report of one collection's pause."""
+    _gc["pause_ns"] += ns
+
+
+def set_gc_hooked(hooked: bool) -> None:
+    """Whether a gc hook feeds the total (the stall watch says so as it
+    installs and removes its hook)."""
+    _gc["hooked"] = hooked
+
+
+def gc_pause_ns() -> int | None:
+    """Nanoseconds every generation's collections have paused the
+    process so far; None while no hook is installed."""
+    return _gc["pause_ns"] if _gc["hooked"] else None
 
 
 class Span:
     """One node of a trace tree.  Construct only via make_root() /
     parent.span() — see the module docstring (vlint: span-discipline)."""
 
-    __slots__ = ("name", "t0", "t1", "attrs", "children")
+    __slots__ = ("name", "t0", "t1", "attrs", "children", "gc0", "gc1")
 
     enabled = True
 
     def __init__(self, name: str, attrs: dict):
         global _created
         self.name = name
+        self.gc0 = gc_pause_ns()
+        self.gc1 = None
         self.t0 = time.perf_counter()
         self.t1 = None
         self.attrs = attrs
@@ -123,6 +154,7 @@ class Span:
     def close(self) -> None:
         if self.t1 is None:
             self.t1 = time.perf_counter()
+            self.gc1 = gc_pause_ns()
 
     def open_spans(self) -> int:
         """Descendants (incl. self) not yet closed — 0 after any query
@@ -139,14 +171,22 @@ class Span:
         rendered trace reads as a waterfall."""
         if base is None:
             base = self.t0
-        end = self.t1 if self.t1 is not None else time.perf_counter()
+        if self.t1 is None:
+            # an open span (the `request` root writing its own answer)
+            # reads its clock and the gc total now
+            end = time.perf_counter()
+            gc1 = gc_pause_ns()
+        else:
+            end, gc1 = self.t1, self.gc1
         out = {
             "name": self.name,
             "start_ms": round((self.t0 - base) * 1e3, 3),
             "duration_ms": round((end - self.t0) * 1e3, 3),
         }
         with _attrs_mu:
-            attrs = dict(self.attrs) if self.attrs else None
+            attrs = dict(self.attrs)
+        if self.gc0 is not None and gc1 is not None:
+            attrs["gc_ms"] = round((gc1 - self.gc0) / 1e6, 3)
         if attrs:
             out["attrs"] = attrs
         if self.children:

@@ -162,8 +162,9 @@ class Metrics:
             add(metric_name(base, **labels), v)
         for base, labels, v in journal.metrics_samples():
             add(metric_name(base, **labels), v)
-        # the always-on stall watch: stalls seen by its heartbeat and
-        # generation-2 gc pauses (obs/stallwatch.py)
+        # the always-on stall watch: stalls seen by its heartbeat, gc
+        # pauses by generation and the CPU of the threads that serve no
+        # request (obs/stallwatch.py)
         for base, labels, v in stallwatch.metrics_samples():
             add(metric_name(base, **labels), v)
         # ingest conservation ledger: per-tenant accepted/forwarded/

@@ -903,12 +903,17 @@ def _launch(runner, dispatch, *args):
     as one `operand_blocks`.  On a trace the span carries
     `device_queue_depth`: the scheduler's leased slots (dispatch units
     submitted and not yet harvested, process-wide) at this instant,
-    this unit's own lease left out."""
+    this unit's own lease left out, and the call runs under a
+    `vl_launch` profiler annotation, so a device profile's host planes
+    show which of jax's own host events the span's time is."""
     with tracing.current_span().span("launch") as sp:
         if sp.enabled:
             sp.set("device_queue_depth",
                    max(0, sched.scheduler().in_flight() - 1))
-        out = dispatch(*args)
+            with jax.profiler.TraceAnnotation("vl_launch"):
+                out = dispatch(*args)
+        else:
+            out = dispatch(*args)
     runner._bump("operand_blocks")
     return out
 

@@ -690,7 +690,6 @@ def _make_sync(runner):
         dt = time.perf_counter() - t0
         runner._bump("host_sync_wait_s", dt)
         hist.HOST_SYNC_WAIT.observe(dt)
-        tracing.current_span().add("host_sync_wait_s", dt)
         return out
 
     return sync
